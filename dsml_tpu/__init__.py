@@ -31,14 +31,6 @@ reference's own module name, ``/root/reference/DSML``).
 
 __version__ = "0.1.0"
 
-# Old-jax (0.4.x) compat shims must be in place before ANY framework module
-# (or test) touches jax.shard_map / lax.axis_size — the package init is the
-# one spot that runs first on every dsml_tpu.* import path. Imports jax but
-# does not initialize a backend, so platform selection still works after.
-from dsml_tpu.utils import compat as _compat
-
-_compat.install()
-
 # Lazy subpackage access keeps the heavy subpackages (models, comm, …) out
 # of the import path until used.
 _SUBPACKAGES = ("ops", "parallel", "models", "comm", "runtime", "utils", "cli",

@@ -836,15 +836,6 @@ class GPT2:
         batch_ranks = 1
         for a in batch_axes:
             batch_ranks *= lax.axis_size(a)
-        # On a jax without vma tracking (compat shim), the in-shard_map vjp
-        # transposes psum to psum, so the REPLICATED head seed crossing the
-        # logits' tp psum comes out multiplied by tp_size (exactly once:
-        # every later crossing sees an already-varying cotangent, which
-        # psum-transpose reduces correctly). Pre-divide the seed to cancel.
-        seed_div = batch_ranks
-        if getattr(jax, "_dsml_shimmed_vma", False) and tp_axis:
-            seed_div *= lax.axis_size(tp_axis)
-
         def stage_fn(stage_layers, x):
             def body(hh, one_layer):
                 return block(one_layer, hh), None
@@ -857,59 +848,13 @@ class GPT2:
 
         loss, d_stage, d_head, d_micros = pipeline_train_1f1b(
             stage_fn, head_fn, params["layers"], head_params, micros, tgt_micros,
-            pp_axis, vary_axes=vary_axes, loss_seed_scale=1.0 / (n_micro * seed_div),
+            pp_axis, vary_axes=vary_axes, loss_seed_scale=1.0 / (n_micro * batch_ranks),
         )
-        # On a jax WITHOUT vma tracking (the 0.4.x compat shim), the
-        # per-tick vjps do not auto-psum cotangents of replicated inputs:
-        # each rank holds a partial over every axis its compute varied on
-        # (and non-last pp ranks hold the head's masked zeros). Reduce each
-        # grad leaf over the varying axes its PartitionSpec leaves it
-        # REPLICATED on. On new jax a reduced leaf's vma already excludes
-        # those axes, so the psum list is empty and this is a no-op.
-        specs = self.param_specs(pp=True)
-
-        def _respec(g, spec):
-            named = set()
-            for part in spec:
-                if part is None:
-                    continue
-                named.update(part if isinstance(part, (tuple, list)) else (part,))
-            axes = tuple(
-                a for a in vary_axes if a not in named and a in jax.typeof(g).vma
-            )
-            return lax.psum(g, axes) if axes else g
-
-        head_specs = {k: v for k, v in specs.items() if k != "layers"}
-        d_head = jax.tree.map(_respec, d_head, head_specs)
-        d_stage = jax.tree.map(_respec, d_stage, specs["layers"])
-        if getattr(jax, "_dsml_shimmed_vma", False):
-            # no-vma jax: keep the feed cotangent VARYING over tp so the
-            # embed vjp's internal psum transpose performs the tp reduction
-            # itself (a replicated d_h would come out of that transpose
-            # multiplied by tp_size — the vocab-sharded wte leg). Leaves
-            # with no collective in their leg (wpe) stay per-rank partials;
-            # sum them over every non-pp axis their spec replicates.
-            d_h = lax.psum(d_micros.reshape(b, *h.shape[1:]), pp_axis)
-            (d_embed,) = embed_vjp(d_h)
-
-            def _reduce_partials(g, spec):
-                named = set()
-                for part in spec:
-                    if part is None:
-                        continue
-                    named.update(part if isinstance(part, (tuple, list)) else (part,))
-                axes = tuple(
-                    a for a in vary_axes if a != pp_axis and a not in named
-                )
-                return lax.psum(g, axes) if axes else g
-
-            d_embed = jax.tree.map(_reduce_partials, d_embed, head_specs)
-        else:
-            # cotangent of the (pp/tp-replicated) embedded stream: rank 0
-            # holds the pipeline's feed cotangent, tp ranks hold partials
-            sum_axes = (pp_axis,) + ((tp_axis,) if tp_axis else ())
-            d_h = lax.psum(d_micros.reshape(b, *h.shape[1:]), sum_axes)
-            (d_embed,) = embed_vjp(d_h)
+        # cotangent of the (pp/tp-replicated) embedded stream: rank 0
+        # holds the pipeline's feed cotangent, tp ranks hold partials
+        sum_axes = (pp_axis,) + ((tp_axis,) if tp_axis else ())
+        d_h = lax.psum(d_micros.reshape(b, *h.shape[1:]), sum_axes)
+        (d_embed,) = embed_vjp(d_h)
         grads_head = jax.tree.map(jnp.add, d_head, d_embed)
         return loss, {**grads_head, "layers": d_stage}
 
@@ -1464,15 +1409,15 @@ class GPT2:
         (out, lse) merge, dead/scratch entries skip-predicated — so the
         dense ``[b, H, S, hd]`` view is never materialized and HBM
         traffic scales with LIVE pages; the XLA gather path stays the
-        fallback and the parity oracle (``ops.paged_attention``). All
+        off-TPU route and the parity oracle (``ops.paged_attention``). All
         three surfaces' masks are ``key_pos <= query_pos``, which is why
         one kernel serves them: ``positions`` broadcast to [b, C] IS the
         mask."""
         from dsml_tpu.ops.paged_attention import paged_attention, paged_attn_impl
 
-        # pass the page geometry so the router can veto a working set that
-        # would blow the VMEM budget (falls back to the XLA gather with a
-        # warn-once instead of dying inside Mosaic at compile time)
+        # pass the page geometry so the router can refuse a working set
+        # that would blow the VMEM budget (a ValueError naming it, instead
+        # of dying inside Mosaic at compile time)
         use_pallas = paged_attn_impl(
             page_size=pool[0]["k"].shape[2],
             head_dim=self.config.d_model // self.config.n_head,

@@ -36,8 +36,7 @@ kernel walks the page table directly instead:
   GPT-2 (rep=1) and Llama (rep>1), dense-parity pinned for both.
 
 Routing: ``DSML_PAGED_ATTN=pallas|xla`` (:func:`paged_attn_impl`; default
-pallas on TPU, xla elsewhere — the gather path stays the fallback and the
-parity oracle). All three paged serving surfaces (decode / chunked prefill /
+pallas on TPU, xla elsewhere — the gather path stays the parity oracle). All three paged serving surfaces (decode / chunked prefill /
 speculative verify) route through here via ``_decode_core_paged``: their
 masks are all ``key_pos <= query_pos``, which is the one mask this kernel
 implements. On non-TPU backends the kernel runs under the Pallas
@@ -59,7 +58,7 @@ try:  # pltpu imports on CPU builds too; guard anyway (ops/flash.py idiom)
 except ImportError:  # pragma: no cover
     pltpu = None
 
-from dsml_tpu.ops.vmem_budget import fits_vmem, vmem_block_bytes, warn_once
+from dsml_tpu.ops.vmem_budget import require_vmem, vmem_block_bytes
 
 __all__ = [
     "paged_attention",
@@ -87,44 +86,37 @@ def paged_attn_impl(
     batcher compiles its programs once, so flip the env before
     construction, not between ticks.
 
-    When the caller passes its page GEOMETRY the answer is additionally
-    gated on the VMEM budget: a page whose kernel working set can't fit
-    the chip's VMEM would die inside Mosaic with an opaque allocation
-    error at compile time, so the route falls back to the ``xla`` gather
-    path here, with a warn-once, instead. Geometry-less calls keep the
-    env-only behavior (the knob test's contract)."""
+    When the caller passes its page GEOMETRY a ``pallas`` answer is
+    additionally checked against the VMEM budget: a page whose kernel
+    working set can't fit the chip's VMEM would die inside Mosaic with an
+    opaque allocation error at compile time, so this raises a
+    ``ValueError`` naming the geometry, the estimate and the budget
+    instead. Geometry-less calls keep the env-only behavior (the knob
+    test's contract)."""
     raw = os.environ.get("DSML_PAGED_ATTN", "").strip().lower()
     if raw not in ("pallas", "xla"):
         raw = "pallas" if jax.default_backend() == "tpu" else "xla"
     if raw == "pallas" and page_size is not None and head_dim is not None:
-        need = paged_vmem_bytes(page_size, head_dim, mode,
-                                n_query_rows=n_query_rows,
-                                pipeline=paged_pipeline())
-        if not fits_vmem(need):
-            warn_once(
-                f"paged-vmem-{page_size}-{head_dim}-{mode}",
-                f"paged-attention kernel working set ({need} B at "
-                f"page_size={page_size}, head_dim={head_dim}, mode={mode}) "
-                "exceeds the VMEM budget; falling back to the XLA gather "
-                "path (set DSML_VMEM_LIMIT_MB or shrink page_size)",
-            )
-            return "xla"
+        require_vmem(
+            paged_vmem_bytes(page_size, head_dim, mode,
+                             n_query_rows=n_query_rows,
+                             pipeline=paged_pipeline()),
+            f"paged-attention kernel at page_size={page_size}, "
+            f"head_dim={head_dim}, mode={mode}",
+        )
     return raw
 
 
 def paged_pipeline() -> bool:
     """The double-buffer knob: ``DSML_PAGED_ATTN_PIPELINE`` ∈ {"1"/"on",
-    "0"/"off"}; unset/"auto"/malformed enables the hand-pipelined kernel
-    on real TPUs and keeps the single-buffer kernel under the interpreter
-    (the interpreter executes DMAs synchronously, so manual pipelining
-    there is pure bookkeeping overhead — CPU parity tests opt in
-    explicitly). Read at trace time, like ``DSML_PAGED_ATTN``."""
+    "0"/"off"}; unset/"auto"/malformed selects the single-buffer kernel
+    on every backend. Mosaic refuses the hand-pipelined kernel at every
+    geometry with head_dim < 128 (``tests/test_tpu_compile.py`` carries
+    the message), so it is never chosen on its own; an explicit "1" still
+    selects it and lets the compiler's error surface. Read at trace time,
+    like ``DSML_PAGED_ATTN``."""
     raw = os.environ.get("DSML_PAGED_ATTN_PIPELINE", "").strip().lower()
-    if raw in ("1", "on", "true"):
-        return True
-    if raw in ("0", "off", "false"):
-        return False
-    return jax.default_backend() == "tpu"
+    return raw in ("1", "on", "true")
 
 
 def paged_vmem_bytes(
@@ -337,7 +329,7 @@ def _pipelined_kernel(table_ref, q_ref, pos_ref, k_hbm, v_hbm, *rest, mode,
 
 
 def _any_spec():
-    return pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    return pl.BlockSpec(memory_space=pl.ANY)
 
 
 def paged_attention(
@@ -373,8 +365,8 @@ def paged_attention(
     fold pages through the SAME ``_fold_page`` float sequence over the
     SAME live-entry order, so outputs are bit-identical — the
     single-buffer kernel is the pipelined kernel's parity oracle. A slot
-    ring that can't fit VMEM falls back to the single-buffer kernel with
-    a warn-once (:mod:`dsml_tpu.ops.vmem_budget`)."""
+    ring that can't fit VMEM raises a ``ValueError``
+    (:mod:`dsml_tpu.ops.vmem_budget`)."""
     if mode not in (None, "int8", "int4"):
         raise ValueError(f"unknown page quant mode {mode!r}")
     b, hq, c, hd = q.shape
@@ -388,16 +380,11 @@ def paged_attention(
     if pipeline is None:
         pipeline = paged_pipeline()
     if pipeline:
-        need = paged_vmem_bytes(page_size, hd, mode, pipeline=True)
-        if not fits_vmem(need):
-            warn_once(
-                f"paged-pipeline-vmem-{page_size}-{hd}-{mode}",
-                f"double-buffered paged-attention slot ring ({need} B at "
-                f"page_size={page_size}, head_dim={hd}, mode={mode}) "
-                "exceeds the VMEM budget; falling back to the "
-                "single-buffer kernel",
-            )
-            pipeline = False
+        require_vmem(
+            paged_vmem_bytes(page_size, hd, mode, pipeline=True),
+            f"double-buffered paged-attention slot ring at "
+            f"page_size={page_size}, head_dim={hd}, mode={mode}",
+        )
 
     # group query heads over their kv head (the GQA grouping rule — head
     # h serves kv head h // rep, matching Llama._decode_attention), then

@@ -510,7 +510,7 @@ def dequantize_weight_blocks(qwt: QuantizedWeight) -> jax.Array:
     """Reference inverse → f32 at the ORIGINAL shape. The serving hot path
     never calls this on-device (that would materialize the full-width
     weight in HBM — exactly what the fused kernel exists to avoid); it is
-    the parity oracle and the XLA fallback's operand."""
+    the parity oracle."""
     nb, n_p = qwt.qs.shape
     kb = qwt.block
     if qwt.scheme == "int4":
@@ -572,10 +572,10 @@ def quantized_matmul(x: jax.Array, qwt: QuantizedWeight,
     fused into the matmul: integer codes stream HBM→VMEM at their packed
     width, unpack + scale-fold happen per VMEM tile. Off-TPU the kernel
     runs under the Pallas interpreter (same float sequence — the CPU
-    parity pin); a block shape that would blow the VMEM budget falls back
-    to the XLA dequantize-then-dot path with a warn-once (the fallback
-    DOES materialize the f32 weight — slower and bigger, but it serves)."""
-    from dsml_tpu.ops.vmem_budget import fits_vmem, warn_once
+    parity pin); a block shape that would blow the VMEM budget raises a
+    ``ValueError`` (shrink ``DSML_QBLOCK`` or raise
+    ``DSML_VMEM_LIMIT_MB``)."""
+    from dsml_tpu.ops.vmem_budget import require_vmem
 
     m, d = x.shape
     nb, n_p = qwt.qs.shape
@@ -590,17 +590,10 @@ def quantized_matmul(x: jax.Array, qwt: QuantizedWeight,
         bm = 128
     m_p = -(-m // bm) * bm
     bn = 128
-    if not fits_vmem(quantized_matmul_vmem_bytes(bm, kb, bn, int4)):
-        warn_once(
-            f"qmm-vmem-{bm}-{kb}-{bn}-{qwt.scheme}",
-            f"dequant-fused matmul block ({bm}x{kb}x{bn}, {qwt.scheme}) "
-            f"exceeds the VMEM budget; falling back to the XLA "
-            f"dequantize-then-dot path (set DSML_VMEM_LIMIT_MB or shrink "
-            f"DSML_QBLOCK)",
-        )
-        return x.astype(jnp.float32) @ dequantize_weight_blocks(
-            qwt
-        ).reshape(d, -1)
+    require_vmem(
+        quantized_matmul_vmem_bytes(bm, kb, bn, int4),
+        f"dequant-fused matmul block {bm}x{kb}x{bn} ({qwt.scheme})",
+    )
     xf = x.astype(jnp.float32)
     if (m_p, d_p) != (m, d):
         xf = jnp.pad(xf, ((0, m_p - m), (0, d_p - d)))
@@ -622,7 +615,7 @@ def quantized_matmul(x: jax.Array, qwt: QuantizedWeight,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m_p, n_p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,

@@ -8,10 +8,10 @@ buffers HERE, at trace time, against the same model: blocks live in VMEM at
 their Mosaic-padded footprint (last dim padded to the 128-lane width,
 second-minor to the dtype's sublane tile), manual double buffering doubles
 every streamed buffer, and Pallas' own automatic pipelining double-buffers
-grid-walked BlockSpec operands. If the estimate doesn't fit, the caller
-falls back to its XLA path (or the unpipelined kernel) with a WARN-ONCE —
-a slower tick beats a crashed trace, and one log line beats a Mosaic
-stack trace (docs/TUNING.md "Kernel fusion" has the sizing rule).
+grid-walked BlockSpec operands. If the estimate doesn't fit,
+:func:`require_vmem` raises a ``ValueError`` naming the geometry, the
+estimate and the budget — no caller gives way to another path on its own
+(docs/TUNING.md "Kernel fusion" has the sizing rule).
 
 ``DSML_VMEM_LIMIT_MB`` overrides the default 16 MiB/core budget (the v4/v5
 figure the flash block sweep assumed); the guard spends at most
@@ -21,12 +21,9 @@ semaphores, and the operands the estimate can't see.
 
 from __future__ import annotations
 
-import logging
 import os
 
-logger = logging.getLogger("dsml_tpu.vmem")
-
-__all__ = ["vmem_limit_bytes", "vmem_block_bytes", "fits_vmem", "warn_once"]
+__all__ = ["vmem_limit_bytes", "vmem_block_bytes", "fits_vmem", "require_vmem"]
 
 _DEFAULT_VMEM_BYTES = 16 * 1024 * 1024  # per-core VMEM on v4/v5-class chips
 _SPEND_FRACTION = 0.9  # headroom for spills/semaphores the estimate omits
@@ -34,8 +31,6 @@ _SPEND_FRACTION = 0.9  # headroom for spills/semaphores the estimate omits
 # sublane tile height per itemsize (the Mosaic (sublane, 128-lane) tiling:
 # f32 packs (8, 128), bf16 (16, 128), int8/uint8 (32, 128))
 _SUBLANE = {4: 8, 2: 16, 1: 32}
-
-_warned: set = set()
 
 
 def vmem_limit_bytes() -> int:
@@ -77,13 +72,14 @@ def fits_vmem(nbytes: int) -> bool:
     return nbytes <= int(vmem_limit_bytes() * _SPEND_FRACTION)
 
 
-def warn_once(key: str, msg: str) -> None:
-    """Log ``msg`` once per process per ``key`` — the fallback path runs
-    every tick, the explanation should not."""
-    if key not in _warned:
-        _warned.add(key)
-        logger.warning(msg)
-
-
-def _reset_for_tests() -> None:  # pragma: no cover - test hook
-    _warned.clear()
+def require_vmem(nbytes: int, what: str) -> None:
+    """Raise unless ``nbytes`` of kernel working set fits the budget.
+    ``what`` names the kernel and its geometry for the message."""
+    if not fits_vmem(nbytes):
+        limit = vmem_limit_bytes()
+        raise ValueError(
+            f"{what}: estimated VMEM working set {nbytes} B exceeds the "
+            f"VMEM budget of {int(limit * _SPEND_FRACTION)} B "
+            f"({_SPEND_FRACTION:.0%} of {limit} B; DSML_VMEM_LIMIT_MB "
+            "overrides the limit)"
+        )

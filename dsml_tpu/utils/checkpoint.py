@@ -9,9 +9,7 @@ atomic rename commits, async background writes — ``docs/CHECKPOINT.md``).
 
 Backend selection: native by default. Orbax is OPTIONAL — used only when
 explicitly requested (``backend="orbax"`` or ``DSML_CKPT_BACKEND=orbax``)
-AND importable; the installed orbax/jax-0.4.37 pairing has known restore
-incompatibilities (PyTreeRestore argument drift), which is exactly why the
-default moved to the native backend.
+AND importable.
 """
 
 from __future__ import annotations

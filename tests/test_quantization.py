@@ -708,24 +708,22 @@ def test_blocked_weight_batcher_tokens_and_ledger():
                           weight_quant="fp8")
 
 
-def test_blocked_weight_matmul_vmem_fallback(monkeypatch, caplog):
-    """A starved VMEM budget routes the fused matmul to its XLA
-    dequant fallback with one warning — and the fallback is the oracle,
-    so the answer cannot move."""
+def test_blocked_weight_matmul_vmem_fallback(monkeypatch):
+    """A starved VMEM budget refuses the fused matmul with a ValueError
+    naming the block geometry, the estimate and the budget — it does not
+    give way to an XLA dequantize-then-dot."""
     from dsml_tpu.ops import vmem_budget
-    from dsml_tpu.ops.quantization import quantize_weight_blocks, quantized_matmul
+    from dsml_tpu.ops.quantization import (
+        quantize_weight_blocks, quantized_matmul, quantized_matmul_vmem_bytes,
+    )
 
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((4, 256)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)
     qwt = quantize_weight_blocks(w, "int4", 128)
-    want = np.asarray(quantized_matmul(x, qwt))
     monkeypatch.setattr(vmem_budget, "_DEFAULT_VMEM_BYTES", 16 * 1024)
     monkeypatch.delenv("DSML_VMEM_LIMIT_MB", raising=False)
-    vmem_budget._reset_for_tests()
-    with caplog.at_level("WARNING", logger="dsml_tpu.vmem"):
-        got = np.asarray(quantized_matmul(x, qwt))
-        np.asarray(quantized_matmul(x, qwt))
-    assert sum("VMEM budget" in r.message for r in caplog.records) == 1
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    vmem_budget._reset_for_tests()
+    need = quantized_matmul_vmem_bytes(8, 128, 128, True)
+    with pytest.raises(ValueError, match=rf"8x128x128 \(int4\).*{need} B"
+                       rf".*{int(16 * 1024 * 0.9)} B"):
+        quantized_matmul(x, qwt)

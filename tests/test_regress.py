@@ -1,11 +1,10 @@
-"""Perf-regression gate: extraction over the real (messy) BENCH history,
-noise-band math pinned against numpy, and the CLI contract — an injected
->=20% step-time slowdown exits nonzero, the unchanged committed history
-exits zero.
+"""Perf-regression gate: extraction over every (messy) record shape a
+driver's capture can take, noise-band math pinned against numpy, and the CLI
+contract — an injected >=20% step-time slowdown exits nonzero, an unchanged
+history exits zero.
 """
 
 import json
-import os
 
 import numpy as np
 import pytest
@@ -19,34 +18,31 @@ from dsml_tpu.obs.regress import (
     noise_band,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
 # ---------------------------------------------------------------------------
-# extraction: every artifact shape the committed history actually has
+# extraction: every artifact shape a capture can take
 # ---------------------------------------------------------------------------
 
 
-def test_extracts_full_record_with_parsed_payload():
-    m = extract_metrics(os.path.join(REPO, "BENCH_r01.json"))
-    assert m["mnist_samples_per_sec_per_chip"] == pytest.approx(36980619.8)
-    assert m["allreduce_ring_p50_ms"] == pytest.approx(0.016)
+def test_extracts_full_record_with_parsed_payload(bench_history):
+    m = extract_metrics(str(bench_history / "BENCH_r01.json"))
+    assert m["mnist_samples_per_sec_per_chip"] == pytest.approx(1200.5)
+    assert m["allreduce_ring_p50_ms"] == pytest.approx(0.5)
     assert "cmd" not in m and "rc" not in m  # record structure is not a metric
 
 
-def test_extracts_truncated_tail_with_null_parsed():
-    # r03's 2000-byte tail is cut mid-JSON on BOTH ends and parsed is null —
-    # a strict json.loads would yield nothing; the scanner must recover the
-    # numeric pairs anyway
-    m = extract_metrics(os.path.join(REPO, "BENCH_r03.json"))
-    assert len(m) >= 15
-    assert m["allreduce_ring_p50_ms"] == pytest.approx(9.853)
+def test_extracts_truncated_tail_with_null_parsed(bench_history):
+    # r03's tail is cut mid-JSON on BOTH ends and parsed is null — a strict
+    # json.loads would yield nothing; the scanner must recover the numeric
+    # pairs anyway
+    m = extract_metrics(str(bench_history / "BENCH_r03.json"))
+    assert len(m) >= 10
+    assert m["allreduce_ring_p50_ms"] == pytest.approx(0.52)
     assert m["gpt2_realtext_eval_ppl"] == pytest.approx(13.72)
 
 
-def test_timeout_record_yields_nothing_not_garbage():
+def test_timeout_record_yields_nothing_not_garbage(bench_history):
     # r04 timed out (rc=124) before emitting any metrics line
-    assert extract_metrics(os.path.join(REPO, "BENCH_r04.json")) == {}
+    assert extract_metrics(str(bench_history / "BENCH_r04.json")) == {}
 
 
 def test_extracts_headline_metric_from_raw_stdout():
@@ -233,12 +229,11 @@ def test_report_only_mode_always_exits_zero(tmp_path):
     assert rep["report_only"] is True
 
 
-def test_real_bench_history_self_check_exits_zero():
-    """THE committed-history pin: the gate run exactly as CI runs it, over
-    BENCH_r01..r05 with the newest record as the fresh sample, must be
-    clean — these five artifacts are the accepted baseline, not a
-    regression against themselves."""
-    rc = regress.main(["--history", os.path.join(REPO, "BENCH_r*.json")])
+def test_bench_history_self_check_exits_zero(bench_history):
+    """Self-check mode: the gate over BENCH_r01..r05 with the newest record
+    as the fresh sample must be clean — a history is not a regression
+    against itself, whatever shapes its records take."""
+    rc = regress.main(["--history", str(bench_history / "BENCH_r*.json")])
     assert rc == 0
 
 
@@ -252,8 +247,8 @@ def test_unparseable_history_exits_2(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_profile_exports_collective_constants_from_real_history():
-    history = [extract_metrics(os.path.join(REPO, f"BENCH_r{i:02d}.json"))
+def test_profile_exports_collective_constants_from_history(bench_history):
+    history = [extract_metrics(str(bench_history / f"BENCH_r{i:02d}.json"))
                for i in range(1, 6)]
     history = [h for h in history if h]
     fresh = history[-1]
