@@ -33,7 +33,14 @@ import os
 import sys
 import time
 
+import jax
 import numpy as np
+import optax
+
+from dsml_tpu.models.gpt2 import GPT2, GPT2Config
+from dsml_tpu.parallel.hybrid import init_hybrid, make_hybrid_train_step
+from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
+from dsml_tpu.utils.platform import configure_compile_cache
 
 BATCH = 8
 LR = 3e-4
@@ -54,7 +61,7 @@ def _version(dist: str) -> str | None:
         return None
 
 
-def _device_report(jax) -> dict:
+def _device_report() -> dict:
     dev = jax.devices()[0]
     return {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}
 
@@ -64,11 +71,6 @@ def _run_steps(model, mesh, attn_impl: str, x, y, n_steps: int, seed: int):
     one batch. The first call is timed as compile; the rest as step wall time
     around ``block_until_ready``. Returns the printable notes and the live
     ``(step, params, opt_state)`` for the checks that need them."""
-    import jax
-    import optax
-
-    from dsml_tpu.parallel.hybrid import init_hybrid, make_hybrid_train_step
-
     opt = optax.adamw(LR)
     params, opt_state = init_hybrid(model, opt, mesh, seed=seed)
     step = make_hybrid_train_step(model, opt, mesh, attn_impl=attn_impl)
@@ -102,13 +104,9 @@ def _check_losses_agree(failures: list, got: list, want: list, what: str) -> Non
 
 def phase_train(model, x, y, seed: int, rehearse: bool) -> list:
     """One chip: four flash steps, checked against three xla steps."""
-    import jax
-
-    from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
-
     failures: list = []
     mesh = build_mesh(MeshSpec(dp=1), jax.devices()[:1])
-    flash, state = _run_steps(model, mesh, "flash", x, y, 4, seed)
+    flash, (step, params, opt_state) = _run_steps(model, mesh, "flash", x, y, 4, seed)
     losses = flash["losses"]
     ln_vocab = math.log(model.config.vocab_size)
     _check(failures, all(math.isfinite(v) for v in losses), f"non-finite loss: {losses}")
@@ -119,13 +117,11 @@ def phase_train(model, x, y, seed: int, rehearse: bool) -> list:
     if not rehearse:
         # the flash kernel must be the Mosaic one, not the interpreter; the
         # persistent cache makes this second compile of the same step cheap
-        step, params, opt_state = state
         text = step.lower(params, opt_state, x, y).compile().as_text()
         flash["tpu_custom_calls"] = text.count("tpu_custom_call")
         _check(failures, flash["tpu_custom_calls"] > 0,
                "no tpu_custom_call in the compiled flash step")
-        del step, params, opt_state
-    del state  # free the flash run's device state before the reference
+    del params, opt_state  # free the flash run's device state before the reference
 
     xla, _ = _run_steps(model, mesh, "xla", x, y, 3, seed)
     _check_losses_agree(failures, losses[:3], xla["losses"], "flash vs xla")
@@ -139,10 +135,6 @@ def phase_train(model, x, y, seed: int, rehearse: bool) -> list:
 def phase_dp4(model, x, y, seed: int, rehearse: bool) -> list:
     """Four chips: three dp=4 steps (two rows a chip), checked against the
     same three steps on a one-device mesh in this process."""
-    import jax
-
-    from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
-
     failures: list = []
     devices = jax.devices()
     if len(devices) < 4:
@@ -177,13 +169,8 @@ def main(argv=None) -> int:
                     help="sandbox rehearsal: tiny config, no TPU-only checks")
     args = ap.parse_args(argv)
 
-    import jax
-
-    from dsml_tpu.models.gpt2 import GPT2, GPT2Config
-    from dsml_tpu.utils.platform import configure_compile_cache
-
     cache_dir = configure_compile_cache()
-    device = _device_report(jax)
+    device = _device_report()
     if device["platform"] != "tpu" and not args.rehearse:
         _emit({"ok": False, "reason": f"no TPU: jax.devices()[0].platform is "
                f"{device['platform']!r}; this smoke runs on the chip only", "device": device})

@@ -36,10 +36,10 @@ kernel walks the page table directly instead:
   GPT-2 (rep=1) and Llama (rep>1), dense-parity pinned for both.
 
 Routing: ``DSML_PAGED_ATTN=pallas|xla`` (:func:`paged_attn_impl`; default
-pallas on TPU, xla elsewhere — the gather path stays the parity oracle). All three paged serving surfaces (decode / chunked prefill /
-speculative verify) route through here via ``_decode_core_paged``: their
-masks are all ``key_pos <= query_pos``, which is the one mask this kernel
-implements. On non-TPU backends the kernel runs under the Pallas
+pallas on TPU, xla elsewhere — the gather path stays the parity oracle).
+All three paged serving surfaces (decode / chunked prefill / speculative
+verify) route through here via ``_decode_core_paged``: their masks are all
+``key_pos <= query_pos``, which is the one mask this kernel implements. On non-TPU backends the kernel runs under the Pallas
 interpreter, which is how CI pins parity on the CPU mesh.
 """
 

@@ -29,6 +29,34 @@ def test_peak_flops_raises_on_unknown_device_kind():
         bench._peak_flops(types.SimpleNamespace(device_kind="cpu"))
 
 
+def test_main_exits_nonzero_when_a_section_raises(monkeypatch, capsys):
+    """``main()`` runs on whatever ``jax.devices()`` gives, keeps the other
+    sections' rows, prints the one JSON line naming the device, and returns
+    nonzero because a section raised — no fallback, no exit 0."""
+    sys.path.insert(0, REPO)
+    import bench
+
+    def boom():
+        raise RuntimeError("flagship failed")
+
+    monkeypatch.setattr(bench, "bench_gpt2", boom)
+    monkeypatch.setattr(bench, "_MAIN_SECTIONS", (("mnist", lambda: {"mnist_rows": 1}, 0),))
+    assert bench.main() == 1
+    head = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert head["value"] is None
+    ex = head["extras"]
+    assert "flagship failed" in ex["errors"]["gpt2"]
+    assert ex["mnist_rows"] == 1
+    assert (ex["platform"], ex["device_kind"]) == ("cpu", "cpu")
+
+    monkeypatch.setattr(bench, "bench_gpt2", lambda: {})
+    assert bench.main() == 0
+
+
+# slow tier, like every other section-schema test below it (19 s): the
+# registry, latency histograms and step breakdown the section reads have
+# their own unit tests in test_obs.py
+@pytest.mark.slow
 def test_obs_section_schema():
     """The BENCH `obs` section's contract (ISSUE 4 acceptance): per-
     algorithm collective-latency histograms, a step-time breakdown whose
@@ -132,6 +160,10 @@ def test_cluster_section_schema(bench_history, monkeypatch):
     assert prof["schema"] == "dsml.obs.collective_profile/1"
 
 
+# slow tier (13 s, a subprocess): the quantized-ring loss-trajectory parity
+# stays default in test_bucketing.py, the wire-byte counts in
+# test_quantization.py, and tier1.yml runs the section itself as a smoke step
+@pytest.mark.slow
 def test_quant_sweep_section_schema(monkeypatch):
     """The BENCH `quant_sweep` section's contract (ISSUE 9 acceptance):
     the (bucket × scheme × algorithm) grid reports per-cell sync ms +

@@ -67,14 +67,11 @@ def test_compile_cache_helper_leaves_env_choice_alone(monkeypatch, tmp_path):
 def test_compile_cache_helper_default_is_the_checkout(monkeypatch, tmp_path):
     from dsml_tpu.utils.platform import configure_compile_cache
 
-    before = jax.config.jax_compilation_cache_dir
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    try:
-        got = []
-        for cwd in (tmp_path, REPO):
-            monkeypatch.chdir(cwd)
-            got.append(configure_compile_cache())
-            assert jax.config.jax_compilation_cache_dir == got[-1]
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+    got = []
+    for cwd in (tmp_path, REPO):
+        monkeypatch.chdir(cwd)
+        got.append(configure_compile_cache())
+        assert jax.config.jax_compilation_cache_dir == got[-1]
+    # (the conftest's autouse fixture unsets it again after this test)
     assert got == [os.path.join(REPO, ".jax_cache")] * 2

@@ -54,14 +54,10 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
-def _one_chip():
-    return SingleDeviceSharding(_TOPO.devices[0])
-
-
 def _compile(fn, *shapes):
     """Lower ``fn`` on shape-only arguments placed on one described v5e
     device and compile with the real TPU compiler; returns the HLO text."""
-    sharding = _one_chip()
+    sharding = SingleDeviceSharding(_TOPO.devices[0])
     args = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), shapes
     )
