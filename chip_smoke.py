@@ -18,13 +18,15 @@ skips the checks only a TPU can answer (the platform, ``tpu_custom_call`` in
 the compiled step, per-device ``memory_stats`` — the CPU reports none).
 
 Each phase prints one JSON line of notes (compile seconds, step ms, losses,
-peak bytes) — notes for the next PR, not benchmark numbers. The last line is
-the verdict the driver reads: ``{"ok": true, "device": {...}}``.
+what the persistent compile cache served, the compiled step's memory, peak
+bytes) — notes for the next PR, not benchmark numbers. The last line is the
+verdict the driver reads: ``{"ok": true, "device": {...}}``.
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import importlib.metadata
 import json
@@ -49,6 +51,28 @@ LR = 3e-4
 # different order. Absolute, on losses near ln(vocab) ~ 10.8.
 LOSS_TOL = 2e-2
 
+# what jax's persistent compile cache did, counted from its own events:
+# `requests` compiles consulted it, `hits` were served from it, `writes` were
+# stored in it (a compile under a second is not stored)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "writes",
+}
+_cache_counts: collections.Counter = collections.Counter()
+
+
+def _count_cache_event(event: str, **_) -> None:
+    if event in _CACHE_EVENTS:
+        _cache_counts[_CACHE_EVENTS[event]] += 1
+
+
+jax.monitoring.register_event_listener(_count_cache_event)
+
+
+def _cache_since(before: collections.Counter) -> dict:
+    return {name: _cache_counts[name] - before[name] for name in _CACHE_EVENTS.values()}
+
 
 def _emit(obj: dict) -> None:
     print(json.dumps(obj), flush=True)
@@ -69,12 +93,14 @@ def _device_report() -> dict:
 def _run_steps(model, mesh, attn_impl: str, x, y, n_steps: int, seed: int):
     """init_hybrid + make_hybrid_train_step on ``mesh``; ``n_steps`` on the
     one batch. The first call is timed as compile; the rest as step wall time
-    around ``block_until_ready``. Returns the printable notes and the live
-    ``(step, params, opt_state)`` for the checks that need them."""
+    around ``block_until_ready``; the cache counts cover the step calls alone.
+    Returns the printable notes and the live ``(step, params, opt_state)``
+    for the checks that need them."""
     opt = optax.adamw(LR)
     params, opt_state = init_hybrid(model, opt, mesh, seed=seed)
     step = make_hybrid_train_step(model, opt, mesh, attn_impl=attn_impl)
     losses, wall_ms = [], []
+    cache_before = _cache_counts.copy()
     for _ in range(n_steps):
         t0 = time.perf_counter()
         params, opt_state, loss = step(params, opt_state, x, y)
@@ -87,6 +113,7 @@ def _run_steps(model, mesh, attn_impl: str, x, y, n_steps: int, seed: int):
         "losses": losses,
         "compile_s": round(wall_ms[0] / 1e3, 3),
         "step_ms": [round(ms, 3) for ms in wall_ms[1:]],
+        "compile_cache": _cache_since(cache_before),
     }
     return notes, (step, params, opt_state)
 
@@ -114,14 +141,22 @@ def phase_train(model, x, y, seed: int, rehearse: bool) -> list:
            f"step-1 loss {losses[0]:.4f} not within 1.0 of ln(vocab)={ln_vocab:.4f}")
     _check(failures, losses[3] < losses[0],
            f"step-4 loss {losses[3]:.4f} not below step-1 loss {losses[0]:.4f}")
+    # the flash kernel must be the Mosaic one, not the interpreter: read the
+    # compiled step (jit hands back the executable the first call built, in
+    # ~0.4 s on the chip, without another compile). The same object gives the
+    # step's memory as the compiler planned it: on this runtime memory_stats'
+    # peak counts live buffers and leaves the program's temp out.
+    t0 = time.perf_counter()
+    compiled = step.lower(params, opt_state, x, y).compile()
+    flash["tpu_custom_calls"] = compiled.as_text().count("tpu_custom_call")
+    mem = compiled.memory_analysis()
+    flash["compiled_memory_bytes"] = {
+        k: getattr(mem, f"{k}_size_in_bytes") for k in ("argument", "output", "alias", "temp")}
+    flash["read_compiled_s"] = round(time.perf_counter() - t0, 3)
     if not rehearse:
-        # the flash kernel must be the Mosaic one, not the interpreter; the
-        # persistent cache makes this second compile of the same step cheap
-        text = step.lower(params, opt_state, x, y).compile().as_text()
-        flash["tpu_custom_calls"] = text.count("tpu_custom_call")
         _check(failures, flash["tpu_custom_calls"] > 0,
                "no tpu_custom_call in the compiled flash step")
-    del params, opt_state  # free the flash run's device state before the reference
+    del params, opt_state, compiled  # free the flash run's device state before the reference
 
     xla, _ = _run_steps(model, mesh, "xla", x, y, 3, seed)
     _check_losses_agree(failures, losses[:3], xla["losses"], "flash vs xla")

@@ -18,8 +18,16 @@ def configure_compile_cache() -> str:
     With ``JAX_COMPILATION_CACHE_DIR`` in the environment jax reads it
     itself and nothing is set in code. Otherwise the cache lives at
     ``<checkout>/.jax_cache``, computed from this file so every working
-    directory resolves the same path (the path is part of the cache key —
-    a directory that moves never hits)."""
+    directory resolves the same path.
+
+    What it serves (PERF.md §6, PR 22, measured on a v5e): a program of XLA
+    ops alone hits from any checkout. A program that holds a Pallas kernel
+    hits only from the same checkout path with the same source lines: the
+    Mosaic payload inside ``tpu_custom_call`` carries the Python locations
+    of the kernel's call stack, and jax strips debug info from the module
+    around it, not from the payload. ``JAX_TRACEBACK_IN_LOCATIONS_LIMIT=0``
+    in the environment takes the locations out (and with them the source
+    lines in Mosaic's errors); then it hits from anywhere."""
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

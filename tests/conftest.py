@@ -23,25 +23,15 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
+# no persistent compile cache in the test process: the examples' ``main()``
+# and ``bench.main()`` place one (``utils.platform.configure_compile_cache``),
+# and a compile for a described TPU (test_tpu_compile.py) cannot be read back
+# without the chip. Subprocesses a test starts keep their own.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import json  # noqa: E402
 
 import pytest  # noqa: E402
-
-
-@pytest.fixture(autouse=True)
-def _compile_cache_stays_per_test():
-    """The examples' ``main()`` and ``bench.main()`` place jax's persistent
-    compile cache (``utils.platform.configure_compile_cache``) in this
-    process; undo it after the test, so one test's cache never serves (or
-    slows) the tests after it."""
-    yield
-    if (jax.config.jax_compilation_cache_dir is not None
-            and "JAX_COMPILATION_CACHE_DIR" not in os.environ):
-        from jax.experimental.compilation_cache import compilation_cache
-
-        jax.config.update("jax_compilation_cache_dir", None)
-        compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="session")

@@ -53,10 +53,6 @@ def test_main_exits_nonzero_when_a_section_raises(monkeypatch, capsys):
     assert bench.main() == 0
 
 
-# slow tier, like every other section-schema test below it (19 s): the
-# registry, latency histograms and step breakdown the section reads have
-# their own unit tests in test_obs.py
-@pytest.mark.slow
 def test_obs_section_schema():
     """The BENCH `obs` section's contract (ISSUE 4 acceptance): per-
     algorithm collective-latency histograms, a step-time breakdown whose
@@ -160,10 +156,6 @@ def test_cluster_section_schema(bench_history, monkeypatch):
     assert prof["schema"] == "dsml.obs.collective_profile/1"
 
 
-# slow tier (13 s, a subprocess): the quantized-ring loss-trajectory parity
-# stays default in test_bucketing.py, the wire-byte counts in
-# test_quantization.py, and tier1.yml runs the section itself as a smoke step
-@pytest.mark.slow
 def test_quant_sweep_section_schema(monkeypatch):
     """The BENCH `quant_sweep` section's contract (ISSUE 9 acceptance):
     the (bucket × scheme × algorithm) grid reports per-cell sync ms +

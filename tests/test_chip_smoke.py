@@ -49,8 +49,14 @@ def test_smoke_rehearsal_passes_and_caches_where_told(tmp_path):
     assert train["phase"] == "train" and train["failures"] == []
     assert len(train["flash"]["losses"]) == 4
     assert len(train["xla_reference"]["losses"]) == 3
-    # the environment's directory is the one the run wrote, and the
-    # checkout's default was left alone
+    # the environment's directory is the one the run wrote (by jax's own
+    # count: each step compiled once, nothing to hit in a new directory, the
+    # flash step stored — the xla one may compile in under the second jax
+    # asks of an entry), and the checkout's default was left alone
+    for run in (train["flash"], train["xla_reference"]):
+        assert (run["compile_cache"]["requests"], run["compile_cache"]["hits"]) == (1, 0)
+    assert train["flash"]["compile_cache"]["writes"] == 1
+    assert train["flash"]["compiled_memory_bytes"]["temp"] > 0
     assert any(cache.iterdir())
     assert not (tmp_path / ".jax_cache").exists()
 
@@ -73,5 +79,5 @@ def test_compile_cache_helper_default_is_the_checkout(monkeypatch, tmp_path):
         monkeypatch.chdir(cwd)
         got.append(configure_compile_cache())
         assert jax.config.jax_compilation_cache_dir == got[-1]
-    # (the conftest's autouse fixture unsets it again after this test)
+    # (the value stays set; conftest keeps the cache itself off in this process)
     assert got == [os.path.join(REPO, ".jax_cache")] * 2

@@ -12,7 +12,9 @@ under the kernel vs the pool-table shape under the gather.
 """
 
 import dataclasses
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -195,16 +197,7 @@ def test_paged_attn_impl_env_knob(monkeypatch):
     assert paged_attn_impl() == ("pallas" if on_tpu else "xla")
 
 
-# default tier keeps the fp pool (the three-implementation decode surface);
-# the int4/int8 legs (34 s / 22 s under the interpreter) ride the slow tier —
-# per-codec kernel parity stays default in test_kernel_parity_* and
-# test_pipelined_kernel_bit_identical_all_codecs, and the quantized paged
-# batcher end to end in test_paged_kv.py
-@pytest.mark.parametrize("quant", [
-    pytest.param("int4", marks=pytest.mark.slow),
-    pytest.param("int8", marks=pytest.mark.slow),
-    False,
-])
+@pytest.mark.parametrize("quant", ["int4", "int8", False])
 def test_decode_step_slots_paged_greedy_bit_identity(setup, monkeypatch,
                                                      quant):
     """The full decode surface: prefill a prompt into scattered pages,
@@ -212,7 +205,9 @@ def test_decode_step_slots_paged_greedy_bit_identity(setup, monkeypatch,
     — the XLA gather, the single-buffer kernel, and the double-buffered
     kernel — greedy argmax tokens BIT-identical (the acceptance bar),
     logits within f32 reassociation noise, and the two kernel schedules
-    bit-identical to each other (same ``_fold_page`` float sequence)."""
+    bit-identical to each other (same ``_fold_page`` float sequence).
+    Both entry points run jitted, as the batcher runs them (eager, the
+    interpreter re-walks the kernel grid op by op on every call)."""
     cfg, model, params = setup
     rng = np.random.default_rng(5)
     prompt = rng.integers(1, cfg.vocab_size, 21).astype(np.int32)
@@ -223,26 +218,29 @@ def test_decode_step_slots_paged_greedy_bit_identity(setup, monkeypatch,
     table[0, : len(pages)] = pages
 
     def run(impl, pipe="0"):
+        # the env knobs are read at trace time: one jit pair per implementation
         monkeypatch.setenv("DSML_PAGED_ATTN", impl)
         monkeypatch.setenv("DSML_PAGED_ATTN_PIPELINE", pipe)
+        prefill = jax.jit(functools.partial(model.prefill_chunk_paged, quant=quant))
+        decode = jax.jit(functools.partial(model.decode_step_slots_paged, quant=quant))
         pool = model.init_page_pool(14, page, quant=quant)
         for start in range(0, len(prompt), c):
             end = min(start + c, len(prompt))
             padded = np.zeros((1, c), np.int32)
             padded[0, : end - start] = prompt[start:end]
             last = (len(prompt) - 1) - start if end >= len(prompt) else c - 1
-            logits, pool = model.prefill_chunk_paged(
+            logits, pool = prefill(
                 params, pool, jnp.asarray(table), jnp.asarray(padded),
-                jnp.int32(start), last_index=last, quant=quant,
+                jnp.int32(start), last_index=jnp.int32(last),
             )
         toks, rows = [], []
         tok = jnp.argmax(logits[0]).astype(jnp.int32)
         pos = len(prompt)
         for _ in range(5):
             toks.append(int(tok))
-            logits, pool = model.decode_step_slots_paged(
+            logits, pool = decode(
                 params, pool, jnp.asarray(table), tok[None],
-                jnp.asarray([pos], jnp.int32), quant=quant,
+                jnp.asarray([pos], jnp.int32),
             )
             rows.append(np.asarray(logits[0]))
             tok = jnp.argmax(logits[0]).astype(jnp.int32)

@@ -56,11 +56,7 @@ def test_ring_forward_matches_full_attention(devices8, cp, s, causal):
     np.testing.assert_allclose(got, expected, rtol=2e-4, atol=2e-5)
 
 
-# the evenly-tiling (4, 96) legs ride the slow tier (12 s for the pair): the
-# odd, padded lengths at cp=2 and cp=4 — the harder path — stay default, as
-# does the (4, 96) forward above
-@pytest.mark.parametrize("cp,s", [
-    (2, 66), pytest.param(4, 96, marks=pytest.mark.slow), (4, 52)])
+@pytest.mark.parametrize("cp,s", [(2, 66), (4, 96), (4, 52)])
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_backward_matches_full_attention(devices8, cp, s, causal):
     """The KV re-streaming backward: dq accumulated locally, dk/dv toured
@@ -469,15 +465,12 @@ def test_ring_fused_forward_bit_identical(devices8, cp, s, causal, fused):
         got, np.asarray(attention(q, k, v, causal)), rtol=2e-4, atol=2e-5)
 
 
-# default tier keeps one pairing: both fused modes share ONE backward
-# schedule (ops/ring_attention.py::_ring_bwd_rule branches on `fused`, not
-# on the mode), the dma forward stays default in
-# test_ring_fused_forward_bit_identical, and cp=4 backward parity in
-# test_ring_backward_matches_full_attention; the other pairings (17 s for
-# the dma leg under the interpreter) are the slow tier
+# default tier keeps cp ∈ {2,4} with the two modes split across them
+# (the acceptance pin); the transposed mode×cp pairings are the slow-tier
+# half of the matrix — the backward schedule differs by mode, not by cp
 @pytest.mark.parametrize("cp,s,fused", [
     (2, 66, "sendahead"),
-    pytest.param(4, 52, "dma", marks=pytest.mark.slow),
+    (4, 52, "dma"),
     pytest.param(2, 66, "dma", marks=pytest.mark.slow),
     pytest.param(4, 52, "sendahead", marks=pytest.mark.slow),
 ])

@@ -6,13 +6,18 @@ Nothing runs here, so nothing is said about results or times — a passed
 compile is not a chip run. What this guards is what interpret mode cannot
 see: Mosaic layout rules (tile alignment, unsupported ops on packed types)
 and block-shape rules. A kernel the compiler refuses is a strict ``xfail``
-whose ``reason`` quotes the refusal, so the PR that repairs the kernel's
-layout flips the mark in the same diff. Every case passes ``interpret=False``
-itself: under ``JAX_PLATFORMS=cpu`` the kernels' own default is the
-interpreter, which compiles to zero ``tpu_custom_call``.
+whose ``reason`` quotes the refusal, and the case checks that the compiler
+still says exactly that: a kernel that now compiles, a changed message or any
+other error fails the case, so the PR that repairs the kernel's layout flips
+the mark in the same diff. Every case passes ``interpret=False`` itself: under
+``JAX_PLATFORMS=cpu`` the kernels' own default is the interpreter, which
+compiles to zero ``tpu_custom_call``. The persistent compile cache is off for
+the whole test process (``conftest.py``): a compile for a described chip
+cannot be read back without the chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -37,21 +42,6 @@ pytestmark = pytest.mark.skipif(
     isinstance(_TOPO, Exception),
     reason=f"cannot describe a v5e:2x2 topology here: {_TOPO!r}",
 )
-
-
-@pytest.fixture(autouse=True)
-def _no_compile_cache():
-    """A compile for a described chip is written to the persistent cache but
-    cannot be read back without the chip (the next one warns and recompiles),
-    so these tests turn the cache off around themselves."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    cc.reset_cache()
 
 
 def _compile(fn, *shapes):
@@ -122,47 +112,67 @@ def _paged_compile(mode, pipeline):
     )
 
 
-def _refused(*values, reason):
-    """A case the compiler refuses today: strict, so the repair flips it."""
-    return pytest.param(*values, marks=pytest.mark.xfail(strict=True, reason=reason))
+class _Refused(Exception):
+    """The compiler refused the kernel with the message the case quotes."""
 
 
-_SHRUI = (
-    "Mosaic failed to compile TPU kernel: failed to legalize operation "
-    "'arith.shrui' on vector<8x128x4xi8> (_fold_page's nibble unpack shifts "
-    "an i8 vector)"
-)
-_SLICE = (
-    "Mosaic failed to compile TPU kernel: Slice shape along dimension 3 must "
-    "be aligned to tiling (128), but is {} (the slot ring DMAs one "
-    "[page, head_dim] page out of a pool whose lane dim pads to 128)"
-)
+def _refused(*values, message, why):
+    """A case the compiler refuses today, carrying the message it gives.
+    Strict, and only ``_Refused`` counts: see ``_answer``."""
+    return pytest.param(
+        *values, message, id="-".join(map(str, values)),
+        marks=pytest.mark.xfail(strict=True, raises=_Refused, reason=f"{message} ({why})"),
+    )
+
+
+def _answer(refusal, compile_fn):
+    """The compiled text of a kernel the compiler takes. For one it refuses,
+    hold it to the quoted message, then hand the refusal to the xfail mark."""
+    if refusal is None:
+        return compile_fn()
+    with pytest.raises(Exception, match=re.escape(refusal)) as caught:
+        compile_fn()
+    raise _Refused(refusal) from caught.value
+
+
+_SHRUI = "Mosaic failed to compile TPU kernel: failed to legalize operation 'arith.shrui'"
+_SHRUI_WHY = "_fold_page's nibble unpack shifts an i8 vector, vector<8x128x4xi8>"
+_SLICE = ("Mosaic failed to compile TPU kernel: Slice shape along dimension 3 "
+          "must be aligned to tiling (128), but is {}")
+_SLICE_WHY = ("the slot ring DMAs one [page, head_dim] page out of a pool whose "
+              "lane dim pads to 128")
 _BLOCK = (
     "The Pallas TPU lowering currently requires that the last two dimensions "
     "of your block shape are divisible by 8 and 128 respectively, or be equal "
-    "to the respective dimensions of the overall array: the scale operand's "
-    "block (1, 128) on an array (6, 3072)"
+    "to the respective dimensions of the overall array"
 )
+_BLOCK_WHY = "the scale operand's block (1, 128) on an array (6, 3072)"
 
 
-@pytest.mark.parametrize("mode", [None, "int8", _refused("int4", reason=_SHRUI)])
-def test_paged_decode_single_buffer_compiles(mode):
-    assert "tpu_custom_call" in _paged_compile(mode, pipeline=False)
-
-
-@pytest.mark.parametrize("mode", [
-    _refused(None, reason=_SLICE.format(64)),
-    _refused("int8", reason=_SLICE.format(64)),
-    _refused("int4", reason=_SLICE.format(32)),
+@pytest.mark.parametrize("mode,refusal", [
+    pytest.param(None, None, id="fp"), pytest.param("int8", None, id="int8"),
+    _refused("int4", message=_SHRUI, why=_SHRUI_WHY),
 ])
-def test_paged_decode_pipelined_compiles(mode):
-    assert "tpu_custom_call" in _paged_compile(mode, pipeline=True)
+def test_paged_decode_single_buffer_compiles(mode, refusal):
+    text = _answer(refusal, lambda: _paged_compile(mode, pipeline=False))
+    assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("scheme", [
-    _refused("int8", reason=_BLOCK), _refused("int4", reason=_BLOCK),
+@pytest.mark.parametrize("mode,refusal", [
+    _refused(None, message=_SLICE.format(64), why=_SLICE_WHY),
+    _refused("int8", message=_SLICE.format(64), why=_SLICE_WHY),
+    _refused("int4", message=_SLICE.format(32), why=_SLICE_WHY),
 ])
-def test_quantized_matmul_compiles(scheme):
+def test_paged_decode_pipelined_compiles(mode, refusal):
+    text = _answer(refusal, lambda: _paged_compile(mode, pipeline=True))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("scheme,refusal", [
+    _refused("int8", message=_BLOCK, why=_BLOCK_WHY),
+    _refused("int4", message=_BLOCK, why=_BLOCK_WHY),
+])
+def test_quantized_matmul_compiles(scheme, refusal):
     """The dequant-fused decode matmul at m=8 (one token a slot), d=768,
     n=3072 — GPT-2-small's MLP up-projection."""
     from dsml_tpu.ops.quantization import quantize_weight_blocks, quantized_matmul
@@ -170,10 +180,10 @@ def test_quantized_matmul_compiles(scheme):
     qwt = jax.eval_shape(
         lambda w: quantize_weight_blocks(w, scheme, 128), _sds((768, 3072), jnp.float32)
     )
-    text = _compile(
+    text = _answer(refusal, lambda: _compile(
         lambda x, q: quantized_matmul(x, q, interpret=False),
         _sds((8, 768), jnp.bfloat16), qwt,
-    )
+    ))
     assert "tpu_custom_call" in text
 
 
