@@ -293,6 +293,7 @@ class GPT2:
         h = self._hidden_spmd(params, tokens, tp_axis, sp_axis, attn_impl, seq_offset, pp_axis, n_micro)
         return h @ self._unembed_matrix(params).T  # unembedding → [b, s, vocab/tp]
 
+    @jax.named_scope("loss_head")
     def _head_loss_spmd(self, params, h_raw, targets, tp_axis=None):
         """Final norm + tied unembedding + next-token CE for PRE-final-norm
         hidden states ``h_raw`` [b, s, d] → scalar mean loss. The head the
@@ -329,6 +330,7 @@ class GPT2:
         tgt = lax.psum(jnp.where(in_shard[..., None], tgt, 0.0), tp_axis)
         return jnp.mean(lse - tgt)
 
+    @jax.named_scope("embed")
     def _embed_spmd(self, params, tokens, tp_axis=None, sp_axis=None, seq_offset=None):
         """Token + position embedding → [b, s_local, d]. ``wte`` is
         vocab-sharded over tp → masked gather + psum (each token's row lives
@@ -447,12 +449,14 @@ class GPT2:
         whole-block remat at long context) and recomputes just the two
         cheap O(s·d·ff) FFN matmuls. ~half the activation memory of no
         remat for ~a tenth of whole-block remat's recompute FLOPs."""
-        h = h + self._attn_block(layer, h, n_head_local, tp_axis, sp_axis, attn_impl)
+        with jax.named_scope("attn"):
+            h = h + self._attn_block(layer, h, n_head_local, tp_axis, sp_axis, attn_impl)
         sub, key = ((self._moe_block, "moe") if self.config.n_experts
                     else (self._mlp_block, "mlp"))
 
         def ffn(sub_p, ln_p, hh):
-            return sub(sub_p, _layer_norm(hh, **ln_p), tp_axis)
+            with jax.named_scope("mlp"):
+                return sub(sub_p, _layer_norm(hh, **ln_p), tp_axis)
 
         if self.config.remat == "mlp":
             ffn = jax.checkpoint(ffn)

@@ -290,6 +290,7 @@ def _flash_fwd(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, int
             _scratch((block_q, 128)),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v)
     return out, lse
 
@@ -605,6 +606,7 @@ def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, b
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[_scratch((block_q, d))],
         interpret=interpret,
+        name="flash_dq",
     )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, delta, glse8)
 
     krow = [
@@ -636,6 +638,7 @@ def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, b
         ],
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         interpret=interpret,
+        name="flash_dkv",
     )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, delta, glse8)
     return dq, dk, dv
 

@@ -345,8 +345,9 @@ def make_hybrid_train_step(
                     f"grad_accum*dp = {grad_accum}*{n_dp}"
                 )
             loss, grads = explicit_step_grads(params, x, y)
-            updates, opt_state = optimizer.update(grads, opt_state, params, value=loss)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state, params, value=loss)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         return _with_step_watermark(jax.jit(step, donate_argnums=(0, 1)))
@@ -383,8 +384,9 @@ def make_hybrid_train_step(
             (loss, grads), _ = jax.lax.scan(body, (0.0, zero), (xs, ys))
             loss = loss / grad_accum
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params, value=loss)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params, value=loss)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return _with_step_watermark(jax.jit(step, donate_argnums=(0, 1)))

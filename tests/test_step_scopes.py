@@ -1,0 +1,48 @@
+"""The train step names its work: every piece the benchmark times by scope
+(``benchmarks/scope_reduce.py``) is an ``op_name`` path component of some
+instruction of the compiled hybrid step, forward and, where there is one,
+backward (``transpose(``). Compile-time metadata only: nothing runs."""
+
+import functools
+import re
+
+import jax
+import optax
+import pytest
+
+from dsml_tpu.models.gpt2 import GPT2, GPT2Config
+from dsml_tpu.parallel.hybrid import make_hybrid_train_step
+from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
+
+
+@functools.lru_cache(maxsize=None)
+def _op_names(dp: int, dp_sync: str = "xla") -> list[list[str]]:
+    """The op names of the compiled tiny step, each split into its components."""
+    model = GPT2(GPT2Config.tiny())
+    optimizer = optax.adamw(1e-3)
+    mesh = build_mesh(MeshSpec(dp=dp), jax.devices()[:dp])
+    step = make_hybrid_train_step(
+        model, optimizer, mesh, attn_impl="flash", dp_sync=dp_sync)
+    params = jax.eval_shape(lambda: model.init(0))
+    batch = jax.ShapeDtypeStruct((2 * dp, 32), "int32")
+    text = step.lower(params, jax.eval_shape(optimizer.init, params), batch, batch).compile().as_text()
+    return [re.split(r"[/();]", name) for name in set(re.findall(r'op_name="([^"]*)"', text))]
+
+
+def _has(names, scope: str, backward: bool) -> bool:
+    return any(scope in tokens and ("transpose" in tokens[:tokens.index(scope)]) == backward
+               for tokens in names)
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+@pytest.mark.parametrize("scope,backward", [
+    ("embed", False), ("embed", True), ("attn", False), ("attn", True),
+    ("mlp", False), ("mlp", True), ("loss_head", False), ("loss_head", True),
+    ("optimizer", False),
+])
+def test_compiled_step_names_its_work(dp, scope, backward):
+    assert _has(_op_names(dp), scope, backward)
+
+
+def test_optimizer_scope_on_the_explicit_sync_step():
+    assert _has(_op_names(2, dp_sync="ring"), "optimizer", False)

@@ -72,16 +72,36 @@ def test_flash_fwd_compiles():
     assert "tpu_custom_call" in text
 
 
-def test_flash_bwd_compiles():
+@pytest.fixture(scope="module")
+def flash_grad_text():
     from dsml_tpu.ops.flash import flash_attention
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, interpret=False)
         return out.astype(jnp.float32).sum()
 
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), _QKV, _QKV, _QKV)
+    return _compile(jax.grad(loss, argnums=(0, 1, 2)), _QKV, _QKV, _QKV)
+
+
+def test_flash_bwd_compiles(flash_grad_text):
     # forward + dq + dkv kernels
-    assert text.count("tpu_custom_call") >= 3
+    assert flash_grad_text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_flash_kernels_carry_their_names(flash_grad_text, kernel):
+    """``name=`` on each ``pl.pallas_call`` names the instruction and is a path
+    component of its ``op_name``: what ``benchmarks/scope_reduce.py`` tells
+    the three kernels apart by."""
+    calls = re.findall(r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', flash_grad_text)
+    assert calls
+    named = [re.split(r"[/();]", op_name) for _, op_name in calls]
+    assert all(sum(k in tokens for k in ("flash_fwd", "flash_dq", "flash_dkv")) == 1
+               for tokens in named), calls
+    mine = [name for (name, _), tokens in zip(calls, named) if kernel in tokens]
+    # the instruction is transpose_jvp_flash_dq__.1 here, flash_dq.1 under the step's shard_map
+    assert mine and all(kernel in name for name in mine), calls
 
 
 # paged decode at GPT-2-small serving geometry: 8 slots, 12 heads,
