@@ -29,6 +29,20 @@ touches HBM:
 - :func:`flash_block_grads` — the raw one-block backward given MERGED
   (out, lse) statistics; the primitive that re-streaming backward calls.
 
+Dtypes. Every dot takes its operands in the INPUTS' dtype and accumulates in
+float32 (``preferred_element_type``): bf16 ``q``/``k``/``v``/``do`` blocks go
+into the MXU as they lie in HBM, and ``p`` / ``ds`` are cast to that dtype
+immediately before the dots that consume them (``p·v``; ``ds·k``, ``pᵀ·do``,
+``dsᵀ·q``) — the precision every other matmul of a bf16 model has, and what
+plain attention feeds ``p·v`` under the same dtype. float32 inputs keep
+float32 dots. Whatever is a statistic or an accumulator is float32 always:
+the scores ``s``, the running max ``m`` and denominator ``l``, ``lse``,
+``delta − g_lse``, ``exp``, and the ``acc`` / ``dk_acc`` / ``dv_acc``
+scratch. The softmax scale multiplies the [block_q, d] ``q`` block where
+that is exact (a power of two: head 64) and the float32 scores otherwise.
+The dkv kernel works the TRANSPOSED tile (``sᵀ = k·qᵀ``), so no dot has a
+transposed left operand and the row statistics are used lane-major as stored.
+
 Sequences that don't tile into blocks run through a PADDED path: zero-pad
 to a block multiple (≤ 25% waste), mask the padded kv tail inside the
 kernels via a ``kv_stop`` SMEM scalar, slice padded q rows off outputs —
@@ -36,8 +50,9 @@ cp/ring shards make odd residual lengths the common case.
 ``DSML_FLASH_BLOCK`` overrides the swept block defaults (docs/TUNING.md).
 
 Causal blocks entirely above the diagonal are skipped via ``pl.when``
-predication (a dynamic predicate when offsets are traced). On non-TPU
-backends the same kernels run under the Pallas interpreter
+predication (a dynamic predicate when offsets are traced); the forward also
+runs blocks entirely below it, and before ``kv_stop``, with no mask at all.
+On non-TPU backends the same kernels run under the Pallas interpreter
 (``interpret=True``), which is how tests validate them on the CI CPU mesh;
 on TPU they compile through Mosaic.
 
@@ -48,6 +63,7 @@ Used by ``dsml_tpu.models.gpt2`` via ``attn_impl="flash"`` (single-chip) and
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -152,20 +168,17 @@ def _default_blocks(
     s_q: int, s_kv: int, block_q: int | None, block_k: int | None,
     head_dim: int | None = None,
 ) -> tuple[int, int]:
-    """Swept-on-hardware block defaults (scripts/flash_block_sweep.py on a
-    v5e, k_extra=16 differenced timing, HEAD_DIM 64 — the GPT-2 shape): at
-    sequence lengths >= 4096 the 1024x1024 tiling runs the fwd+bwd pair
-    ~1.4x faster than 512x512 (43.7 vs 31.2 TFLOPs at seq 8192 — fewer
-    grid revisits of the dq/dkv accumulators); anything wider than 1024
-    already fails TPU compilation on VMEM at d=64. The 1024 widening is
-    therefore GATED on head_dim <= 64: kernel VMEM scales with
+    """Block defaults (``scripts/flash_block_sweep.py`` on a v5e, head
+    dim 64 — the GPT-2 shape): 1024x1024 at sequence lengths >= 4096 (fewer
+    grid revisits of the dq/dkv accumulators), 512x512 below; anything
+    wider than 1024 fails TPU compilation on VMEM at d=64. The 1024
+    widening is GATED on head_dim <= 64: kernel VMEM scales with
     block x head_dim, so a d=128 model (Llama presets) at the same block
     could exhaust VMEM outright where the 512 default compiles — wider
     heads keep 512x512 until a sweep at that head_dim says otherwise.
-    Below 4096 the 512x512 tiling measured best-or-equal wherever the
-    differenced signal rose above dispatch jitter. Callers can still pin
-    blocks explicitly (the ring path does, per-shard); lengths the
-    preferred block doesn't divide degrade through _pick_block's ladder.
+    Callers can still pin blocks explicitly (the ring path does,
+    per-shard); lengths the preferred block doesn't divide degrade through
+    _pick_block's ladder.
 
     ``DSML_FLASH_BLOCK`` ("B" or "BQxBK") overrides the swept auto defaults
     — the tuning knob for cp-sharded per-rank lengths the sweep never saw —
@@ -183,11 +196,113 @@ def _default_blocks(
     return block_q, block_k
 
 
-def _positions(qs, ks, qi, ki, block_q, block_k):
-    """Global (row, col) position grids for the current (q, kv) block pair."""
-    q_pos = qs + qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos = ks + ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return q_pos, k_pos
+_NT = (((1,), (1,)), ((), ()))  # a·bᵀ: contract the head dim of both
+_NN = (((1,), (0,)), ((), ()))  # a·b
+
+
+def _dot(a, b, dims):
+    """MXU dot on the operands' own dtype (bf16 blocks go in as bf16),
+    accumulated in float32."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scale_folds(scale: float) -> bool:
+    """True when ``scale`` is a power of two (head 64: 0.125): multiplying
+    the [block_q, d] q block by it is then exact in any float dtype, so the
+    scale leaves the [block_q, block_k] score tile. Any other scale (head
+    128) stays on the float32 scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _stat_lanes(block_k: int) -> int:
+    """Lane width of the forward's running statistics. 128 — one value a
+    lane, ``m`` replicated and ``l`` as per-lane partial sums — wherever the
+    score tile is whole 128-lane groups: every per-tile update is then
+    vreg-wise and the one cross-lane op left on a tile is the row max
+    (``l``'s lanes are summed once per q block, in ``_finish``). A narrower
+    tile keeps plain [block_q, 1] columns."""
+    return 128 if block_k % 128 == 0 else 1
+
+
+def _lanes(x, n: int):
+    """[rows, n] out of ``x`` [rows, w] whose lanes all hold the row's
+    value: whole lane groups are sliced or repeated, no cross-lane op."""
+    w = x.shape[1]
+    if n <= w:
+        return x[:, :n]
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _fold_lanes(p, w: int):
+    """Row sums of ``p`` kept as ``w`` per-lane partials: the tile's lane
+    groups added vreg-wise (``w`` = 1: the plain row sum)."""
+    if w == 1:
+        return jnp.sum(p, -1, keepdims=True)
+    out = p[:, :w]
+    for c in range(1, p.shape[1] // w):
+        out = out + p[:, c * w:(c + 1) * w]
+    return out
+
+
+def _row_chunks(block: int) -> list[slice]:
+    """The forward works a tile 512 rows at a time: the rows' softmax
+    chains are independent, and at 1024 rows one chunk's exp and row max
+    overlap the other's dots (measured on a v5e at 1024x1024, PERF.md §6;
+    dq and dkv read the same either way, so they take the tile whole)."""
+    rows = 512 if block % 512 == 0 else block
+    return [slice(r, r + rows) for r in range(0, block, rows)]
+
+
+def _mask(s, q0, k0, kv_stop, causal, mask_kv, q_axis):
+    """Mask the score tile ``s`` whose first query / key sit at global
+    positions ``q0`` / ``k0``; queries lie along ``q_axis`` of ``s`` (0, or
+    1 for the transposed tile of the dkv kernel). A key survives when it is
+    at or before its query (causal) and before ``kv_stop`` (zero-padded kv
+    tail: its columns must not enter the softmax denominator). Positions
+    are one column and one row vector, so the tile itself sees one compare
+    and one select."""
+    def pos(start, axis):
+        shape = [1, 1]
+        shape[axis] = s.shape[axis]
+        return start + jax.lax.broadcasted_iota(jnp.int32, tuple(shape), axis)
+
+    last = None  # the last key position each query may see
+    if causal:
+        last = pos(q0, q_axis)
+    if mask_kv:
+        last = kv_stop - 1 if last is None else jnp.minimum(last, kv_stop - 1)
+    return jnp.where(pos(k0, 1 - q_axis) <= last, s, _NEG_INF)
+
+
+def _seen(q0, k0, block_q):
+    """False for a tile whose every key is in the future of its every query:
+    a causal kernel skips it (a dynamic predicate: offsets are traced)."""
+    return k0 <= q0 + block_q - 1
+
+
+def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k):
+    """Run the forward's ``compute(masked)`` as the tile's place demands.
+    Three classes, told apart by SMEM scalars: every key in the future of
+    every query — skipped; every key at or before every query and before
+    ``kv_stop`` — the body with no mask at all; anything else — the masked
+    body. The backward kernels run the masked body on every tile they do
+    not skip: a second body measured no faster there and every body is
+    traced and lowered once a layer (PERF.md §6)."""
+    if not (causal or mask_kv):
+        compute(False)
+        return
+    clear = True  # nothing in the tile is masked
+    if causal:
+        clear = k0 + block_k - 1 <= q0
+    if mask_kv:
+        clear = jnp.logical_and(clear, k0 + block_k <= kv_stop)
+    crossed = jnp.logical_not(clear)
+    if causal:
+        crossed = jnp.logical_and(crossed, _seen(q0, k0, block_q))
+    pl.when(clear)(lambda: compute(False))
+    pl.when(crossed)(lambda: compute(True))
 
 
 # ---------------------------------------------------------------------------
@@ -204,7 +319,9 @@ def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, 
         qi = pl.program_id(1)
     if ki is None:
         ki = pl.program_id(2)
-    qs, ks = qs_ref[0], ks_ref[0]
+    q0 = qs_ref[0] + qi * block_q
+    k0 = ks_ref[0] + ki * block_k
+    fold = _scale_folds(scale)
 
     @pl.when(ki == 0)
     def _init():
@@ -212,43 +329,29 @@ def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, 
         m_scr[:] = jnp.full_like(m_scr, _MAX_FLOOR)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    def compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if mask_kv or causal:
-            q_pos, k_pos = _positions(qs, ks, qi, ki, block_q, block_k)
-            if mask_kv:
-                # zero-padded kv tail (sequence not a block multiple): its
-                # columns must not enter the softmax denominator
-                s = jnp.where(k_pos < kstop_ref[0], s, _NEG_INF)
-            if causal:
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[:] = jnp.broadcast_to(l_scr[:, :1] * corr + jnp.sum(p, -1, keepdims=True), l_scr.shape)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    def compute(masked):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        if fold:
+            q = q * scale
+        for rows in _row_chunks(block_q):
+            s = _dot(q[rows], k, _NT)
+            if not fold:
+                s = s * scale
+            if masked:
+                s = _mask(s, q0 + rows.start, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
+            m_prev = m_scr[rows]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            l_scr[rows] = l_scr[rows] * corr + _fold_lanes(p, l_scr.shape[1])
+            acc[rows] = acc[rows] * _lanes(corr, acc.shape[1]) + _dot(p.astype(v.dtype), v, _NN)
+            m_scr[rows] = m_new
 
-    if causal:
-        # blocks with every column strictly in the future contribute nothing
-        # (dynamic predicate: offsets are traced values)
-        @pl.when(ks + ki * block_k <= qs + qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    _per_tile_class(compute, q0, k0, kstop_ref[0], causal, mask_kv, block_q, block_k)
 
     @pl.when(ki == kv_blocks - 1)
     def _finish():
-        l_fin = jnp.maximum(l_scr[:, :1], 1e-30)
+        l_fin = jnp.maximum(jnp.sum(l_scr[:], -1, keepdims=True), 1e-30)
         o_ref[0] = (acc[:] / l_fin).astype(o_ref.dtype)
         # lse is stored [bh, 8, seq] — 8 identical sublanes keep the block
         # shape Mosaic-tileable (last two dims (8, block_q))
@@ -286,8 +389,8 @@ def _flash_fwd(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, int
         ],
         scratch_shapes=[
             _scratch((block_q, d)),
-            _scratch((block_q, 128)),
-            _scratch((block_q, 128)),
+            _scratch((block_q, _stat_lanes(block_k))),
+            _scratch((block_q, _stat_lanes(block_k))),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -458,7 +561,7 @@ def flash_stream_hop(
             jax.ShapeDtypeStruct(vsend.shape, vsend.dtype),
         ],
         scratch_shapes=[
-            _scratch((bq, d)), _scratch((bq, 128)), _scratch((bq, 128)),
+            _scratch((bq, d)), _scratch((bq, _stat_lanes(bk))), _scratch((bq, _stat_lanes(bk))),
             pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -479,43 +582,35 @@ def flash_stream_hop(
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, glse_ref, dq_ref, acc, *, scale, causal, block_q, block_k, kv_blocks, mask_kv):
+def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, scale, causal, block_q, block_k, kv_blocks, mask_kv):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    qs, ks = qs_ref[0], ks_ref[0]
+    q0 = qs_ref[0] + qi * block_q
+    k0 = ks_ref[0] + ki * block_k
+    fold = _scale_folds(scale)
 
     @pl.when(ki == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0].reshape(block_q, 1)
-        delta = delta_ref[0, 0].reshape(block_q, 1)
-        glse = glse_ref[0, 0].reshape(block_q, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if mask_kv or causal:
-            q_pos, k_pos = _positions(qs, ks, qi, ki, block_q, block_k)
-            if mask_kv:
-                s = jnp.where(k_pos < kstop_ref[0], s, _NEG_INF)
-            if causal:
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta + glse)  # glse: cotangent of the lse output
-        acc[:] = acc[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        if fold:
+            q = q * scale
+        s = _dot(q, k, _NT)
+        if not fold:
+            s = s * scale
+        if causal or mask_kv:
+            s = _mask(s, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
+        # the row statistics arrive lane-major over seq and are relaid as
+        # columns on every tile: keeping the columns in scratch across the
+        # kv blocks measured slower (PERF.md §6)
+        p = jnp.exp(s - lse_ref[0, 0].reshape(block_q, 1))
+        ds = p * (_dot(do, v, _NT) - dd_ref[0, 0].reshape(block_q, 1))
+        acc[:] = acc[:] + _dot(ds.astype(k.dtype), k, _NN)
 
     if causal:
-        @pl.when(ks + ki * block_k <= qs + qi * block_q + block_q - 1)
-        def _():
-            compute()
+        pl.when(_seen(q0, k0, block_q))(compute)
     else:
         compute()
 
@@ -524,10 +619,12 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, glse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q, block_k, q_blocks, mask_kv):
+def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q, block_k, q_blocks, mask_kv):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    qs, ks = qs_ref[0], ks_ref[0]
+    q0 = qs_ref[0] + qi * block_q
+    k0 = ks_ref[0] + ki * block_k
+    fold = _scale_folds(scale)
 
     @pl.when(qi == 0)
     def _init():
@@ -535,53 +632,45 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0].reshape(block_q, 1)
-        delta = delta_ref[0, 0].reshape(block_q, 1)
-        glse = glse_ref[0, 0].reshape(block_q, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if mask_kv or causal:
-            q_pos, k_pos = _positions(qs, ks, qi, ki, block_q, block_k)
-            if mask_kv:
-                s = jnp.where(k_pos < kstop_ref[0], s, _NEG_INF)
-            if causal:
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)  # [bq, bk]
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta + glse)
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        # the TRANSPOSED tile, [block_k, block_q] (k is the left operand):
+        # dv += pᵀ·do and dk += dsᵀ·q are then plain a·b dots with no
+        # transposed left operand, and the row statistics are used as the
+        # lane-major row vectors they are stored as
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        if fold:
+            q = q * scale  # scales sᵀ here and dk below: dk = scale · dsᵀ·q
+        st = _dot(k, q, _NT)
+        if not fold:
+            st = st * scale
+        if causal or mask_kv:
+            st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0, :1])
+        dv_acc[:] = dv_acc[:] + _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - dd_ref[0, :1])
+        dk_acc[:] = dk_acc[:] + _dot(dst.astype(q.dtype), q, _NN)
 
-    if causal:
-        # q blocks entirely before this kv block see none of it
-        @pl.when(qs + qi * block_q + block_q - 1 >= ks + ki * block_k)
-        def _():
-            compute()
+    if causal:  # q blocks entirely before this kv block see none of it
+        pl.when(_seen(q0, k0, block_q))(compute)
     else:
         compute()
 
     @pl.when(qi == q_blocks - 1)
     def _finish():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dk = dk_acc[:] if fold else dk_acc[:] * scale
+        dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
+def _flash_bwd(q, k, v, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
     bh, s_q, d = q.shape
     s_kv = k.shape[1]
     scale = d**-0.5
     q_blocks, kv_blocks = s_q // block_q, s_kv // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [bh, s_q]
-    delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, s_q))  # sublane-aligned like lse
+    # ds = p · (dp − delta + g_lse): delta = Σ do·o, and g_lse is the
+    # cotangent of the lse output. Both are per query row, so their
+    # difference is taken here once, not on every score tile
+    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1) - glse  # [bh, s_q]
+    dd = jnp.broadcast_to(dd[:, None, :], (bh, 8, s_q))  # sublane-aligned like lse
     qrow = [
         _smem_spec(),
         _smem_spec(),
@@ -590,7 +679,6 @@ def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, b
         _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
         _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
         _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
         _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
         _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
     ]
@@ -607,7 +695,7 @@ def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, b
         scratch_shapes=[_scratch((block_q, d))],
         interpret=interpret,
         name="flash_dq",
-    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, delta, glse8)
+    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, dd)
 
     krow = [
         _smem_spec(),
@@ -617,7 +705,6 @@ def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, b
         _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
         _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
         _vmem_spec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-        _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
         _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
         _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
     ]
@@ -639,7 +726,7 @@ def _flash_bwd(q, k, v, o, lse8, do, glse8, q_start, k_start, kv_stop, causal, b
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         interpret=interpret,
         name="flash_dkv",
-    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, delta, glse8)
+    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, dd)
     return dq, dk, dv
 
 
@@ -662,10 +749,8 @@ def _flash_fwd_rule(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k
 def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, res, g):
     q, k, v, out, lse8, q_start, k_start, kv_stop = res
     g_out, g_lse = g
-    bh, s_q, _ = q.shape
-    glse8 = jnp.broadcast_to(g_lse.astype(jnp.float32)[:, None, :], (bh, 8, s_q))
     dq, dk, dv = _flash_bwd(
-        q, k, v, out, lse8, g_out, glse8, q_start, k_start, kv_stop, causal,
+        q, k, v, out, lse8, g_out, g_lse.astype(jnp.float32), q_start, k_start, kv_stop, causal,
         block_q, block_k, interpret, mask_kv
     )
     return dq, dk, dv, None, None, None
@@ -818,9 +903,8 @@ def flash_block_grads(
         pad3 = ((0, 0), (0, pk - s_kv), (0, 0))
         kf, vf = jnp.pad(kf, pad3), jnp.pad(vf, pad3)
     lse8 = jnp.broadcast_to(lse_f[:, None, :], (b * h, 8, pq))
-    glse8 = jnp.broadcast_to(glse_f[:, None, :], (b * h, 8, pq))
     dq, dk, dv = _flash_bwd(
-        qf, kf, vf, of, lse8, dof, glse8, q_start, k_start, k_start + s_kv,
+        qf, kf, vf, of, lse8, dof, glse_f, q_start, k_start, k_start + s_kv,
         causal, bq, bk, interpret, mask_kv,
     )
     dq = dq[:, :s_q].astype(jnp.float32).reshape(b, h, s_q, d)

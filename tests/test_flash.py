@@ -293,3 +293,108 @@ def test_default_blocks_adapt_to_sequence_lengths():
     assert _default_blocks(8192, 8192, None, None) == (512, 512)
     # explicit blocks are never second-guessed, whatever the head_dim
     assert _default_blocks(8192, 8192, 1024, 1024, 128) == (1024, 1024)
+
+
+# ---------------------------------------------------------------------------
+# bf16 operands, tile classes, statistic layout (PR 26)
+# ---------------------------------------------------------------------------
+
+# One bf16 ulp, relative (8 significant bits) and, for elements near zero,
+# absolute: the outputs and all three gradients are STORED in bf16, which
+# alone is up to half of this; p and ds rounded to bf16 before their dots
+# (2^-9 relative each, averaging over a row's 128+ terms) and the float32
+# accumulation order make up the rest. Largest error measured over the six
+# cases below: 0.54 ulp of the largest element (3.1e-2 at |dv| 7.4).
+_BF16_ULP = 2.0 ** -7
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "seq,d",
+    [
+        (384, 64),  # 3x3 tiles of 128: three skipped, three crossed, three clear; scale 2^-3 folds into q
+        (203, 64),  # odd: padded to 256, kv tail masked (mask_kv); tile (1, 0) clear, (1, 1) crossed twice
+        (384, 32),  # scale 32^-0.5 is no power of two: stays on the float32 scores
+    ],
+)
+def test_flash_bf16_matches_float32_reference(causal, seq, d):
+    """bf16 inputs go into the MXU as bf16 (float32 accumulation, float32
+    statistics): forward and dq/dk/dv against plain attention evaluated in
+    float32 on the same bf16-rounded inputs."""
+    rng = np.random.default_rng(seq + d)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, seq, d)), jnp.bfloat16) for _ in range(3))
+    w = jnp.cos(jnp.arange(d)).astype(jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, block_q=128, block_k=128)
+
+    def reference(q, k, v):
+        return attention(*(t.astype(jnp.float32) for t in (q, k, v)), causal)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
+
+    got = (jax.jit(flash)(q, k, v), *jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v))
+    expected = (reference(q, k, v), *jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v))
+    for g, e in zip(got, expected):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(e, np.float32), rtol=_BF16_ULP, atol=_BF16_ULP
+        )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_tile_classes_equal_masked_body(monkeypatch, dtype):
+    """Traced offsets that put a tile in each class — q rows 256..511
+    against k columns 128..639 in 128-blocks: (0,0) clear, (0,1) crossed,
+    (0,2) and (0,3) skipped, (1,0) and (1,1) clear, (1,2) crossed, (1,3)
+    skipped. The mask is the identity on a clear tile, so out, lse and the
+    gradients must equal the kernels run with the masked body on every
+    tile, and the dense reference."""
+    from dsml_tpu.ops import flash
+
+    rng = np.random.default_rng(26)
+    q = jnp.asarray(rng.standard_normal((1, 2, 256, 64)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, 512, 64)), dtype) for _ in range(2))
+
+    def run():
+        def fn(q, k, v, qs, ks):
+            out, lse = flash.flash_attention_lse(q, k, v, True, qs, ks, block_q=128, block_k=128)
+            return (out.astype(jnp.float32) * jnp.cos(jnp.arange(64.0))).sum() + lse.sum(), (out, lse)
+
+        grads, (out, lse) = jax.jit(jax.grad(fn, argnums=(0, 1, 2), has_aux=True))(
+            q, k, v, jnp.int32(256), jnp.int32(128)
+        )
+        return [np.asarray(t, np.float32) for t in (out, lse, *grads)]
+
+    by_class = run()
+
+    def every_tile_masked(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k):
+        flash.pl.when(flash._seen(q0, k0, block_q))(lambda: compute(True))
+
+    monkeypatch.setattr(flash, "_per_tile_class", every_tile_masked)
+    for got, masked in zip(by_class, run()):
+        np.testing.assert_allclose(got, masked, rtol=1e-6, atol=1e-6)
+
+    qf, kf, vf = (t.astype(jnp.float32) for t in (q, k, v))
+    scores = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * 0.125
+    seen = (256 + jnp.arange(256))[:, None] >= (128 + jnp.arange(512))[None, :]
+    scores = jnp.where(seen, scores, -1e30)
+    tol = 1e-5 if dtype == jnp.float32 else _BF16_ULP
+    np.testing.assert_allclose(
+        by_class[1], np.asarray(jax.scipy.special.logsumexp(scores, axis=-1)), rtol=1e-5, atol=1e-5
+    )
+    np.testing.assert_allclose(
+        by_class[0], np.asarray(jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), vf)),
+        rtol=tol, atol=tol,
+    )
+
+
+def test_flash_forward_row_chunks_of_a_1024_block():
+    """A 1024-row q block is worked 512 rows at a time (the forward only):
+    the second chunk's mask starts 512 rows further down."""
+    q, k, v = _qkv(b=1, h=1, s=1024, d=64, seed=26)
+    got = jax.jit(lambda q, k, v: flash_attention(q, k, v, True, block_q=1024, block_k=512))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(attention(q, k, v, True)), rtol=1e-5, atol=1e-5
+    )
