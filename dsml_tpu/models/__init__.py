@@ -1,4 +1,4 @@
-"""Model families: MLP (MNIST), CNN, ResNet-18 (CIFAR-10), GPT-2, Llama."""
+"""Model families: MLP (MNIST), CNN, ResNet-18 (CIFAR-10), GPT-2, Llama, Jamba."""
 
 from dsml_tpu.models.mlp import MLP  # noqa: F401
 

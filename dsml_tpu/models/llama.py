@@ -260,6 +260,7 @@ class Llama(GPT2):
             )
         return super()._block_closure(tp_axis, sp_axis, attn_impl)
 
+    @jax.named_scope("embed")
     def _embed_spmd(self, params, tokens, tp_axis=None, sp_axis=None, seq_offset=None):
         """Token embedding only — positions enter through RoPE, not a table."""
         if tp_axis:
@@ -270,6 +271,11 @@ class Llama(GPT2):
             safe_ids = jnp.clip(local_ids, 0, vocab_shard - 1)
             return lax.psum(params["wte"][safe_ids] * in_shard[..., None], tp_axis)
         return params["wte"][tokens]
+
+    def _rotate(self, t, positions):
+        """Positions enter here, on q and k (a family without rotary
+        overrides with the identity)."""
+        return _rope(t, positions, self.config.rope_theta)
 
     def _qkv_gqa(self, layer, x, n_head_local, n_kv_local, positions):
         """Separate q/k/v projections, head split, RoPE on q/k. Returns
@@ -286,14 +292,18 @@ class Llama(GPT2):
         q = heads(qmatmul(x, layer["attn"]["wq"], x.dtype), n_head_local)
         k = heads(qmatmul(x, layer["attn"]["wk"], x.dtype), n_kv_local)
         v = heads(qmatmul(x, layer["attn"]["wv"], x.dtype), n_kv_local)
-        q = _rope(q, positions, self.config.rope_theta)
-        k = _rope(k, positions, self.config.rope_theta)
+        q, k = self._rotate(q, positions), self._rotate(k, positions)
         repeat = n_head_local // n_kv_local
         ka = jnp.repeat(k, repeat, axis=1) if repeat > 1 else k
         va = jnp.repeat(v, repeat, axis=1) if repeat > 1 else v
         return q, k, v, ka, va
 
     def _block(self, layer, h, n_head_local, tp_axis, sp_axis, attn_impl):
+        with jax.named_scope("attn"):
+            h = h + self._attn_block(layer, h, n_head_local, tp_axis, sp_axis, attn_impl)
+        return self._ffn(layer, h, tp_axis)
+
+    def _attn_block(self, layer, h, n_head_local, tp_axis, sp_axis, attn_impl):
         cfg = self.config
         n_kv_local = n_head_local * cfg.n_kv_head // cfg.n_head
         s_local = h.shape[1]
@@ -307,9 +317,7 @@ class Llama(GPT2):
         out = qmatmul(self._merge_heads(out), layer["attn"]["wo"], out.dtype)
         if tp_axis:
             out = lax.psum(out, tp_axis)
-        h = h + out
-        h = self._ffn(layer, h, tp_axis)
-        return h
+        return out
 
     def _mlp_block(self, mlp, x, tp_axis):
         mid = jax.nn.silu(qmatmul(x, mlp["w_gate"], x.dtype)) * qmatmul(x, mlp["w_up"], x.dtype)  # [b, s, ff/tp]
@@ -325,7 +333,8 @@ class Llama(GPT2):
                     else (self._mlp_block, "mlp"))
 
         def ffn(sub_p, scale, hh):
-            return sub(sub_p, _rms_norm(hh, scale, self.config.rms_eps), tp_axis)
+            with jax.named_scope("mlp"):
+                return sub(sub_p, _rms_norm(hh, scale, self.config.rms_eps), tp_axis)
 
         if self.config.remat == "mlp":
             # selective remat, same contract as GPT2._block: attention

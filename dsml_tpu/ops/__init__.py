@@ -22,3 +22,4 @@ from dsml_tpu.ops.ring_attention import (  # noqa: F401
     causal_keep_fraction,
     ring_kv_wire_bytes,
 )
+from dsml_tpu.ops.selective_scan import selective_scan  # noqa: F401

@@ -11,14 +11,15 @@ import optax
 import pytest
 
 from dsml_tpu.models.gpt2 import GPT2, GPT2Config
+from dsml_tpu.models.jamba import Jamba, JambaConfig
 from dsml_tpu.parallel.hybrid import make_hybrid_train_step
 from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
 
 
 @functools.lru_cache(maxsize=None)
-def _op_names(dp: int, dp_sync: str = "xla") -> list[list[str]]:
+def _op_names(dp: int, dp_sync: str = "xla", family: str = "gpt2") -> list[list[str]]:
     """The op names of the compiled tiny step, each split into its components."""
-    model = GPT2(GPT2Config.tiny())
+    model = GPT2(GPT2Config.tiny()) if family == "gpt2" else Jamba(JambaConfig.tiny(remat=True))
     optimizer = optax.adamw(1e-3)
     mesh = build_mesh(MeshSpec(dp=dp), jax.devices()[:dp])
     step = make_hybrid_train_step(
@@ -46,3 +47,24 @@ def test_compiled_step_names_its_work(dp, scope, backward):
 
 def test_optimizer_scope_on_the_explicit_sync_step():
     assert _has(_op_names(2, dp_sync="ring"), "optimizer", False)
+
+
+@pytest.mark.parametrize("scope,backward", [
+    # the Mamba mixer and, inside it, the scan kernels by their own names (interpreted here,
+    # so each is the prefix of its body's ops); whole-block remat keeps the forward kernel's
+    # outputs, so neither kernel runs in the other's pass
+    ("ssm", False), ("ssm", True), ("ssm_scan_fwd", False), ("ssm_scan_fwd", None),
+    ("ssm_scan_bwd", True),
+    # what the family shares with the others keeps its names: Llama's blocks, GPT-2's head
+    ("embed", False), ("attn", False), ("attn", True), ("mlp", False), ("mlp", True),
+    ("flash_fwd", False), ("flash_dq", True), ("flash_dkv", True),
+    ("loss_head", False), ("loss_head", True), ("optimizer", False),
+])
+def test_compiled_jamba_step_names_its_work(scope, backward):
+    names = _op_names(1, family="jamba")
+    if backward is None:
+        assert not _has(names, scope, True)
+        return
+    assert _has(names, scope, backward)
+    if scope == "ssm_scan_bwd":
+        assert not _has(names, scope, False)

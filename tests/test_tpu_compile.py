@@ -120,6 +120,35 @@ def test_flash_compiles_at_other_geometries(topo, shape, what):
     assert text.count("tpu_custom_call") >= (1 if what == "fwd" else 3)
 
 
+# the selective-scan pair at Jamba2-3B's Mamba geometry and the cell's length: one row of
+# 8192, 5120 channels (40 lane tiles), state 16 (two float32 sublane tiles), bf16 in and out
+@pytest.fixture(scope="module")
+def scan_grad_text(topo):
+    from dsml_tpu.ops.selective_scan import selective_scan
+
+    def loss(*operands):
+        return selective_scan(*operands, interpret=False).astype(jnp.float32).sum()
+
+    wide, narrow = _sds((1, 8192, 5120), jnp.bfloat16), _sds((1, 8192, 16), jnp.bfloat16)
+    return _compile(topo, jax.grad(loss, argnums=tuple(range(6))), wide, wide,
+                    _sds((5120, 16), jnp.float32), narrow, narrow, _sds((5120,), jnp.float32))
+
+
+@pytest.mark.parametrize("kernel", ["ssm_scan_fwd", "ssm_scan_bwd"])
+def test_selective_scan_kernels_compile_and_carry_their_names(scan_grad_text, kernel):
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', scan_grad_text)
+    assert len(calls) == 2, calls
+    assert sum(kernel in re.split(r"[/();]", op_name) for op_name in calls) == 1, calls
+
+
+def test_selective_scan_holds_no_state_history_in_hbm(scan_grad_text):
+    """No ``[S, E, N]`` array in either direction: nothing the compiled gradient
+    holds has as many elements as one row's state history (8192 x 5120 x 16)."""
+    sizes = [np.prod([int(n) for n in dims.split(",")])
+             for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", scan_grad_text)]
+    assert max(sizes) == 8192 * 5120, max(sizes)
+
+
 # paged decode at GPT-2-small serving geometry: 8 slots, 12 heads,
 # head_dim 64, page 16, 1024 ctx (64 table entries a slot)
 _SLOTS, _HEADS, _HD, _PAGE, _CTX = 8, 12, 64, 16, 1024
