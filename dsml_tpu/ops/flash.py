@@ -10,12 +10,23 @@ touches HBM:
 - forward: blockwise q·kᵀ on the MXU with online-softmax accumulators
   (running row-max, running denominator) held in VMEM scratch across the
   innermost kv-block grid dimension; emits the per-row logsumexp.
-- backward: the standard two-kernel flash split — one pass accumulates dq
-  over kv blocks, a second accumulates dk/dv over q blocks — recomputing
-  p = exp(s − L) from the forward's saved logsumexp rather than storing
-  probabilities. The logsumexp output is differentiable too (its cotangent
-  folds into ds as ``p · g_lse``), which is what lets whole flash calls be
-  COMBINED downstream.
+- backward: ONE kernel (``flash_dkv``) on the grid ``(batch·heads,
+  kv_blocks, q_blocks)``: the score tile is recomputed once, transposed
+  (``sᵀ = k·qᵀ``, ``p = exp(s − L)`` from the forward's saved logsumexp
+  rather than stored probabilities), and feeds five dots: ``sᵀ``, ``dpᵀ``
+  and the three gradients, each accumulated transposed (``dvᵀ += doᵀ·p``,
+  ``dkᵀ += qᵀ·ds``, ``dqᵀ += kᵀ·dsᵀ``: a ``[d, block]`` result fills the
+  MXU's width where ``[block, d]`` leaves half of it idle at head 64).
+  ``dk``/``dv`` accumulate over the inner q axis in block-sized scratch;
+  ``dq`` sums over the OUTER kv axis, so its float32 accumulator is the
+  whole query length of one (batch, head), resident in VMEM across that
+  walk (2 MB at 8192 × 64, 4 MB at 8192 × 128). A query whose resident
+  ``dq`` does not fit beside the tile (``_fused_bwd_vmem`` against
+  ``_VMEM_BUDGET``: a rule on shapes alone, about 50k rows at head 64 in
+  bf16) keeps the two-kernel split, ``flash_dq`` over kv blocks then the
+  same ``flash_dkv`` without its ``dq`` part, seven dots. The logsumexp output is differentiable too (its
+  cotangent folds into ds as ``p · g_lse``), which is what lets whole
+  flash calls be COMBINED downstream.
 - :func:`ring_flash_attention` — sequence-parallel attention where every
   ring hop is one flash call: q/k blocks carry their global position
   offsets (SMEM scalars, so the causal mask is correct for any hop pair),
@@ -32,16 +43,19 @@ touches HBM:
 Dtypes. Every dot takes its operands in the INPUTS' dtype and accumulates in
 float32 (``preferred_element_type``): bf16 ``q``/``k``/``v``/``do`` blocks go
 into the MXU as they lie in HBM, and ``p`` / ``ds`` are cast to that dtype
-immediately before the dots that consume them (``p·v``; ``ds·k``, ``pᵀ·do``,
-``dsᵀ·q``) — the precision every other matmul of a bf16 model has, and what
+immediately before the dots that consume them (``p·v``; ``doᵀ·p``, ``qᵀ·ds``,
+``kᵀ·dsᵀ``) — the precision every other matmul of a bf16 model has, and what
 plain attention feeds ``p·v`` under the same dtype. float32 inputs keep
 float32 dots. Whatever is a statistic or an accumulator is float32 always:
 the scores ``s``, the running max ``m`` and denominator ``l``, ``lse``,
-``delta − g_lse``, ``exp``, and the ``acc`` / ``dk_acc`` / ``dv_acc``
-scratch. The softmax scale multiplies the [block_q, d] ``q`` block where
-that is exact (a power of two: head 64) and the float32 scores otherwise.
-The dkv kernel works the TRANSPOSED tile (``sᵀ = k·qᵀ``), so no dot has a
-transposed left operand and the row statistics are used lane-major as stored.
+``delta − g_lse``, ``exp``, and the ``acc`` / ``dk_acc`` / ``dv_acc`` /
+``dq_acc`` scratch. The softmax scale multiplies the [block_q, d] ``q``
+block where that is exact (a power of two: head 64) and the float32 scores
+otherwise.
+The dkv kernel works the TRANSPOSED tile (``sᵀ = k·qᵀ``), so the row
+statistics are used lane-major as stored; the transposed left operand of a
+gradient dot is a ``[block, d]`` operand block (``do``, ``q``, ``k``), never
+the tile. ``dsᵀ`` is cast to the operand dtype once and feeds both its dots.
 
 Sequences that don't tile into blocks run through a PADDED path: zero-pad
 to a block multiple (≤ 25% waste), mask the padded kv tail inside the
@@ -170,7 +184,8 @@ def _default_blocks(
 ) -> tuple[int, int]:
     """Block defaults (``scripts/flash_block_sweep.py`` on a v5e, head
     dim 64 — the GPT-2 shape): 1024x1024 at sequence lengths >= 4096 (fewer
-    grid revisits of the dq/dkv accumulators), 512x512 below; anything
+    grid steps, each a read-modify-write of the block-sized dk/dv scratch
+    and of one block of the backward's resident dq), 512x512 below; anything
     wider than 1024 fails TPU compilation on VMEM at d=64. The 1024
     widening is GATED on head_dim <= 64: kernel VMEM scales with
     block x head_dim, so a d=128 model (Llama presets) at the same block
@@ -198,6 +213,8 @@ def _default_blocks(
 
 _NT = (((1,), (1,)), ((), ()))  # a·bᵀ: contract the head dim of both
 _NN = (((1,), (0,)), ((), ()))  # a·b
+_TN = (((0,), (0,)), ((), ()))  # aᵀ·b: contract the first axis of both
+_TT = (((0,), (1,)), ((), ()))  # aᵀ·bᵀ: the first axis of a with the last of b
 
 
 def _dot(a, b, dims):
@@ -619,7 +636,31 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q, block_k, q_blocks, mask_kv):
+def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, scale, causal, block_q, block_k, q_blocks, kv_blocks, mask_kv):
+    """``dk`` and ``dv`` of one kv block, accumulated over the q blocks; given
+    a third output and scratch (``dq_ref``, ``dq_acc``) also ``dq``, from the
+    tile it already holds.
+
+    All three accumulate TRANSPOSED, ``[d, block]`` float32: each gradient
+    dot then yields a block-wide result from ``d`` rows where ``[block, d]``
+    would leave the columns past ``d`` of the 128-wide MXU idle (head 64),
+    and what the dot relays is a ``[block, d]`` operand block, never the
+    tile (kernel-only at ``[48, 8192, 64]``: 13.94 ms a call with
+    ``dq += ds·k``, 12.58 with ``dqᵀ += kᵀ·dsᵀ``, 10.82 with ``dk`` and
+    ``dv`` transposed too; PERF.md §6). Each is transposed once, on its way
+    out.
+
+    ``dq`` sums over ``ki``, the OUTER of the two inner grid axes, so its
+    accumulator is the whole query length of one (batch, head),
+    ``[q_blocks, d, block_q]``, resident across that walk: block ``qi`` is
+    zeroed at ``ki == 0`` and written out at the last ``ki``, both outside
+    the ``_seen`` predicate (offsets are traced: a q block may be skipped on
+    every step and still owes its zeros). ``dq_ref`` is the whole query
+    length too, in ``q``'s dtype, and goes to HBM once a (batch, head)."""
+    if len(rest) == 2:
+        (dk_acc, dv_acc), dq_ref, dq_acc = rest, None, None
+    else:
+        dq_ref, dk_acc, dv_acc, dq_acc = rest
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     q0 = qs_ref[0] + qi * block_q
@@ -631,11 +672,16 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    if dq_ref is not None:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
     def compute():
-        # the TRANSPOSED tile, [block_k, block_q] (k is the left operand):
-        # dv += pᵀ·do and dk += dsᵀ·q are then plain a·b dots with no
-        # transposed left operand, and the row statistics are used as the
-        # lane-major row vectors they are stored as
+        # the TRANSPOSED tile, [block_k, block_q] (k is the left operand): the
+        # row statistics are used as the lane-major row vectors they are
+        # stored as, and each gradient dot contracts the tile over the axis
+        # it already has in place
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         if fold:
             q = q * scale  # scales sᵀ here and dk below: dk = scale · dsᵀ·q
@@ -645,9 +691,11 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if causal or mask_kv:
             st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1)
         pt = jnp.exp(st - lse_ref[0, :1])
-        dv_acc[:] = dv_acc[:] + _dot(pt.astype(do.dtype), do, _NN)
-        dst = pt * (_dot(v, do, _NT) - dd_ref[0, :1])
-        dk_acc[:] = dk_acc[:] + _dot(dst.astype(q.dtype), q, _NN)
+        dv_acc[:] = dv_acc[:] + _dot(do, pt.astype(do.dtype), _TT)  # dvᵀ += doᵀ·p
+        dst = (pt * (_dot(v, do, _NT) - dd_ref[0, :1])).astype(q.dtype)  # cast once, feeds both its dots
+        dk_acc[:] = dk_acc[:] + _dot(q, dst, _TT)  # dkᵀ += qᵀ·ds
+        if dq_ref is not None:
+            dq_acc[qi] = dq_acc[qi] + _dot(k, dst, _TN)  # dqᵀ[q block] += kᵀ·dsᵀ
 
     if causal:  # q blocks entirely before this kv block see none of it
         pl.when(_seen(q0, k0, block_q))(compute)
@@ -657,11 +705,47 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     @pl.when(qi == q_blocks - 1)
     def _finish():
         dk = dk_acc[:] if fold else dk_acc[:] * scale
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk.T.astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].T.astype(dv_ref.dtype)
+
+    if dq_ref is not None:
+        @pl.when(ki == kv_blocks - 1)
+        def _finish_dq():
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_ref[0, rows, :] = (dq_acc[qi] * scale).T.astype(dq_ref.dtype)
+
+
+# What the fused backward may ask of VMEM: half of the 128 MiB a v5e (v5p,
+# v6e) core has. Its call sets the scoped limit to its own plan, never below
+# Mosaic's default and never the whole budget: a limit far above the need
+# slows the kernel and what XLA runs around it (kernel-only, ms a call at
+# [48, 8192, 64] / [20, 8192, 128]: 10.77 / 5.96 at 16 MiB, 10.82 / 5.96 at
+# 24-32, 10.86 / 6.59 at 48-64; PERF.md §6). ``_fused_bwd_vmem`` plans above
+# what Mosaic allocates (30 MiB at [8192, 64] and 1024x1024 blocks where it
+# takes 10 to 12; 15 at [8192, 128] and 512x512 where it takes 10 to 12).
+_VMEM_BUDGET = 64 * 2**20
+_VMEM_DEFAULT = 16 * 2**20
+
+
+def _fused_bwd_vmem(s_q: int, d: int, block_q: int, block_k: int, itemsize: int) -> int:
+    """VMEM bytes of the backward with ``dq`` riding the dkv tile: what is
+    resident for a whole (batch, head) (the float32 ``dqᵀ`` and the ``dq``
+    output in the inputs' dtype, double-buffered, lanes padded to 128), the
+    tile's float32 intermediates (``sᵀ``, ``pᵀ``, ``dpᵀ``, ``dsᵀ``) with
+    their casts, and the double-buffered operand and output blocks with the
+    two block accumulators."""
+    lanes = -(-d // 128) * 128
+    resident = (s_q // block_q) * d * max(block_q, 128) * 4 + 2 * s_q * lanes * itemsize
+    tile = block_q * block_k * (4 * 4 + 2 * itemsize)
+    blocks = 2 * (2 * block_q + 4 * block_k) * lanes * itemsize + 2 * block_k * lanes * 4
+    return resident + tile + blocks
 
 
 def _flash_bwd(q, k, v, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
+    """``(dq, dk, dv)`` in the inputs' dtypes. One kernel (``flash_dkv``, ``dq``
+    riding it) wherever the resident ``dq`` of one (batch, head) fits VMEM
+    beside the tile: a rule on the shapes in hand and nothing else. A longer
+    query keeps the pair, ``flash_dq`` then ``flash_dkv``."""
     bh, s_q, d = q.shape
     s_kv = k.shape[1]
     scale = d**-0.5
@@ -671,31 +755,36 @@ def _flash_bwd(q, k, v, o, lse8, do, glse, q_start, k_start, kv_stop, causal, bl
     # difference is taken here once, not on every score tile
     dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1) - glse  # [bh, s_q]
     dd = jnp.broadcast_to(dd[:, None, :], (bh, 8, s_q))  # sublane-aligned like lse
-    qrow = [
-        _smem_spec(),
-        _smem_spec(),
-        _smem_spec(),
-        _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-        _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-        _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
-        _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
-    ]
+    scalars = (_scalar(q_start), _scalar(k_start), _scalar(kv_stop))
+    vmem = _fused_bwd_vmem(s_q, d, block_q, block_k, q.dtype.itemsize)
+    fused = vmem <= _VMEM_BUDGET
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv,
-        ),
-        grid=(bh, q_blocks, kv_blocks),
-        in_specs=qrow,
-        out_specs=_vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[_scratch((block_q, d))],
-        interpret=interpret,
-        name="flash_dq",
-    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, dd)
+    dq = None
+    if not fused:
+        qrow = [
+            _smem_spec(),
+            _smem_spec(),
+            _smem_spec(),
+            _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+            _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+            _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
+            _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
+        ]
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv,
+            ),
+            grid=(bh, q_blocks, kv_blocks),
+            in_specs=qrow,
+            out_specs=_vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[_scratch((block_q, d))],
+            interpret=interpret,
+            name="flash_dq",
+        )(*scalars, q, k, v, do, lse8, dd)
 
     krow = [
         _smem_spec(),
@@ -708,25 +797,35 @@ def _flash_bwd(q, k, v, o, lse8, do, glse, q_start, k_start, kv_stop, causal, bl
         _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
         _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
     ]
-    dk, dv = pl.pallas_call(
+    out_specs = [
+        _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
+        _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
+    ]
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    scratch_shapes = [_scratch((d, block_k)), _scratch((d, block_k))]
+    compiler_params = None
+    if fused:
+        out_specs.append(_vmem_spec((1, s_q, d), lambda b, ki, qi: (b, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch_shapes.append(_scratch((q_blocks, d, block_q)))
+        if not interpret:
+            compiler_params = pltpu.CompilerParams(vmem_limit_bytes=max(vmem, _VMEM_DEFAULT))
+    dk, dv, *riding = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, q_blocks=q_blocks, mask_kv=mask_kv,
+            _dkv_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            q_blocks=q_blocks, kv_blocks=kv_blocks, mask_kv=mask_kv,
         ),
         grid=(bh, kv_blocks, q_blocks),
         in_specs=krow,
-        out_specs=[
-            _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
+        compiler_params=compiler_params,
         interpret=interpret,
         name="flash_dkv",
-    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v, do, lse8, dd)
+    )(*scalars, q, k, v, do, lse8, dd)
+    if fused:
+        (dq,) = riding
     return dq, dk, dv
 
 

@@ -398,3 +398,144 @@ def test_flash_forward_row_chunks_of_a_1024_block():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(attention(q, k, v, True)), rtol=1e-5, atol=1e-5
     )
+
+
+# ---------------------------------------------------------------------------
+# the backward in one kernel: dq rides the dkv tile (PR 28)
+# ---------------------------------------------------------------------------
+
+
+def _bwd_kernels(fn, *args):
+    """Names of the Pallas calls in ``fn``'s jaxpr, in order."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def _block_grads_both_ways(monkeypatch, q, k, v, causal, q_start=0, k_start=0, block=128):
+    """``flash_block_grads`` on the same inputs through the fused kernel (the
+    shape rule's choice at these sizes) and, with no VMEM to give, the pair."""
+    from dsml_tpu.ops import flash
+
+    out, lse = jax.jit(
+        lambda: flash.flash_attention_lse(q, k, v, causal, q_start, k_start, block, block))()
+    do = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape).astype(q.dtype)
+
+    def run(kernels):
+        def grads(qs, ks):  # offsets traced, as the ring passes them; a fresh function, so nothing cached is reused
+            return flash.flash_block_grads(q, k, v, out, lse, do, None, causal, qs, ks, block, block)
+
+        offsets = (jnp.int32(q_start), jnp.int32(k_start))
+        assert _bwd_kernels(grads, *offsets) == kernels
+        return jax.jit(grads)(*offsets)
+
+    fused = run(["flash_dkv"])
+    monkeypatch.setattr(flash, "_VMEM_BUDGET", 0)
+    return fused, run(["flash_dq", "flash_dkv"])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "seq,d",
+    [
+        (384, 64),  # 3x3 tiles of 128: a tile of each class (skipped, crossed, clear)
+        (203, 64),  # odd: padded to 256, the kv tail masked through mask_kv
+        (384, 32),  # the scale does not fold: it stays on the float32 scores and on dk
+        (256, 128),  # head 128 (Jamba, Llama): the accumulators are whole lane groups
+    ],
+)
+def test_fused_backward_equals_the_pair(monkeypatch, dtype, causal, seq, d):
+    """``dq`` riding the dkv tile: ``dk`` and ``dv`` are the same operations
+    in the same order, so equal; ``dq`` sums the same products over the kv
+    blocks in the same order through a dot of the other orientation."""
+    rng = np.random.default_rng(seq + d)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, seq, d)), dtype) for _ in range(3))
+    fused, pair = _block_grads_both_ways(monkeypatch, q, k, v, causal)
+    np.testing.assert_array_equal(np.asarray(fused[1]), np.asarray(pair[1]))
+    np.testing.assert_array_equal(np.asarray(fused[2]), np.asarray(pair[2]))
+    tol = 1e-5 if dtype == jnp.float32 else _BF16_ULP
+    np.testing.assert_allclose(np.asarray(fused[0]), np.asarray(pair[0]), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_backward_q_block_skipped_on_every_kv_step(monkeypatch, dtype):
+    """Traced offsets that leave q block 0 (rows 0..127) before every key
+    (128..383): its tile is skipped on every kv step, so its ``dq`` is what
+    the zeroing at the first kv block and the write-out at the last leave,
+    both outside the ``_seen`` predicate. Block 1 crosses kv block 0 and
+    skips kv block 1; the pair and the dense reference say the same."""
+    rng = np.random.default_rng(28)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)), dtype) for _ in range(3))
+    fused, pair = _block_grads_both_ways(monkeypatch, q, k, v, True, q_start=0, k_start=128)
+    assert not np.asarray(fused[0][:, :, :128]).any()
+    assert np.asarray(fused[0][:, :, 128:]).any()
+    tol = 1e-5 if dtype == jnp.float32 else _BF16_ULP
+    for f, p in zip(fused, pair):
+        np.testing.assert_allclose(np.asarray(f), np.asarray(p), rtol=tol, atol=tol)
+
+    # dense: rows 128..255 see keys 128..(row), the rest see nothing
+    qf, kf, vf = (t.astype(jnp.float32) for t in (q, k, v))
+    do = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape).astype(dtype).astype(jnp.float32)
+    seen = jnp.arange(256)[:, None] >= (128 + jnp.arange(256))[None, :]
+
+    def dense(q, k, v):
+        s = jnp.where(seen, jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.125, -1e30)
+        p = jnp.where(seen, jax.nn.softmax(s, -1), 0.0)
+        return (jnp.einsum("bhqk,bhkd->bhqd", p, v) * do).sum()
+
+    tol = 1e-4 if dtype == jnp.float32 else 4 * _BF16_ULP  # out and lse were rounded to bf16 first
+    for f, e in zip(fused, jax.grad(dense, argnums=(0, 1, 2))(qf, kf, vf)):
+        np.testing.assert_allclose(np.asarray(f), np.asarray(e), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("s_q,d,block,itemsize,fused", [
+    (8192, 64, 1024, 2, True),  # gpt2s-8k
+    (1024, 64, 512, 2, True),  # gpt2s-1k, gpt2l-1k
+    (8192, 128, 512, 2, True),  # jamba2-3b-8k
+    (8192, 64, 1024, 4, True),
+    (32768, 64, 1024, 2, True),
+    (65536, 64, 1024, 2, False),  # 16 MiB of dqᵀ and 32 of double-buffered bf16 dq beside the tile: the pair
+    (131072, 128, 512, 2, False),
+])
+def test_fused_backward_shape_rule(s_q, d, block, itemsize, fused):
+    """The choice is a function of the shapes in hand against the VMEM budget."""
+    from dsml_tpu.ops import flash
+
+    need = flash._fused_bwd_vmem(s_q, d, block, block, itemsize)
+    assert need >= s_q * d * 4  # never less than the resident float32 dq itself
+    assert (need <= flash._VMEM_BUDGET) == fused
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_fused_backward_through_the_vjp_matches_attention(causal):
+    """The custom VJP end to end at 2x2 blocks with a cotangent on ``lse``
+    too (``g_lse`` folds into ``dd`` before either path sees it)."""
+    from dsml_tpu.ops.flash import flash_attention_lse
+
+    q, k, v = _qkv(b=1, h=2, s=256, d=64, seed=28)
+    w = jnp.cos(jnp.arange(64.0))
+
+    def flash_loss(q, k, v):
+        out, lse = flash_attention_lse(q, k, v, causal, block_q=128, block_k=128)
+        return (out * w).sum() + (lse * jnp.sin(jnp.arange(256.0))).sum()
+
+    def dense_loss(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.125
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((256, 256), bool)), s, -1e30)
+        out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+        return (out * w).sum() + (jax.scipy.special.logsumexp(s, -1) * jnp.sin(jnp.arange(256.0))).sum()
+
+    assert _bwd_kernels(jax.grad(flash_loss, argnums=(0, 1, 2)), q, k, v) == ["flash_fwd", "flash_dkv"]
+    for g, e in zip(jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2)))(q, k, v),
+                    jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-4)

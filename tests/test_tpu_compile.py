@@ -80,31 +80,64 @@ def flash_grad_text(topo):
 
 
 def test_flash_bwd_compiles(flash_grad_text):
-    # forward + dq + dkv kernels
-    assert flash_grad_text.count("tpu_custom_call") >= 3
+    # forward + the one backward kernel (dq rides the dkv tile)
+    assert flash_grad_text.count("tpu_custom_call") == 2
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
-def test_flash_kernels_carry_their_names(flash_grad_text, kernel):
+def _kernel_calls(text):
+    """(instruction name, op_name components) of every Mosaic call in ``text``."""
+    calls = re.findall(r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', text)
+    return [(name, re.split(r"[/();]", op_name)) for name, op_name in calls]
+
+
+_FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def _flash_kernels_in(text):
+    """Which flash kernel each Mosaic call of ``text`` is, in program order."""
+    return [k for _, tokens in _kernel_calls(text) for k in _FLASH_KERNELS if k in tokens]
+
+
+@pytest.fixture(scope="module")
+def flash_pair_grad_text(topo):
+    """The backward of ONE (batch, head) at a query length whose resident
+    ``dq`` (131072 rows: 32 MiB of float32 and 64 of double-buffered bf16)
+    the shape rule refuses: the pair, ``flash_dq`` then ``flash_dkv``."""
+    from dsml_tpu.ops.flash import flash_block_grads
+
+    def grads(q, k, v, out, lse, do):
+        return flash_block_grads(q, k, v, out, lse, do, causal=True, interpret=False)
+
+    q, kv = _sds((1, 1, 131072, 64), jnp.bfloat16), _sds((1, 1, 1024, 64), jnp.bfloat16)
+    return _compile(topo, grads, q, kv, kv, q, _sds((1, 1, 131072), jnp.float32), q)
+
+
+@pytest.mark.parametrize("kernel", _FLASH_KERNELS)
+def test_flash_kernels_carry_their_names(flash_grad_text, flash_pair_grad_text, kernel):
     """``name=`` on each ``pl.pallas_call`` names the instruction and is a path
     component of its ``op_name``: what ``benchmarks/scope_reduce.py`` tells
-    the three kernels apart by."""
-    calls = re.findall(r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
-                       r'op_name="([^"]*)"', flash_grad_text)
+    the kernels apart by. The gradient at the cells' lengths is two calls,
+    ``flash_fwd`` and ``flash_dkv``; ``flash_dq`` is held to the program
+    where the shape rule keeps the pair."""
+    text = flash_pair_grad_text if kernel == "flash_dq" else flash_grad_text
+    calls = _kernel_calls(text)
     assert calls
-    named = [re.split(r"[/();]", op_name) for _, op_name in calls]
-    assert all(sum(k in tokens for k in ("flash_fwd", "flash_dq", "flash_dkv")) == 1
-               for tokens in named), calls
-    mine = [name for (name, _), tokens in zip(calls, named) if kernel in tokens]
-    # the instruction is transpose_jvp_flash_dq__.1 here, flash_dq.1 under the step's shard_map
+    assert all(sum(k in tokens for k in _FLASH_KERNELS) == 1 for _, tokens in calls), calls
+    mine = [name for name, tokens in calls if kernel in tokens]
+    # the instruction is transpose_jvp_flash_dkv__.1 here, flash_dkv.1 under the step's shard_map
     assert mine and all(kernel in name for name in mine), calls
+    if kernel == "flash_dq":
+        assert sorted(_flash_kernels_in(text)) == ["flash_dkv", "flash_dq"]
 
 
-# what no benchmark cell or 1k case above holds the compiler to: the 8k geometry
-# of `gpt2s-8k` (1024x1024 blocks: bf16 operands, the transposed dkv tile, the
-# forward's row chunks and the VMEM plan at the widest tile) and a head of 128
-# (Llama presets; the scale stays on the scores, 512x512 blocks)
-@pytest.mark.parametrize("shape", [(4, 12, 8192, 64), (2, 8, 1024, 128)], ids=["8k-head64", "1k-head128"])
+# what no 1k case above holds the compiler to: the 8k geometry of `gpt2s-8k`
+# (1024x1024 blocks: bf16 operands, the transposed tile with the dq dot's
+# relayout, the forward's row chunks, the VMEM plan at the widest tile beside
+# 2 MB of resident dq), a head of 128 (Llama presets; the scale stays on the
+# scores, 512x512 blocks) and Jamba's 8k at head 128 (4 MB resident)
+@pytest.mark.parametrize("shape", [(4, 12, 8192, 64), (2, 8, 1024, 128), (1, 20, 8192, 128)],
+                         ids=["8k-head64", "1k-head128", "8k-head128"])
 @pytest.mark.parametrize("what", ["fwd", "grad"])
 def test_flash_compiles_at_other_geometries(topo, shape, what):
     from dsml_tpu.ops.flash import flash_attention
@@ -117,7 +150,8 @@ def test_flash_compiles_at_other_geometries(topo, shape, what):
 
     qkv = _sds(shape, jnp.bfloat16)
     text = _compile(topo, fwd if what == "fwd" else jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
-    assert text.count("tpu_custom_call") >= (1 if what == "fwd" else 3)
+    # the gradient is exactly two Mosaic calls, and no flash_dq
+    assert _flash_kernels_in(text) == (["flash_fwd"] if what == "fwd" else ["flash_fwd", "flash_dkv"])
 
 
 # the selective-scan pair at Jamba2-3B's Mamba geometry and the cell's length: one row of
