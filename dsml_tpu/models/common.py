@@ -20,9 +20,10 @@ def transformer_train_flops(cfg, n_tokens: int, seq: int,
     """Analytic matmul FLOPs for ONE training step over ``n_tokens`` tokens
     at sequence length ``seq`` — the PaLM-appendix accounting (fwd matmuls
     + causal attention term; bwd = 2×fwd; remat recompute NOT counted).
-    This is the single FLOP numerator behind every MFU the bench and
-    ``obs.step_stats`` report, kept here so model families cannot drift
-    apart in their accounting.
+    This is the FLOP numerator behind every MFU ``obs.step_stats``
+    reports, kept here so model families cannot drift apart in their
+    accounting (``benchmarks/tests/test_flops.py`` holds the benchmark's
+    ``flops.py::train_flops`` equal to it).
 
     ``cfg`` needs ``n_layer / n_head / d_model / d_ff / vocab_size``;
     GQA shrinks the k/v projections via ``n_kv_head`` when present.

@@ -283,8 +283,8 @@ def observe_recovery_ms(stage: str, ms: float,
     """One elastic-recovery latency sample →
     ``controller_recovery_ms{stage}`` (stages: ``reconfigure`` /
     ``checkpoint_fallback`` / ``grow_keep`` / ``grow_replay``) — the
-    distribution behind the chaos bench's recovery p50/p99
-    (``bench.py --section chaos``, docs/ELASTIC.md)."""
+    distribution behind the chaos report's recovery p50/p99
+    (``python -m dsml_tpu.runtime.chaos``, docs/ELASTIC.md)."""
     reg = registry if registry is not None else get_registry()
     if not reg.enabled:
         return
@@ -301,7 +301,7 @@ def observe_collective_latency_ms(algorithm: str, ms: float,
     """One measured collective latency sample →
     ``collective_latency_ms{algorithm,axis}`` (the EQuARX-style
     per-algorithm accounting surface; ``utils.tracing.ring_latency_ms``
-    and ``bench.py --section obs`` feed it)."""
+    feeds it)."""
     reg = registry if registry is not None else get_registry()
     if not reg.enabled:
         return

@@ -883,9 +883,8 @@ def run_cluster_demo(out_dir: str, n_devices: int = 2,
     device-server SUBPROCESSES, one all-reduce over the wire, then scrape
     every process over the ObsPlane and write the merged exposition +
     stitched trace + report into ``out_dir``. Returns the report with the
-    artifact paths attached. Used by CI and ``bench.py --section
-    cluster``'s round-trip row; the acceptance test drives the same
-    function."""
+    artifact paths attached. Used by CI (``python -m dsml_tpu.obs.cluster
+    --demo``); the acceptance test drives the same function."""
     import subprocess
     import sys
 

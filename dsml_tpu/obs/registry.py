@@ -11,7 +11,8 @@ JSONL and Prometheus-text exposition (``docs/OBSERVABILITY.md``).
 Zero-overhead-by-default contract: the registry starts DISABLED unless
 ``DSML_OBS`` is set truthy; every write op early-returns on a single
 attribute check, so instrumented hot paths cost one branch when off
-(``bench.py --section obs`` guards the <1% bar). Enabling is a runtime
+(``tests/test_obs.py::test_disabled_registry_is_noop`` holds the no-op;
+its cost against a step is not measured on a chip). Enabling is a runtime
 switch (:func:`enable`) — no re-wiring, the same metric objects go live.
 
 Histograms use FIXED bucket bounds (cumulative, Prometheus-style) plus a

@@ -1,7 +1,8 @@
 """Perf-regression gate over a history of ``BENCH_r*.json`` bench records.
 
 "Did this change make the benches worse?" as a machine-checkable answer
-over a driver's captures of ``bench.py`` output:
+over a driver's captures of the pre-chip harness's output (removed in
+PR 29; nothing in the tree writes such records now, ROADMAP Design 13):
 
 - :func:`extract_metrics` — best-effort metric extraction from every
   record shape a capture can take: full bench records with ``parsed``

@@ -248,9 +248,8 @@ class SpanTracer:
 
         Request spans ride a lean class-based path (one lock hold for
         B + flow, no flight-recorder write — the serving layer records
-        its own admit/retire/requeue flight events): the per-request
-        tracing bill is budgeted at < 1% of a decode tick and
-        ``bench.py --section request_tracing`` enforces it."""
+        its own admit/retire/requeue flight events). The per-request
+        tracing bill against a decode tick is not measured on a chip."""
         if flow is not None and flow not in self._FLOW_PH:
             # validate eagerly (like :meth:`flow`): __enter__ only looks
             # the phase up when obs is ENABLED, so a call-site typo would

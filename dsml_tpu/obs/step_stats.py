@@ -7,18 +7,17 @@ reference never had:
   ``forward_backward`` / ``grad_sync`` / ``optimizer`` /
   ``checkpoint_stall`` (the canonical phases; arbitrary names accepted).
   In a FUSED jitted step the middle three are one program — the trainer
-  records ``step_dispatch`` + ``loss_sync`` instead, and the phased
-  decomposition lives in ``bench.py --section obs``, where each phase is
-  its own fenced program and the components must sum to within 5% of the
-  measured wall (the acceptance bar).
+  records ``step_dispatch`` + ``loss_sync`` instead. How much of a step
+  its fenced phases cover on a chip is not measured.
 - :class:`GoodputTracker` — productive step time ÷ wall time across
   preemption/restore events (the Google "goodput" metric): every second
   spent re-doing work after a restore, blocked on a checkpoint, or idle
   between epochs shows up as the gap between the two.
 - :func:`mfu` — achieved model FLOP/s ÷ the chip's peak, with the FLOP
   numerators computed analytically by ``models.common``
-  (``transformer_train_flops`` / ``mlp_train_flops`` — the same
-  accounting ``bench.py`` reports).
+  (``transformer_train_flops`` / ``mlp_train_flops``;
+  ``benchmarks/tests/test_flops.py`` holds ``benchmarks/flops.py`` equal
+  to the first).
 
 Everything here is clock arithmetic — no jax imports, safe in any
 process. ``clock=`` is injectable for deterministic tests.
@@ -96,8 +95,7 @@ class StepBreakdown:
     def summary(self) -> dict:
         """Per-phase totals/means plus ``coverage_pct`` — how much of the
         measured step wall the recorded phases account for (100% means the
-        breakdown explains the whole step; the bench obs section requires
-        >= 95%)."""
+        breakdown explains the whole step)."""
         with self._lock:
             phases = {
                 name: {

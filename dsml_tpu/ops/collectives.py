@@ -233,8 +233,8 @@ def ring_wire_bytes(
     2(n−1) hops × one segment of the (padded) payload each, at ``itemsize``
     bytes per element. The bidirectional ring moves the same total volume
     (two half-payloads, half the bytes per direction). The fp32 baseline
-    the quantized schedules' ``*_wire_reduction`` bench rows divide by
-    (their counterpart is ``ops.quantization.quantized_ring_wire_bytes``);
+    the quantized schedules' wire-byte reduction divides by (their
+    counterpart is ``ops.quantization.quantized_ring_wire_bytes``);
     static shapes ⇒ exact, not sampled."""
     if n_ranks <= 1:
         return 0
@@ -513,13 +513,9 @@ def ppermute_ring(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
 
 
 @functools.lru_cache(maxsize=None)
-def _stacked_all_reduce_fn(
-    mesh: Mesh, axis_name: str, op: ReduceOp, algorithm: str, repeats: int = 1
-):
-    # Keyed per (mesh, axis, op, algorithm, repeats); jax.jit itself
-    # specializes per input shape/dtype and retains those executables.
-    # ``repeats`` chains the collective back-to-back inside ONE program —
-    # bench.py uses it to difference away per-dispatch overhead.
+def _stacked_all_reduce_fn(mesh: Mesh, axis_name: str, op: ReduceOp, algorithm: str):
+    # Keyed per (mesh, axis, op, algorithm); jax.jit itself specializes
+    # per input shape/dtype and retains those executables.
     spec = P(axis_name)
 
     @functools.partial(
@@ -532,10 +528,7 @@ def _stacked_all_reduce_fn(
         jax.shard_map, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )
     def fn(stacked):  # stacked: [1, ...] per-device shard
-        x = stacked[0]
-        for _ in range(repeats):
-            x = all_reduce(x, axis_name, op, algorithm)
-        return x[None]
+        return all_reduce(stacked[0], axis_name, op, algorithm)[None]
 
     return fn
 

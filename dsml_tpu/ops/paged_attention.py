@@ -13,8 +13,7 @@ kernel walks the page table directly instead:
   ``table[b, t]`` and Pallas DMAs exactly that physical page's rows into
   VMEM for grid step ``(b, kv_head, t)`` — the dense view is never
   materialized, and HBM traffic is proportional to the pages the table
-  actually names (:func:`paged_hbm_bytes` is the analytic accounting
-  the bench's A/B table uses).
+  actually names (:func:`paged_hbm_bytes` is the analytic accounting).
 - **In-kernel dequantize.** int4 pages unpack their nibbles (the shared
   ``pack_int4`` layout: channel halves contiguous) and both int4/int8 fold
   the per-row scales from ``quantize_kv_rows`` exactly where the XLA path
@@ -515,8 +514,9 @@ def paged_hbm_bytes(
     """Analytic HBM bytes ONE layer's paged-attention read costs per
     decode tick — counted from the program structure, not sampled (the
     ``collectives.ring_wire_bytes`` contract), with the scratch-page
-    term charged at its worst case. The bench's A/B table and the
-    contract test's scales-with-live-work assertion both read this.
+    term charged at its worst case.
+    ``tests/test_paged_attention.py::test_paged_hbm_bytes_scales_with_live_pages``
+    reads this.
 
     Every quantized page moves its PAYLOAD and its SCALES: the kernel
     DMAs the per-row f32 scale columns (``k_s``/``v_s``, 4 bytes per K

@@ -358,7 +358,7 @@ def dequantize_kv_rows(values: jax.Array, scales: jax.Array,
 def kv_row_bytes(head_dim: int, mode: str | None) -> int:
     """HBM bytes one K or V row (one position, one head) costs under
     ``mode`` (None = f32), scale included — the analytic accounting the
-    paged-KV capacity bench and docs/TUNING.md sizing rules use."""
+    paged-KV capacity tests and docs/TUNING.md sizing rules use."""
     if mode is None:
         return 4 * head_dim
     if mode == "int8":
@@ -421,7 +421,7 @@ class QuantizedWeight:
     @property
     def dense_bytes(self) -> int:
         """What the SAME weight would cost dense at its original dtype —
-        the compression-ratio denominator the bench row reports."""
+        the compression-ratio denominator."""
         import numpy as np
 
         n = 1
@@ -660,8 +660,9 @@ def quantize_roundtrip(flat: jax.Array, scheme="int8") -> jax.Array:
     error-feedback residual is ``flat − quantize_roundtrip(flat)``: the
     dominant, locally-attributable term of the ring's compression error
     (later hops re-quantize *mixed* partial sums, which no single rank can
-    account — the residual is a first-order correction, and the bench
-    parity rows are what pin that it suffices)."""
+    account — the residual is a first-order correction, and the loss
+    trajectories of ``tests/test_bucketing.py`` are what pin that it
+    suffices)."""
     sch = get_scheme(scheme)
     flat = flat.astype(jnp.float32).reshape(-1)
     size = flat.shape[0]
@@ -678,8 +679,8 @@ def quantized_ring_wire_bytes(
     """Analytic per-rank wire bytes of one quantized ring all-reduce:
     2(n−1) hops, each shipping one padded segment's packed values + f32
     block scales. The counterpart fp32 number is
-    ``ops.collectives.ring_wire_bytes`` — their ratio is the bench grid's
-    ``*_wire_reduction`` row (static shapes ⇒ exact, not sampled)."""
+    ``ops.collectives.ring_wire_bytes`` — their ratio is the wire-byte
+    reduction (static shapes ⇒ exact, not sampled)."""
     sch = get_scheme(scheme)
     if n_ranks <= 1:
         return 0

@@ -42,10 +42,9 @@ Mechanics:
   (``trainer.py`` rides them in the manifest) and f32 regardless of the
   gradient dtype, so a bf16 run's correction isn't itself truncated.
 
-Default bucket size: 4 MiB, overridable via ``DSML_BUCKET_MB`` (the
-``bench.py`` bucket-size sweep on the virtual-8 mesh is what the default is
-chosen from — see docs/TUNING.md; the quantized grid rides
-``bench.py --section quant_sweep``).
+Default bucket size: 4 MiB, overridable via ``DSML_BUCKET_MB`` (chosen
+from a sweep on the virtual-8 CPU mesh, not measured on a chip — see
+docs/TUNING.md and ROADMAP Speed 3).
 """
 
 from __future__ import annotations
@@ -110,11 +109,11 @@ def _resolve_quant(algorithm: str, dtype) -> str:
 
 
 def default_bucket_mb() -> float:
-    """The bucket-size default: 4 MiB (chosen from the bench sweep — see
+    """The bucket-size default: 4 MiB (chosen from a CPU sweep — see
     docs/TUNING.md), overridable via ``DSML_BUCKET_MB`` (malformed or
-    non-positive values fall back, same policy as bench.py's env knobs —
-    a size must be positive; "no bucketing" is ``bucket_size_mb=None`` at
-    the call site, not an env value)."""
+    non-positive values fall back — a size must be positive; "no
+    bucketing" is ``bucket_size_mb=None`` at the call site, not an env
+    value)."""
     try:
         mb = float(os.environ.get("DSML_BUCKET_MB", 4.0))
     except ValueError:

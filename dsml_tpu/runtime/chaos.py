@@ -437,7 +437,7 @@ def run_chaos_serving_fleet(router, prompts, max_new: int,
 
 
 # ---------------------------------------------------------------------------
-# smoke: the end-to-end guarantee as an executable check (CI + bench)
+# smoke: the end-to-end guarantee as an executable check (CI)
 # ---------------------------------------------------------------------------
 
 # documented goodput floor for THIS harness (virtual-8 CPU mesh, tiny
@@ -891,7 +891,7 @@ def run_migration_smoke(tmp_dir: str | None = None, reps: int = 1) -> dict:
       ZERO silent corruption (corrupt bytes never land).
 
     ``reps`` repeats the clean migration + fallback timing pair for the
-    bench's recovery-split percentiles. ``verify_migration`` raises the
+    report's recovery-split percentiles. ``verify_migration`` raises the
     violations; the CLI exits nonzero on any."""
     import json
     import shutil

@@ -37,8 +37,8 @@ Recovery time and lost work land in the obs registry
 a 3am preemption leaves a story, not a mystery. The guarantee is TESTED,
 not asserted: ``runtime.chaos`` drives scripted and seeded-random
 kill/restore schedules against this loop (and against the serving
-``DecodeFleet`` below) — see ``docs/ELASTIC.md`` and ``bench.py
---section chaos``.
+``DecodeFleet`` below) — see ``docs/ELASTIC.md`` and ``python -m
+dsml_tpu.runtime.chaos``.
 
 On a single host, device loss is simulated by meshes shrinking between
 steps (the model multi-host JAX presents when a host drops) — the same

@@ -26,8 +26,8 @@ The interference problem this removes: one batcher interleaves prefill
 chunks with decode quanta, so a burst of long prompts inflates every
 in-flight request's per-token latency. Splitting the roles keeps decode
 ticks pure decode — the burst lands on the prefill pool (the
-Gemma-on-TPU disaggregation result; ``bench.py --section serving_fleet``
-measures the isolation A/B at equal chip count).
+Gemma-on-TPU disaggregation result; the isolation A/B at equal chip
+count is not measured on a chip).
 """
 
 from dsml_tpu.serving.batcher import ContinuousBatcher, QueueFull, Request

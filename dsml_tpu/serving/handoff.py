@@ -17,7 +17,7 @@ Three transports, cheapest first:
   handoff (:class:`HandoffIntegrityError`) before any byte reaches a
   cache. :func:`frame_transport` round-trips a handoff through this codec
   with validation on — the in-process stand-in for a wire hop that tests
-  and the bench use to pin bit-identity THROUGH the framing.
+  use to pin bit-identity THROUGH the framing.
 - **Hardened P2P streams** (:func:`register_with_donor` /
   :func:`fetch_from_migrator`): cross-host handoff rides the SAME
   machinery as elastic shard migration — the prefill host registers the

@@ -159,7 +159,7 @@ class Router:
         self.tpot_ewma_s: float | None = None
         self.decode_wait_ewma_s: float | None = None
         # raw per-request samples (ttft_s, tpot_s or None, e2e_s) for
-        # offline percentiles — the bench/SLO-report path; cleared by
+        # offline percentiles — the SLO-report path; cleared by
         # :meth:`reset_latency_stats`. BOUNDED (maxlen deque): a
         # long-lived fleet must not grow host memory one tuple per
         # lifetime request — overflow is counted, never silent
@@ -176,7 +176,7 @@ class Router:
         # trace context per in-flight request; stage marks (monotonic
         # seconds) split TTFT into queue/prefill/handoff/first-decode;
         # request_records is the bounded retired-request ledger the chaos
-        # verdicts and the tail-attribution bench read
+        # verdicts and the tail attribution read
         self._trace: dict[int, TraceContext] = {}
         self._stage_marks: dict[int, dict] = {}
         self._retries: dict[int, int] = {}

@@ -186,8 +186,8 @@ class Trainer:
         # Observability (docs/OBSERVABILITY.md): when the registry is
         # enabled, the loop records a per-step breakdown (data /
         # step_dispatch / loss_sync / checkpoint_stall — the fused jitted
-        # step is one program, so fwd-bwd/sync/opt split lives in
-        # `bench.py --section obs`) and goodput = productive step time ÷
+        # step is one program, so there is no fwd-bwd/sync/opt split
+        # here) and goodput = productive step time ÷
         # wall across resume/checkpoint events. Disabled: one boolean per
         # step, nothing recorded.
         # Failure forensics (docs/OBSERVABILITY.md § Failure forensics), all

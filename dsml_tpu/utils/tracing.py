@@ -15,8 +15,8 @@ pairs around the naive all-reduce (SURVEY.md §5.1). Here:
 - :func:`ring_latency_ms` — the BASELINE.md headline: p50 latency of the
   2(n-1)-step ring all-reduce at a given payload size, timed as ONE device
   program (no host staging in the loop). Samples feed
-  ``collective_latency_ms{algorithm=...}`` — the same per-algorithm
-  accounting surface ``bench.py --section obs`` populates.
+  ``collective_latency_ms{algorithm=...}``, the per-algorithm accounting
+  surface.
 """
 
 from __future__ import annotations

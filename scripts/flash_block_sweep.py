@@ -30,7 +30,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # run as a script 
 
 def time_config(bh: int, seq: int, d: int, block_q: int | None, block_k: int | None, reps: int,
                 k_extra: int = 16) -> dict:
-    """Differenced in-program-scan timing — the bench.py methodology: each
+    """Differenced in-program-scan timing: each
     measurement runs a k-iteration lax.scan inside one jit and the
     (k+1)-vs-1 difference cancels the per-dispatch overhead."""
     from jax import lax
@@ -84,7 +84,7 @@ def time_config(bh: int, seq: int, d: int, block_q: int | None, block_k: int | N
 
     # analytic causal attention FLOPs: fwd = 2 ops/MAC x 2 dots (qk, pv)
     # x bh x seq^2/2 (causal) x d; bwd approximately 2x fwd by the standard
-    # convention (same convention as bench.py so the numbers compare)
+    # convention (benchmarks/flops.py::attention_train_flops counts the same)
     fwd_flops = 2 * 2 * bh * (seq * seq // 2) * d
     return {
         "block_q": block_q,

@@ -12,7 +12,7 @@ import os
 
 # Unit tests run on a virtual 8-device CPU mesh, pinned both through the
 # environment and through jax.config before any backend initializes; chip
-# runs are chip_smoke.py / bench.py / examples, not pytest.
+# runs are chip_smoke.py / benchmarks/run.py / examples, not pytest.
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -24,7 +24,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 # no persistent compile cache in the test process: the examples' ``main()``
-# and ``bench.main()`` place one (``utils.platform.configure_compile_cache``),
+# and ``chip_smoke.py`` place one (``utils.platform.configure_compile_cache``),
 # and a compile for a described TPU (test_tpu_compile.py) cannot be read back
 # without the chip. Subprocesses a test starts keep their own.
 jax.config.update("jax_enable_compilation_cache", False)
@@ -60,10 +60,11 @@ def dp_mesh8(devices8):
 
 
 # ---------------------------------------------------------------------------
-# synthetic bench-history records (obs.regress extraction + gate tests)
+# synthetic history records in the shapes the removed pre-chip harness
+# left (obs.regress extraction + gate tests; ROADMAP Design 13)
 # ---------------------------------------------------------------------------
 
-_CMD = "python bench.py"
+_CMD = "python bench.py"  # history: the ``cmd`` those records carried; obs.regress never reads it
 
 
 def _full_record(n: int, scale: float = 1.0) -> dict:

@@ -311,9 +311,8 @@ def test_load_text_corpus_explicit_path(tmp_path):
 
 def test_lm_learns_real_text():
     """Loss drops on the real-prose corpus through lm_window_batches — the
-    quality-claim path the bench's gpt2_realtext row reports (a 40-step
-    miniature of it). Pinned to the built fallback corpus (independent of
-    any user data/corpus.txt drop-in)."""
+    quality-claim path, in a 40-step miniature. Pinned to the built
+    fallback corpus (independent of any user data/corpus.txt drop-in)."""
     import jax
     import optax
 

@@ -347,12 +347,12 @@ def _rel_dev(got, ref):
 
 @_init_seeds
 def test_dp_step_quant_ring_matches_xla_trajectory(devices8, seed):
-    """The wired dp frontend at q8_ring, and at q8_ring2 with error
-    feedback, tracks the fp32 XLA-sync loss trajectory within int8
-    quantization noise (the ISSUE 9 parity bar, pinned cheaply here; the
-    bench quant_sweep section carries the measured grid)."""
+    """The wired dp frontend at q8_ring with and without error feedback,
+    and at q8_ring2 with it, tracks the fp32 XLA-sync loss trajectory
+    within int8 quantization noise (the ISSUE 9 parity bar)."""
     ref = _dp_trajectory("xla", False, seed)
-    for algorithm, ef_on in (("q8_ring", False), ("q8_ring2", True)):
+    for algorithm, ef_on in (("q8_ring", False), ("q8_ring", True),
+                             ("q8_ring2", True)):
         got = _dp_trajectory(algorithm, ef_on, seed)
         assert _rel_dev(got, ref) < _INT8_BOUND, (algorithm, ef_on, got, ref)
 

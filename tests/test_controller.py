@@ -76,8 +76,8 @@ def test_scripted_chaos_bit_identical_zero_lost_steps(devices8, tmp_path):
     the run completes every step, the replay grow-back erases the outage
     from the lineage, and the final params are bit-identical to an
     uninterrupted run of the same 24 steps on the same full mesh. Recovery
-    p50/p99 are computable from the report (the bench chaos section's
-    surface)."""
+    p50/p99 are computable from the report (what ``python -m
+    dsml_tpu.runtime.chaos --report`` writes)."""
     report = chaos.run_smoke(n_steps=24, seeds=(), serving=False,
                              tmp_dir=str(tmp_path))
     assert chaos.verify(report) == []
@@ -331,8 +331,11 @@ def test_live_coordinator_failure_feed_drives_recovery(devices8, tmp_path):
     # the controller's mesh actually contains
     handles = serve_local_devices(2, base_device_id=0, mem_size=0x4000)
     rt = CoordinatorRuntime(CoordinatorConfig(
-        health_interval_s=0.1, probe_timeout_s=0.5,
-        dial_retries=2, dial_backoff_s=0.05,
+        # the default 2 s probe timeout: under a loaded six-worker run a
+        # 0.5 s probe of a LIVE server timed out and the health loop failed
+        # device 0 before the test killed device 1 (a stopped server refuses
+        # the connection at once, so detection is no slower)
+        health_interval_s=0.1, dial_retries=2, dial_backoff_s=0.05,
     ))
     model, cfg = _model()
     provider = _batches(cfg, 12)

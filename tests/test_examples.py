@@ -32,7 +32,7 @@ def test_gpt2_example_trains_and_loss_drops():
 def test_gpt2_example_adafactor_remat_trains():
     """The XL-on-one-chip recipe's ingredients (adafactor factored state +
     remat) compose with the hybrid step and actually train — the same flag
-    path the bench's gpt2_xl row and the README recipe use, at toy scale."""
+    path the README recipe uses, at toy scale."""
     import train_gpt2
 
     result = train_gpt2.main(

@@ -2,8 +2,9 @@
 
 Runs on the CI CPU mesh via the Pallas interpreter (``interpret=True`` is
 the default off-TPU); on TPU the same kernels compile through Mosaic —
-bench/examples exercise that path. Forward AND the custom-VJP backward
-(dq/dk/dv flash kernels) must agree with ``attention`` to float32 tolerance.
+``tests/test_tpu_compile.py`` asks the compiler, ``benchmarks/`` runs them.
+Forward AND the custom-VJP backward (dq/dk/dv flash kernels) must agree
+with ``attention`` to float32 tolerance.
 """
 
 import jax

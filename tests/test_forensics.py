@@ -601,11 +601,18 @@ def test_trainer_nan_halt_leaves_complete_postmortem(tmp_path, monkeypatch):
     obs.enable()
     try:
         data = synthetic_classification(1280, features=16, classes=4, seed=1)
-        data.train_x[:] = np.nan  # the injected NaN
+        cfg = TrainConfig(epochs=1, batch_size=16, lr=0.05, sync_every=32)
+        # the injected NaN: every row the loader serves after the first sync
+        # window (epoch 1 is shuffled from ``seed + 1``), so the sync at step
+        # 32 reads a finite loss and the next one, a window later, trips.
+        # One window is the default 32 steps: 64 collective-bearing steps
+        # queued unsynced on the 8-device CPU mesh abort in XLA's in-process
+        # rendezvous when the host is loaded (ROADMAP Design 14)
+        order = np.arange(len(data.train_x))
+        np.random.default_rng(cfg.seed + 1).shuffle(order)
+        data.train_x[order[cfg.sync_every * cfg.batch_size:]] = np.nan
         model = MLP(sizes=(16, 32, 4))
-        trainer = Trainer(model, TrainConfig(
-            epochs=1, batch_size=16, lr=0.05, sync_every=64,
-        ))
+        trainer = Trainer(model, cfg)
         with pytest.raises(SentinelTripped) as e:
             trainer.train(data)
         bundle = e.value.bundle
@@ -615,7 +622,10 @@ def test_trainer_nan_halt_leaves_complete_postmortem(tmp_path, monkeypatch):
         assert len(events) >= 50, f"only {len(events)} trailing events"
         kinds = {ev["kind"] for ev in events}
         assert {"train_start", "step", "loss_sync", "sentinel_trip"} <= kinds
-        # the trip saw the NaN at the sync point
+        # the sync before the injection was clean; the trip saw the NaN at
+        # the next sync point, within one window of the first bad step
+        syncs = {ev["step"]: ev["loss"] for ev in events if ev["kind"] == "loss_sync"}
+        assert np.isfinite(syncs[32]) and not np.isfinite(syncs[64])
         trip = [ev for ev in events if ev["kind"] == "sentinel_trip"][-1]
         assert trip["step"] == 64
 

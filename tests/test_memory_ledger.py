@@ -211,6 +211,64 @@ def test_failed_poll_is_retried_not_cached(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the hybrid step's wiring: claims at init, one watermark a step
+# ---------------------------------------------------------------------------
+
+
+def test_hybrid_init_claims_and_step_watermarks(devices8):
+    """``init_hybrid`` claims params and optimizer state at what one device
+    holds (dp only: every leaf replicated, so the hand count is plain shape
+    arithmetic), and the wrapped step lands one source-stamped watermark a
+    call."""
+    import math
+
+    import optax
+
+    from dsml_tpu.models.gpt2 import GPT2, GPT2Config
+    from dsml_tpu.parallel.hybrid import init_hybrid, make_hybrid_train_step
+    from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    def hand_count(tree):
+        return sum(math.prod(l.shape) * np.dtype(l.dtype).itemsize
+                   for l in jax.tree.leaves(tree) if hasattr(l, "shape"))
+
+    reg = obs.get_registry()
+    was = reg.enabled
+    reg.enable()
+    led = obs_memory.get_memory_ledger()
+    led.clear()
+    try:
+        cfg = GPT2Config.tiny()
+        model = GPT2(cfg)
+        optimizer = optax.adam(1e-3)
+        mesh = build_mesh(MeshSpec(dp=8), devices8)
+        params, opt_state = init_hybrid(model, optimizer, mesh)
+        claims = led.claimed()
+        assert claims["params"]["hybrid"] == hand_count(params) > 0
+        assert claims["optimizer"]["hybrid"] == hand_count(opt_state)
+        # adam's m and v double the parameter bytes (plus its count)
+        assert claims["optimizer"]["hybrid"] >= 2 * claims["params"]["hybrid"]
+
+        step = make_hybrid_train_step(model, optimizer, mesh)
+        rng = np.random.default_rng(0)
+        x = rng.integers(0, cfg.vocab_size, (8, cfg.max_seq)).astype(np.int32)
+        y = np.roll(x, -1, 1).astype(np.int32)
+        for _ in range(3):
+            params, opt_state, loss = step(params, opt_state, x, y)
+        assert np.isfinite(float(loss))
+        marks = led.watermarks()
+        assert len(marks) == 3
+        assert marks[-1]["peak_bytes"] > 0
+        # CPU devices report no memory_stats(): provenance says so
+        assert marks[-1]["source"] == "claimed"
+    finally:
+        led.clear()
+        if not was:
+            reg.disable()
+            reg.reset()
+
+
+# ---------------------------------------------------------------------------
 # disabled-mode no-op contract
 # ---------------------------------------------------------------------------
 

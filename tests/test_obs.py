@@ -225,25 +225,36 @@ def test_span_disabled_records_nothing():
 # ---------------------------------------------------------------------------
 
 
-def test_step_breakdown_coverage():
+@pytest.mark.parametrize("phases, tail, coverage", [
+    # three timed phases and an untimed tail
+    ({"data": 1.0, "forward_backward": 6.0, "optimizer": 2.0}, 1.0, 90.0),
+    # the five canonical phases, each fenced: only the step's own
+    # bookkeeping is left outside them
+    ({"data": 1.0, "forward_backward": 5.0, "grad_sync": 2.0,
+      "optimizer": 1.0, "checkpoint_stall": 0.75}, 0.25, 97.5),
+], ids=["three_phases_untimed_tail", "five_canonical_phases"])
+def test_step_breakdown_coverage(phases, tail, coverage):
+    from dsml_tpu.obs.step_stats import STEP_PHASES
+
     clock = FakeClock()
     reg = Registry(enabled=True)
     bd = StepBreakdown(registry=reg, clock=clock)
     for _ in range(3):
         with bd.step():
-            with bd.phase("data"):
-                clock.advance(1.0)
-            with bd.phase("forward_backward"):
-                clock.advance(6.0)
-            with bd.phase("optimizer"):
-                clock.advance(2.0)
-            clock.advance(1.0)  # untimed tail
+            for name, seconds in phases.items():
+                with bd.phase(name):
+                    clock.advance(seconds)
+            clock.advance(tail)
     s = bd.summary()
     assert s["steps"] == 3
-    assert s["phases"]["forward_backward"]["total_s"] == pytest.approx(18.0)
+    assert set(s["phases"]) == set(phases) <= set(STEP_PHASES)
+    assert s["phases"]["forward_backward"]["total_s"] == pytest.approx(
+        3 * phases["forward_backward"])
     assert s["phases"]["data"]["mean_ms"] == pytest.approx(1000.0)
     assert s["step_wall_s"] == pytest.approx(30.0)
-    assert s["coverage_pct"] == pytest.approx(90.0)
+    assert s["coverage_pct"] == pytest.approx(coverage)
+    if len(phases) == len(STEP_PHASES):
+        assert tuple(s["phases"]) == STEP_PHASES  # the canonical names, in order
 
 
 class FakeClock:
@@ -314,10 +325,9 @@ def test_mfu():
     assert mfu(45e12, 0) is None
 
 
-def test_transformer_flops_match_bench_accounting():
-    """models.common.transformer_train_flops IS the bench's analytic count
-    (the inline formulas bench.py used before this subsystem), for both
-    the GPT-2 and the GQA/SwiGLU (Llama) forms."""
+def test_transformer_flops_match_hand_count():
+    """models.common.transformer_train_flops against the count written out
+    by hand, for both the GPT-2 and the GQA/SwiGLU (Llama) forms."""
     from dsml_tpu.models.common import mlp_train_flops, transformer_train_flops
     from dsml_tpu.models.gpt2 import GPT2Config
     from dsml_tpu.models.llama import LlamaConfig
@@ -490,6 +500,43 @@ def test_ring_latency_routes_per_algorithm(mesh8):
     finally:
         if not was:
             reg.disable()
+
+
+@pytest.mark.parametrize("algorithm", ["ring", "ring2", "naive", "q8"])
+def test_collective_latency_histogram_per_algorithm(mesh8, algorithm):
+    """Every explicit algorithm of the bucketed sync runs and lands in its
+    own ``collective_latency_ms`` series, whose exposed buckets are
+    cumulative and end in a ``+Inf`` bucket equal to the sample count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from dsml_tpu import obs
+    from dsml_tpu.ops.collectives import ReduceOp
+    from dsml_tpu.parallel.bucketing import bucketed_all_reduce
+    from dsml_tpu.utils.tracing import time_jitted
+
+    rng = np.random.default_rng(0)
+    tree = {f"w{i}": jnp.asarray(rng.standard_normal(4096), jnp.float32)
+            for i in range(4)}
+    fn = jax.jit(jax.shard_map(
+        lambda t: bucketed_all_reduce(t, "dev", ReduceOp.AVG, algorithm, 0.02),
+        mesh=mesh8, in_specs=P(), out_specs=P(), check_vma=False,
+    ))
+    reg = Registry(enabled=True)
+    samples = time_jitted(fn, tree, iters=6)["samples_ms"]
+    for ms in samples:
+        obs.observe_collective_latency_ms(
+            algorithm, ms, payload_bytes=4 * 4096 * 4, axis="dev", registry=reg)
+    hist = reg.histogram("collective_latency_ms", labels=("algorithm", "axis"))
+    summary = hist.summary(algorithm=algorithm, axis="dev")
+    assert summary["count"] == len(samples) > 0
+    assert summary["p90"] >= summary["p50"]
+    (rec,) = [r for r in reg.collect() if r["name"] == "collective_latency_ms"]
+    assert rec["labels"] == {"algorithm": algorithm, "axis": "dev"}
+    counts = list(rec["buckets"].values())
+    assert counts == sorted(counts)
+    assert rec["buckets"]["+Inf"] == summary["count"]
 
 
 # ---------------------------------------------------------------------------

@@ -261,6 +261,43 @@ def test_paged_capacity_backpressure_and_reuse(setup):
         paged.submit(_prompts(cfg, [100], seed=7)[0], 20)
 
 
+def test_paged_pool_holds_4x_dense_concurrency_at_equal_hbm(setup):
+    """At the dense f32 cache's HBM budget the int4 page pool buys >= 4x
+    the rows (analytic), and a paged batcher sized to that budget really
+    holds >= 4x the dense batcher's sequences in flight, with greedy
+    tokens identical to the dense batcher at the same codec. Counts of
+    slots and bytes, not timings."""
+    cfg, model, params = setup
+    m4 = GPT2(dataclasses.replace(cfg, kv_quant="int4"))
+    hd = cfg.d_model // cfg.n_head
+    n_dense, page_size = 2, 8
+    per_row = cfg.n_layer * 2 * cfg.n_head
+    budget = n_dense * per_row * cfg.max_seq * kv_row_bytes(hd, None)
+    n_pages = budget // (per_row * page_size * kv_row_bytes(hd, "int4"))
+    assert n_pages * page_size / (n_dense * cfg.max_seq) >= 4.0
+
+    prompts = _prompts(cfg, [10, 33, 21, 15, 38, 12, 27, 19, 30, 11, 24, 36],
+                       seed=12)
+
+    def drain(batcher):
+        rids = [batcher.submit(p, 24) for p in prompts]
+        peak = 0
+        while (batcher.n_queued or batcher.n_active or batcher.n_pending):
+            batcher.step()
+            peak = max(peak, batcher.n_active)
+        out = batcher.collect()
+        return [out[r] for r in rids], peak
+
+    want, dense_peak = drain(ContinuousBatcher(
+        m4, params, n_slots=n_dense, prefill_chunk=32))
+    got, paged_peak = drain(ContinuousBatcher(
+        model, params, n_slots=4 * n_dense, prefill_chunk=32,
+        paged_kv="int4", page_size=page_size, n_pages=int(n_pages)))
+    assert got == want
+    assert dense_peak == n_dense
+    assert paged_peak >= 4 * dense_peak
+
+
 def test_never_fits_accounts_for_registry_pages(setup):
     """The never-fits checks subtract the prefix registry's permanent
     holdings (the code-review livelock: a pool mostly eaten by
@@ -900,8 +937,7 @@ def test_tp2_paged_fleet_matches_monolithic(setup, devices8):
 def test_tp2_paged_capacity_ratio_per_chip(setup):
     """The ≥4× capacity story survives TP: at the dense f32 cache's
     per-chip HBM budget, the int4 page pool's per-chip rows (heads/tp of
-    every page) hold ≥4× the sequences — the analytic accounting the
-    bench's tp=2 leg measures."""
+    every page) hold ≥4× the sequences (analytic accounting)."""
     cfg, model, params = setup
     hd = cfg.d_model // cfg.n_head
     tp = 2

@@ -804,7 +804,7 @@ def test_prefix_cache_small_default():
 
 @pytest.mark.slow
 def test_llama_kvquant_turbo_composition_matches_generate():
-    """The exact composition the bench's serving_llama_kvquant row runs:
+    """One composition end to end:
     Llama family + GQA + int8 KV cache + turbo escalation — tokens equal
     standalone generate and turbo genuinely engages."""
     import dataclasses

@@ -47,7 +47,7 @@ def test_mnist_reaches_reference_accuracy(dp_mesh8):
     """MNIST parity: the reference hit 92.89% after 10 epochs on the full
     60k train set (BASELINE.md). The mirror lacks that blob, so this trains
     on the augmented t10k split — 3 epochs must already clear 85%, and the
-    full-budget run is exercised by bench/examples."""
+    full-budget run is ``examples/train_mnist.py``."""
     data = load_mnist()
     model = MLP()  # 784-128-64-10, the documented architecture
     trainer = Trainer(model, TrainConfig(epochs=3, batch_size=64, lr=0.1, optimizer="momentum"), mesh=dp_mesh8)
