@@ -63,9 +63,10 @@ class GPT2Config:
     # compressed remat (ops.quantization.compressed_checkpoint): the stash is
     # blockwise-int8, 4x smaller again, gradients exact in expectation
     remat: bool | str = False
-    # unsharded-vocab losses stream the unembedding in chunks of this many
-    # rows (ops/xent.py) instead of materializing [tokens, vocab] logits;
-    # only kicks in when vocab_size > xent_chunk (0 disables)
+    # unsharded-vocab losses above this vocabulary sweep the tokens in blocks
+    # (ops/xent.py, which sizes the blocks from the shapes) instead of
+    # materializing [tokens, vocab] logits: a threshold and nothing else
+    # (vocab_size > xent_chunk; 0 = off, the dense head)
     xent_chunk: int = 8192
     # interleaved virtual pipeline stages (Megatron PTD-P): each pp rank
     # holds this many non-contiguous layer chunks; >1 shrinks the pipeline
@@ -304,11 +305,11 @@ class GPT2:
         tp_size = lax.axis_size(tp_axis) if tp_axis else 1
         if tp_size == 1:
             if cfg.xent_chunk and cfg.vocab_size > cfg.xent_chunk:
-                # big unsharded vocab: stream the unembedding — [tokens,
+                # big unsharded vocab: sweep the tokens in blocks — [tokens,
                 # vocab] logits never exist (ops/xent.py)
                 from dsml_tpu.ops.xent import chunked_softmax_xent
 
-                return chunked_softmax_xent(h, self._unembed_matrix(params), targets, cfg.xent_chunk)
+                return chunked_softmax_xent(h, self._unembed_matrix(params), targets)
             logits = (h @ self._unembed_matrix(params).T).astype(jnp.float32)
             logp = jax.nn.log_softmax(logits)
             nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)

@@ -67,7 +67,7 @@ class JambaConfig:
     rms_eps: float = 1e-6
     dtype: str = "float32"
     remat: bool = False     # True recomputes each block in the backward, but for `_KEPT`
-    xent_chunk: int = 8192
+    xent_chunk: int = 8192  # the blocked head's vocabulary threshold, 0 = dense (`GPT2Config.xent_chunk`)
     n_experts: int = 0      # `Llama._ffn` reads it: one plain gated MLP a layer
 
     def is_attention(self, layer: int) -> bool:

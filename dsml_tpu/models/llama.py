@@ -67,7 +67,7 @@ class LlamaConfig:
     expert_top_k: int = 2
     capacity_factor: float = 1.25
     remat: bool | str = False
-    xent_chunk: int = 8192
+    xent_chunk: int = 8192  # the blocked head's vocabulary threshold, 0 = dense (GPT2Config.xent_chunk)
     pp_interleave: int = 1
     # int8 KV cache with per-position scales (see GPT2Config.kv_quant) —
     # stacks with the GQA cache's kv-heads-only memory win

@@ -1,131 +1,131 @@
-"""Chunked softmax cross-entropy — full logits never materialize.
+"""Blocked softmax cross-entropy — full logits never materialize, and the
+gradients are taken in the forward.
 
 For a tied-embedding LM the loss ``mean(logsumexp(h·Wᵀ) − h·W[target])``
 normally materializes [batch·seq, vocab] float32 logits (GPT-2-small at
 batch 8 × seq 1024 × vocab 50257 is ~1.6 GB — often the single largest
-tensor of the step). This computes the same value by scanning the vocab in
-chunks with an online logsumexp, so peak memory is [N, chunk]:
+tensor of the step). This computes the same value by sweeping the TOKENS in
+blocks of ``rows`` over the whole vocabulary, so peak memory is [rows, V]
+and a block's log-sum-exp is complete when its logits are:
 
-- forward: running (row-max, sum-exp) across chunks + the target logit
-  (each target row lives in exactly one chunk);
-- backward (custom VJP): per chunk, recompute ``p = exp(h·Wcᵀ − lse)``,
-  subtract the one-hot target, and accumulate ``dh += p·Wc`` and
-  ``dWc = pᵀ·h`` — the textbook softmax-CE gradient, chunk by chunk.
+- one block: ``logits = h_blk·Wᵀ``, row max, exp, row sum, target logit →
+  the block's share of the mean loss;
+- under differentiation (custom VJP) the same sweep goes on with the
+  textbook softmax-CE gradient, ``ds = (softmax − onehot) / N``,
+  ``dh_blk = ds·W`` and ``dW += dsᵀ·h_blk``: three vocabulary-wide matmuls
+  a block and no second pass; the backward only scales by the cotangent.
 
-This is the single-shard counterpart of the TP path's distributed-logsumexp
-loss (``models/gpt2.py::loss_spmd``), which splits vocab across chips
-instead of across time. Used automatically by GPT-2 when the vocab is
-unsharded and large.
+``rows`` comes from the shapes alone (:func:`block_rows`). This is the
+single-shard counterpart of the TP path's distributed-logsumexp loss
+(``models/gpt2.py::loss_spmd``), which splits vocab across chips instead of
+across time. Used automatically by GPT-2 when the vocab is unsharded and large.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["chunked_softmax_xent"]
+__all__ = ["chunked_softmax_xent", "block_rows"]
+
+# what the head's float32 working set may take: one block's [rows, V] logits and,
+# where there are several blocks, the [V, d] dW they sum into. Blocks cost traffic
+# (each reads and writes that dW) and bytes cost the step's peak, which the head
+# stands on. Sized on the chip (scripts/xent_head_check.py --sweep) and by the
+# cells' compiles: GPT-2's vocabulary takes 4,096 rows at d 768 (786 + 147 MiB,
+# under the [N, 8192] pair a vocabulary scan held at 32,768 tokens)
+_HEAD_BYTES = 1 << 30
 
 
-def _pad_vocab(wte: jax.Array, chunk: int):
+def block_rows(n: int, v: int, d: int) -> tuple[int, int]:
+    """``(n_blocks, rows)`` for ``n`` tokens of width ``d`` over a vocabulary of
+    ``v``: the fewest blocks whose working set fits ``_HEAD_BYTES`` (the logits
+    get an eighth of it at least), rows a multiple of 8 (the
+    ``n_blocks * rows - n`` padding rows weigh nothing)."""
+    if 4 * n * v <= _HEAD_BYTES:
+        return 1, n
+    n_blocks = -(-4 * n * v // max(_HEAD_BYTES - 4 * v * d, _HEAD_BYTES // 8))
+    return n_blocks, -(-n // (8 * n_blocks)) * 8
+
+
+def _vary_alike(*xs):
+    """``xs``, each marked varying over every mesh axis any of them varies
+    over (identity on values, and outside a ``shard_map`` that tracks them):
+    a scan's carry and a custom VJP's cotangents must match their inputs' types,
+    and the transpose of the mark is the ``psum`` a replicated ``wte`` is owed."""
+    axes = frozenset().union(*(jax.typeof(x).vma for x in xs))
+    return [lax.pcast(x, tuple(missing), to="varying") if (missing := axes - jax.typeof(x).vma) else x
+            for x in xs]
+
+
+def _sweep(h, wte, targets, rows, grads):
+    """Mean loss over the ``n`` rows of ``h`` and, with ``grads``, its
+    gradients ``dh`` [n, d] and ``dW`` [V, d] in the dtypes of ``h`` and
+    ``wte`` (``dW`` summed over the blocks in float32); else ``None``."""
+    n, d = h.shape
     v = wte.shape[0]
-    n_chunks = -(-v // chunk)
-    padded = n_chunks * chunk
-    if padded != v:
-        wte = jnp.pad(wte, ((0, padded - v), (0, 0)))
-    return wte, n_chunks, v
+    n_blocks = -(-n // rows)
+    pad = n_blocks * rows - n
+    weight = jnp.pad(jnp.full((n,), 1.0 / n, jnp.float32), (0, pad))
+    blocks = (
+        jnp.pad(h, ((0, pad), (0, 0))).reshape(n_blocks, rows, d),
+        jnp.pad(targets, (0, pad)).reshape(n_blocks, rows),
+        weight.reshape(n_blocks, rows),
+    )
+
+    def body(carry, block):
+        loss, dw = carry
+        h_b, t_b, w_b = block
+        h32 = h_b.astype(jnp.float32)
+        # wte is cast where it is used, so no whole-vocab f32 copy outlives a matmul
+        logits = h32 @ wte.astype(jnp.float32).T  # [rows, V]
+        m = logits.max(axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+        tgt = jnp.take_along_axis(logits, t_b[:, None], 1)[:, 0]
+        loss = loss + jnp.sum((lse - tgt) * w_b)
+        if not grads:
+            return (loss, dw), None
+        onehot = jnp.arange(v)[None, :] == t_b[:, None]
+        ds = (jnp.exp(logits - lse[:, None]) - onehot) * w_b[:, None]  # [rows, V]
+        return (loss, dw + ds.T @ h32), (ds @ wte.astype(jnp.float32)).astype(h.dtype)
+
+    loss0, dw0 = _vary_alike(h, jnp.zeros((), jnp.float32), jnp.zeros((v, d), jnp.float32))[1:]
+    (loss, dw), dh = lax.scan(body, (loss0, dw0 if grads else None), blocks)
+    # dW is cast here, not in the backward: the float32 [V, d] must not outlive the sweep
+    return (loss, dh.reshape(n_blocks * rows, d)[:n], dw.astype(wte.dtype)) if grads else (loss, None, None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_xent(h: jax.Array, wte: jax.Array, targets: jax.Array, chunk: int):
-    """Per-row loss ``lse − tgt_logit``. h [N, d] (any float dtype — promoted
-    to f32 for the reductions), wte [V, d], targets [N] int32 → [N] f32."""
-    loss, _ = _forward(h, wte, targets, chunk)
-    return loss
+def _blocked_xent(h: jax.Array, wte: jax.Array, targets: jax.Array, rows: int):
+    """Mean of ``lse − tgt_logit``. h [N, d] (any float dtype — promoted to
+    f32 for the reductions), wte [V, d], targets [N] int32 → scalar f32."""
+    return _sweep(h, wte, targets, rows, grads=False)[0]
 
 
-def _forward(h, wte, targets, chunk):
-    n = h.shape[0]
-    h32 = h.astype(jnp.float32)
-    wte_p, n_chunks, v = _pad_vocab(wte, chunk)
-    # keep the scanned weights in their stored dtype; cast per chunk inside
-    # the body so only [chunk, d] ever exists in f32 (a whole-vocab f32 copy
-    # would cost more than the logits this module avoids)
-    w_chunks = wte_p.reshape(n_chunks, chunk, -1)
-
-    def body(carry, inputs):
-        m, s, tgt = carry
-        w_c, c_idx = inputs
-        logits = h32 @ w_c.astype(jnp.float32).T  # [N, chunk]
-        col = c_idx * chunk + jnp.arange(chunk)
-        logits = jnp.where(col[None, :] < v, logits, -jnp.inf)  # mask vocab padding
-        m_new = jnp.maximum(m, logits.max(axis=-1))
-        s = s * jnp.exp(m - m_new) + jnp.sum(jnp.exp(logits - m_new[:, None]), axis=-1)
-        local = targets - c_idx * chunk
-        in_c = (local >= 0) & (local < chunk)
-        safe = jnp.clip(local, 0, chunk - 1)
-        tgt = tgt + jnp.where(in_c, jnp.take_along_axis(logits, safe[:, None], 1)[:, 0], 0.0)
-        return (m_new, s, tgt), None
-
-    m0 = jnp.full((n,), -jnp.inf, jnp.float32)
-    s0 = jnp.zeros((n,), jnp.float32)
-    t0 = jnp.zeros((n,), jnp.float32)
-    (m, s, tgt), _ = lax.scan(body, (m0, s0, t0), (w_chunks, jnp.arange(n_chunks)))
-    lse = m + jnp.log(s)
-    return lse - tgt, lse
+def _fwd_rule(h, wte, targets, rows):
+    loss, dh, dw = _sweep(h, wte, targets, rows, grads=True)
+    return loss, (dh, dw)
 
 
-def _fwd_rule(h, wte, targets, chunk):
-    loss, lse = _forward(h, wte, targets, chunk)
-    return loss, (h, wte, targets, lse)
+def _bwd_rule(rows, res, g):  # g: scalar cotangent of the mean loss
+    dh, dw = res
+    return (g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None
 
 
-def _bwd_rule(chunk, res, g):  # g: [N] cotangent of the per-row loss
-    h, wte, targets, lse = res
-    h32 = h.astype(jnp.float32)
-    wte_p, n_chunks, v = _pad_vocab(wte, chunk)
-    w_chunks = wte_p.reshape(n_chunks, chunk, -1)  # stored dtype; cast per chunk
-    g32 = g.astype(jnp.float32)
-
-    def body(dh, inputs):
-        w_c, c_idx = inputs
-        w_c32 = w_c.astype(jnp.float32)
-        logits = h32 @ w_c32.T
-        col = c_idx * chunk + jnp.arange(chunk)
-        logits = jnp.where(col[None, :] < v, logits, -jnp.inf)
-        p = jnp.exp(logits - lse[:, None])  # softmax rows for this chunk
-        local = targets - c_idx * chunk
-        in_c = (local >= 0) & (local < chunk)
-        onehot = (col[None, :] == targets[:, None]) & in_c[:, None]
-        ds = (p - onehot.astype(jnp.float32)) * g32[:, None]  # [N, chunk]
-        dh = dh + ds @ w_c32
-        dw_c = ds.T @ h32  # [chunk, d]
-        return dh, dw_c
-
-    dh0 = jnp.zeros_like(h32)
-    dh, dw_chunks = lax.scan(body, dh0, (w_chunks, jnp.arange(n_chunks)))
-    dwte = dw_chunks.reshape(n_chunks * chunk, -1)[:v]
-    return dh.astype(h.dtype), dwte.astype(wte.dtype), None
-
-
-_chunked_xent.defvjp(_fwd_rule, _bwd_rule)
+_blocked_xent.defvjp(_fwd_rule, _bwd_rule)
 
 
 def chunked_softmax_xent(
     h: jax.Array,  # [..., d] final hidden states
     wte: jax.Array,  # [V, d] (tied) unembedding matrix
     targets: jax.Array,  # [...] int32
-    chunk: int = 8192,
 ) -> jax.Array:
     """Mean next-token cross-entropy of ``h @ wte.T`` vs ``targets`` without
     ever materializing the logits. Differentiable in h and wte."""
-    d = h.shape[-1]
-    n_rows = 1
-    for s in h.shape[:-1]:
-        n_rows *= s
-    loss_vec = _chunked_xent(
-        h.reshape(n_rows, d), wte, targets.reshape(n_rows).astype(jnp.int32), int(chunk)
-    )
-    return loss_vec.mean()
+    n, d = math.prod(h.shape[:-1]), h.shape[-1]
+    operands = _vary_alike(h.reshape(n, d), wte, targets.reshape(n).astype(jnp.int32))
+    return _blocked_xent(*operands, block_rows(n, wte.shape[0], d)[1])

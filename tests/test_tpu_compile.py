@@ -183,6 +183,24 @@ def test_selective_scan_holds_no_state_history_in_hbm(scan_grad_text):
     assert max(sizes) == 8192 * 5120, max(sizes)
 
 
+def test_loss_head_plans_no_more_than_the_vocabulary_scan(topo):
+    """The loss head alone, ``value_and_grad`` at the GPT-2-small cells' shape
+    (32,768 tokens of 768 over 50,257, bf16): ONE loop, and no more temp than
+    the vocabulary scan it replaced planned here (1,162,600,448 bytes: its
+    ``[32768, 8192]`` float32 logits, 1.07 GB, and the residuals), so a later
+    change of ``ops/xent.py``'s block rule cannot silently move a cell's peak."""
+    from dsml_tpu.ops.xent import block_rows, chunked_softmax_xent
+
+    n, d, v = 32768, 768, 50257
+    assert block_rows(n, v, d) == (8, 4096)
+    sharding = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding) for shape, dtype in
+            (((n, d), jnp.bfloat16), ((v, d), jnp.bfloat16), ((n,), jnp.int32))]
+    compiled = jax.jit(jax.value_and_grad(chunked_softmax_xent, argnums=(0, 1))).lower(*args).compile()
+    assert compiled.as_text().count(" while(") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_162_600_448
+
+
 # paged decode at GPT-2-small serving geometry: 8 slots, 12 heads,
 # head_dim 64, page 16, 1024 ctx (64 table entries a slot)
 _SLOTS, _HEADS, _HD, _PAGE, _CTX = 8, 12, 64, 16, 1024
