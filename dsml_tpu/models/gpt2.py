@@ -464,6 +464,7 @@ class GPT2:
         return h + ffn(layer[key], layer["ln_2"], h)
 
     _ATTN_IMPLS = ("ring", "ring2", "ulysses", "ulysses_flash", "ring_flash", "flash", "xla")
+    _FLASH_IMPLS = ("flash", "ring_flash", "ulysses_flash", "ring2")  # the flash kernels where the sequence is whole
 
     def _route_attention(self, q, k, v, sp_axis, attn_impl):
         """[b, h_local, s, hd] q/k/v → causal attention output, routed to the
@@ -501,7 +502,7 @@ class GPT2:
 
                 return ring_flash_attention(q, k, v, sp_axis, causal=True)
             return ring_attention(q, k, v, sp_axis, causal=True)
-        if attn_impl in ("flash", "ring_flash", "ulysses_flash", "ring2"):
+        if attn_impl in self._FLASH_IMPLS:
             # no sp axis → every flash variant degenerates to the
             # single-chip kernel (falling through to plain attention would
             # materialize the [seq, seq] scores the caller chose flash to
@@ -511,11 +512,38 @@ class GPT2:
             return flash_attention(q, k, v, causal=True)
         return attention(q, k, v, causal=True)
 
+    def _flash_packs(self, sp_axis, attn_impl, n_head_local):
+        """True where :meth:`_route_attention` would run the single-chip
+        flash kernels AND they can index this shard's heads out of the
+        projections' own ``[b, s, heads·hd]`` layout (``ops.flash.
+        flash_packs``: a rule on shapes). The caller then hands the
+        projections over as they are and no head-major copy is made; any
+        other case (a sharded sequence, another impl, heads that are not
+        64 wide in pairs) takes the head-major route as ever."""
+        from dsml_tpu.ops.flash import flash_packs
+
+        unsharded = not sp_axis or lax.axis_size(sp_axis) == 1
+        head_dim = self.config.d_model // self.config.n_head
+        return unsharded and attn_impl in self._FLASH_IMPLS and flash_packs(n_head_local, head_dim)
+
     def _attn_block(self, layer, h, n_head_local, tp_axis, sp_axis, attn_impl):
         x = _layer_norm(h, **layer["ln_1"])
-        q, k, v = self._qkv_heads(layer, x, n_head_local)
-        out = self._route_attention(q, k, v, sp_axis, attn_impl)
-        out = qmatmul(self._merge_heads(out), layer["attn"]["wo"], out.dtype)  # row-parallel → partial sums
+        w = layer["attn"]["wqkv"]
+        # a plain [d, 3, d_local] weight: a quantized codec (serving) keeps qmatmul's own route
+        if getattr(w, "ndim", 0) == 3 and self._flash_packs(sp_axis, attn_impl, n_head_local):
+            from dsml_tpu.ops.flash import flash_attention_packed
+
+            # [b, s, 3·d_local]: q, k, v side by side, read where they lie. The slot
+            # axis is folded on the WEIGHT (folding the projection's output would
+            # relay it out), from its three slot slices: a reshape reads the padded
+            # parameter and relays weight, gradient and both adam moments
+            w = jnp.concatenate([w[:, 0], w[:, 1], w[:, 2]], axis=1)
+            qkv = qmatmul(x, w, x.dtype) + layer["attn"]["bqkv"].reshape(-1)
+            out, _ = flash_attention_packed(qkv, w.shape[1] // 3 // n_head_local)
+        else:
+            q, k, v = self._qkv_heads(layer, x, n_head_local)
+            out = self._merge_heads(self._route_attention(q, k, v, sp_axis, attn_impl))
+        out = qmatmul(out, layer["attn"]["wo"], out.dtype)  # row-parallel → partial sums
         if tp_axis:
             out = lax.psum(out, tp_axis)  # Megatron psum #1
         return out + layer["attn"]["bo"]
@@ -983,13 +1011,17 @@ class GPT2:
             return c["k"], c["v"], c["k_s"], c["v_s"]
         return c["k"], c["v"], None, None
 
+    def _qkv(self, layer, x):
+        """Fused QKV projection, ``[b, s, 3, d(/tp)]``. ``layer['attn']['wqkv']``
+        is [d, 3, d(/tp)] — the slot axis separates q/k/v so a TP shard of the
+        last dim is purely a head split."""
+        return qmatmul(x, layer["attn"]["wqkv"], x.dtype) + layer["attn"]["bqkv"]
+
     def _qkv_heads(self, layer, x, n_head_local: int | None = None):
-        """Fused QKV projection + head split. ``layer['attn']['wqkv']`` is
-        [d, 3, d(/tp)] — the slot axis separates q/k/v so a TP shard of the
-        last dim is purely a head split; ``n_head_local`` is the head count
-        actually present in this shard (full ``n_head`` when unsharded)."""
+        """:meth:`_qkv` + head split, head-major. ``n_head_local`` is the head
+        count actually present in this shard (full ``n_head`` when unsharded)."""
         n_head_local = n_head_local or self.config.n_head
-        qkv = qmatmul(x, layer["attn"]["wqkv"], x.dtype) + layer["attn"]["bqkv"]
+        qkv = self._qkv(layer, x)
 
         def heads(t):  # [b, s, d_local] -> [b, h_local, s, hd]
             b, s, _ = t.shape

@@ -177,7 +177,7 @@ class Jamba(Llama):
     def _unembed_matrix(self, params):
         return params["wte"]  # tied
 
-    def _rotate(self, t, positions):
+    def _rotate(self, t, positions, head_axis=1):
         return t  # no positional encoding: order enters through the scan and the causal mask
 
     def _block_closure(self, tp_axis, sp_axis, attn_impl):

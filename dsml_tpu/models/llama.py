@@ -127,8 +127,9 @@ def _rms_norm(x, scale, eps=1e-5):
     return (x32 * rms).astype(x.dtype) * scale
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding, rotate-half convention. ``x`` [b, h, s, hd],
+def _rope(x: jax.Array, positions: jax.Array, theta: float, head_axis: int = 1) -> jax.Array:
+    """Rotary embedding, rotate-half convention. ``x`` [b, h, s, hd] (or
+    [b, s, h, hd] with ``head_axis=2``: the projections' own layout),
     ``positions`` [s] GLOBAL token positions (int32) shared across the
     batch, or [b, s] per-row positions (continuous-batching decode, where
     every slot sits at its own depth)."""
@@ -136,12 +137,10 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) * 2.0 / hd)  # [half]
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [(b,) s, half]
-    if angles.ndim == 2:  # shared positions → broadcast over batch and heads
-        cos = jnp.cos(angles)[None, None, :, :]
-        sin = jnp.sin(angles)[None, None, :, :]
-    else:  # per-row positions → broadcast over heads only
-        cos = jnp.cos(angles)[:, None, :, :]
-        sin = jnp.sin(angles)[:, None, :, :]
+    if angles.ndim == 2:  # shared positions → broadcast over the batch too
+        angles = angles[None]
+    cos = jnp.expand_dims(jnp.cos(angles), head_axis)  # one angle for every head
+    sin = jnp.expand_dims(jnp.sin(angles), head_axis)
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
@@ -272,30 +271,33 @@ class Llama(GPT2):
             return lax.psum(params["wte"][safe_ids] * in_shard[..., None], tp_axis)
         return params["wte"][tokens]
 
-    def _rotate(self, t, positions):
+    def _rotate(self, t, positions, head_axis=1):
         """Positions enter here, on q and k (a family without rotary
         overrides with the identity)."""
-        return _rope(t, positions, self.config.rope_theta)
+        return _rope(t, positions, self.config.rope_theta, head_axis)
 
-    def _qkv_gqa(self, layer, x, n_head_local, n_kv_local, positions):
+    def _qkv_gqa(self, layer, x, n_head_local, n_kv_local, positions, head_axis=1):
         """Separate q/k/v projections, head split, RoPE on q/k. Returns
         ``(q, k_kv, v_kv, k_attn, v_attn)``: the kv-head forms (what the
         serving cache stores) and the query-head-repeated forms (what the
         shared MHA attention impls consume) — ONE copy of the GQA math for
-        both the training and serving paths."""
+        both the training and serving paths. Head-major ``[b, h, s, hd]``,
+        or with ``head_axis=2`` the projections' own ``[b, s, h, hd]`` (a
+        reshape, no copy: what the packed flash entry reads)."""
         hd = self.config.d_model // self.config.n_head
 
         def heads(t, n):
             b, s, _ = t.shape
-            return t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
+            t = t.reshape(b, s, n, hd)
+            return t.transpose(0, 2, 1, 3) if head_axis == 1 else t
 
         q = heads(qmatmul(x, layer["attn"]["wq"], x.dtype), n_head_local)
         k = heads(qmatmul(x, layer["attn"]["wk"], x.dtype), n_kv_local)
         v = heads(qmatmul(x, layer["attn"]["wv"], x.dtype), n_kv_local)
-        q, k = self._rotate(q, positions), self._rotate(k, positions)
+        q, k = self._rotate(q, positions, head_axis), self._rotate(k, positions, head_axis)
         repeat = n_head_local // n_kv_local
-        ka = jnp.repeat(k, repeat, axis=1) if repeat > 1 else k
-        va = jnp.repeat(v, repeat, axis=1) if repeat > 1 else v
+        ka = jnp.repeat(k, repeat, axis=head_axis) if repeat > 1 else k
+        va = jnp.repeat(v, repeat, axis=head_axis) if repeat > 1 else v
         return q, k, v, ka, va
 
     def _block(self, layer, h, n_head_local, tp_axis, sp_axis, attn_impl):
@@ -312,9 +314,15 @@ class Llama(GPT2):
         positions = offset + jnp.arange(s_local, dtype=jnp.int32)
 
         x = _rms_norm(h, layer["rms_1"]["scale"], cfg.rms_eps)
-        q, _, _, ka, va = self._qkv_gqa(layer, x, n_head_local, n_kv_local, positions)
-        out = self._route_attention(q, ka, va, sp_axis, attn_impl)
-        out = qmatmul(self._merge_heads(out), layer["attn"]["wo"], out.dtype)
+        if self._flash_packs(sp_axis, attn_impl, n_head_local):
+            from dsml_tpu.ops.flash import flash_attention_packed
+
+            q, _, _, ka, va = self._qkv_gqa(layer, x, n_head_local, n_kv_local, positions, head_axis=2)
+            out, _ = flash_attention_packed([t.reshape(*t.shape[:2], -1) for t in (q, ka, va)], q.shape[-1])
+        else:
+            q, _, _, ka, va = self._qkv_gqa(layer, x, n_head_local, n_kv_local, positions)
+            out = self._merge_heads(self._route_attention(q, ka, va, sp_axis, attn_impl))
+        out = qmatmul(out, layer["attn"]["wo"], out.dtype)
         if tp_axis:
             out = lax.psum(out, tp_axis)
         return out

@@ -10,7 +10,7 @@ touches HBM:
 - forward: blockwise q·kᵀ on the MXU with online-softmax accumulators
   (running row-max, running denominator) held in VMEM scratch across the
   innermost kv-block grid dimension; emits the per-row logsumexp.
-- backward: ONE kernel (``flash_dkv``) on the grid ``(batch·heads,
+- backward: ONE kernel (``flash_dkv``) on the grid ``(batch, lane blocks,
   kv_blocks, q_blocks)``: the score tile is recomputed once, transposed
   (``sᵀ = k·qᵀ``, ``p = exp(s − L)`` from the forward's saved logsumexp
   rather than stored probabilities), and feeds five dots: ``sᵀ``, ``dpᵀ``
@@ -19,8 +19,8 @@ touches HBM:
   MXU's width where ``[block, d]`` leaves half of it idle at head 64).
   ``dk``/``dv`` accumulate over the inner q axis in block-sized scratch;
   ``dq`` sums over the OUTER kv axis, so its float32 accumulator is the
-  whole query length of one (batch, head), resident in VMEM across that
-  walk (2 MB at 8192 × 64, 4 MB at 8192 × 128). A query whose resident
+  whole query length of one (batch, lane block), resident in VMEM across
+  that walk (4 MB at 8192 rows of 128 lanes). A query whose resident
   ``dq`` does not fit beside the tile (``_fused_bwd_vmem`` against
   ``_VMEM_BUDGET``: a rule on shapes alone, about 50k rows at head 64 in
   bf16) keeps the two-kernel split, ``flash_dq`` over kv blocks then the
@@ -40,6 +40,29 @@ touches HBM:
 - :func:`flash_block_grads` — the raw one-block backward given MERGED
   (out, lse) statistics; the primitive that re-streaming backward calls.
 
+Layout. The kernels take ``[B, S, W]`` arrays and a grid step works one
+block of the minor dimension (:func:`_operands`). Head-major, ``[batch·heads,
+seq, head_dim]``: the block is the whole minor dimension, one head
+(:func:`flash_attention`, :func:`flash_attention_lse`,
+:func:`flash_block_grads`: ring, Ulysses, cp, the serving prefill). Packed,
+the projections' own ``[batch, seq, heads·head_dim]``: the block is 128
+lanes, ``128 // head_dim`` heads (two of 64, one of 128), and ``o``, ``dq``,
+``dk``, ``dv`` leave the same way (:func:`flash_attention_packed`). For
+GPT-2's fused projection q, k and v are three views of the ONE ``[batch, seq,
+3·d]`` output of ``wqkv``, found by lane-block offsets (no slice is made; the
+cotangent is one such array, ``dq`` written into its own lanes by the kernel). No head-major copy exists on either side of the kernels, and a
+head of 64 is not stored at 128 lanes in HBM. ONE body serves both layouts: it
+loops over the heads of its block. A head's scores come from a dot over the
+block's lanes with the other heads' lanes of ``q`` zeroed (64 live lanes of
+128 fill the MXU as a 64-wide operand does); ``p·v`` yields the block's lanes
+and each head keeps its own; in the backward a head is a row range of the
+TRANSPOSED operand blocks and accumulators (an aligned slice), so every
+gradient dot still yields ``[head_dim, block]``. ``lse`` and ``delta −
+g_lse`` stay a row a head, lane-major over seq. Callers choose by shapes
+(:func:`flash_packs`), not by an option. The two calls are jitted
+(``_flash_fwd``, ``_flash_bwd_calls``): a model's like layers then trace and lower
+each kernel once, not once a layer.
+
 Dtypes. Every dot takes its operands in the INPUTS' dtype and accumulates in
 float32 (``preferred_element_type``): bf16 ``q``/``k``/``v``/``do`` blocks go
 into the MXU as they lie in HBM, and ``p`` / ``ds`` are cast to that dtype
@@ -54,8 +77,8 @@ block where that is exact (a power of two: head 64) and the float32 scores
 otherwise.
 The dkv kernel works the TRANSPOSED tile (``sᵀ = k·qᵀ``), so the row
 statistics are used lane-major as stored; the transposed left operand of a
-gradient dot is a ``[block, d]`` operand block (``do``, ``q``, ``k``), never
-the tile. ``dsᵀ`` is cast to the operand dtype once and feeds both its dots.
+gradient dot is a ``[block, lanes]`` operand block (``do``, ``q``, ``k``,
+transposed once a grid step), never the tile. ``dsᵀ`` is cast to the operand dtype once and feeds both its dots.
 
 Sequences that don't tile into blocks run through a PADDED path: zero-pad
 to a block multiple (≤ 25% waste), mask the padded kv tail inside the
@@ -70,7 +93,8 @@ On non-TPU backends the same kernels run under the Pallas interpreter
 (``interpret=True``), which is how tests validate them on the CI CPU mesh;
 on TPU they compile through Mosaic.
 
-Used by ``dsml_tpu.models.gpt2`` via ``attn_impl="flash"`` (single-chip) and
+Used by ``dsml_tpu.models.gpt2`` / ``llama`` via ``attn_impl="flash"``
+(single-chip: packed where :func:`flash_packs` says so) and
 ``attn_impl="ring_flash"`` (sequence-parallel).
 """
 
@@ -95,6 +119,8 @@ except ImportError:  # pragma: no cover
 __all__ = [
     "flash_attention",
     "flash_attention_lse",
+    "flash_attention_packed",
+    "flash_packs",
     "flash_block_grads",
     "flash_stream_hop",
     "ring_flash_attention",
@@ -213,8 +239,6 @@ def _default_blocks(
 
 _NT = (((1,), (1,)), ((), ()))  # a·bᵀ: contract the head dim of both
 _NN = (((1,), (0,)), ((), ()))  # a·b
-_TN = (((0,), (0,)), ((), ()))  # aᵀ·b: contract the first axis of both
-_TT = (((0,), (1,)), ((), ()))  # aᵀ·bᵀ: the first axis of a with the last of b
 
 
 def _dot(a, b, dims):
@@ -272,6 +296,33 @@ def _row_chunks(block: int) -> list[slice]:
     return [slice(r, r + rows) for r in range(0, block, rows)]
 
 
+def _head_lanes(lanes: int, head_dim: int) -> list:
+    """One entry for each head of a ``lanes``-wide block: the ``[1, lanes]``
+    mask of the head's own lanes, or ``None`` where the block is one head
+    (the head-major form, and a head of 128 in the packed one)."""
+    if lanes == head_dim:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return [(lane >= j * head_dim) & (lane < (j + 1) * head_dim) for j in range(lanes // head_dim)]
+
+
+def _only(x, own):
+    """``x`` [rows, lanes] with the lanes of every other head zeroed: a dot
+    that contracts the block's lanes then contracts this head's alone (64
+    live lanes of 128 fill the MXU as a 64-wide operand does)."""
+    return x if own is None else jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _weave(parts, heads):
+    """[rows, lanes] that holds, in each head's lanes, that head's ``parts``
+    entry (each entry is [rows, lanes] itself: a dot that yields the block's
+    lanes costs what one that yields a head's does)."""
+    out = parts[-1]
+    for part, own in zip(parts[-2::-1], heads[-2::-1]):
+        out = jnp.where(own, part, out)
+    return out
+
+
 def _mask(s, q0, k0, kv_stop, causal, mask_kv, q_axis):
     """Mask the score tile ``s`` whose first query / key sit at global
     positions ``q0`` / ``k0``; queries lie along ``q_axis`` of ``s`` (0, or
@@ -327,18 +378,20 @@ def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k)
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, scale, causal, block_q, block_k, kv_blocks, mask_kv, qi=None, ki=None):
+def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv, qi=None, ki=None):
     # qi/ki may be pre-read grid indices: a wrapping kernel that delegates
     # here from inside pl.when must hoist its program_id reads to the top
     # level — interpret mode substitutes the primitive only when it's bound
     # in the outer kernel jaxpr, not inside a cond branch
     if qi is None:
-        qi = pl.program_id(1)
+        qi = pl.program_id(2)
     if ki is None:
-        ki = pl.program_id(2)
+        ki = pl.program_id(3)
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
+    scale = head_dim**-0.5
     fold = _scale_folds(scale)
+    heads = _head_lanes(acc.shape[1], head_dim)
 
     @pl.when(ki == 0)
     def _init():
@@ -351,63 +404,87 @@ def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, 
         if fold:
             q = q * scale
         for rows in _row_chunks(block_q):
-            s = _dot(q[rows], k, _NT)
-            if not fold:
-                s = s * scale
-            if masked:
-                s = _mask(s, q0 + rows.start, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
-            m_prev = m_scr[rows]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            corr = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - _lanes(m_new, block_k))
-            l_scr[rows] = l_scr[rows] * corr + _fold_lanes(p, l_scr.shape[1])
-            acc[rows] = acc[rows] * _lanes(corr, acc.shape[1]) + _dot(p.astype(v.dtype), v, _NN)
-            m_scr[rows] = m_new
+            corrs, pvs = [], []
+            for j, own in enumerate(heads):
+                s = _dot(_only(q[rows], own), k, _NT)
+                if not fold:
+                    s = s * scale
+                if masked:
+                    s = _mask(s, q0 + rows.start, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
+                m_prev = m_scr[j, rows]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - _lanes(m_new, block_k))
+                l_scr[j, rows] = l_scr[j, rows] * corr + _fold_lanes(p, l_scr.shape[2])
+                m_scr[j, rows] = m_new
+                corrs.append(_lanes(corr, acc.shape[1]))
+                pvs.append(_dot(p.astype(v.dtype), v, _NN))
+            acc[rows] = acc[rows] * _weave(corrs, heads) + _weave(pvs, heads)
 
     _per_tile_class(compute, q0, k0, kstop_ref[0], causal, mask_kv, block_q, block_k)
 
     @pl.when(ki == kv_blocks - 1)
     def _finish():
-        l_fin = jnp.maximum(jnp.sum(l_scr[:], -1, keepdims=True), 1e-30)
-        o_ref[0] = (acc[:] / l_fin).astype(o_ref.dtype)
-        # lse is stored [bh, 8, seq] — 8 identical sublanes keep the block
+        l_fin = [jnp.maximum(jnp.sum(l_scr[j], -1, keepdims=True), 1e-30) for j in range(len(heads))]
+        o_ref[0] = (acc[:] / _weave([_lanes(l, acc.shape[1]) for l in l_fin], heads)).astype(o_ref.dtype)
+        # lse is stored [heads, 8, seq] — 8 identical sublanes keep the block
         # shape Mosaic-tileable (last two dims (8, block_q))
-        lse_ref[0] = jnp.broadcast_to((m_scr[:, :1] + jnp.log(l_fin)).reshape(1, block_q), (8, block_q))
+        for j, l in enumerate(l_fin):
+            lse_ref[j] = jnp.broadcast_to((m_scr[j, :, :1] + jnp.log(l)).reshape(1, block_q), (8, block_q))
 
 
-def _flash_fwd(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
-    bh, s_q, d = q.shape
-    s_kv = k.shape[1]
-    scale = d**-0.5
-    q_blocks, kv_blocks = s_q // block_q, s_kv // block_k
+def _operands(qkv, head_dim):
+    """How the kernels find q, k and v in what they were handed:
+    ``(q, k, v, at, lanes, groups)``. A grid step takes one ``lanes``-wide
+    block of the minor dimension, ``groups`` of them cover the heads, and
+    ``at`` is the lane block where each operand's first head lies. Three
+    arrays ``[B, S, W]``: where ``W`` is one head (the head-major form, ``B``
+    = batch·heads) the block is the whole minor dimension; else the arrays
+    are a projection's own output, ``W`` = heads·head_dim, and a block is 128
+    lanes, ``128 // head_dim`` heads. ONE array ``[B, S, 3·W]`` is all three
+    side by side, as a fused projection leaves them."""
+    if len(qkv) == 1:
+        blocks = qkv[0].shape[2] // 3 // 128
+        return *qkv * 3, (0, blocks, 2 * blocks), 128, blocks
+    q, k, v = qkv
+    lanes = head_dim if q.shape[2] == head_dim else 128
+    return q, k, v, (0, 0, 0), lanes, q.shape[2] // lanes
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+    q, k, v, (q_at, k_at, v_at), lanes, groups = _operands(qkv, head_dim)
+    batch, s_q = q.shape[:2]
+    heads = lanes // head_dim
+    q_blocks, kv_blocks = s_q // block_q, k.shape[1] // block_k
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
+        _fwd_kernel, head_dim=head_dim, causal=causal,
         block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv,
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, q_blocks, kv_blocks),
+        grid=(batch, groups, q_blocks, kv_blocks),
         in_specs=[
             _smem_spec(),
             _smem_spec(),
             _smem_spec(),
-            _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            _vmem_spec((1, block_q, lanes), lambda b, g, qi, ki: (b, qi, q_at + g)),
+            _vmem_spec((1, block_k, lanes), lambda b, g, qi, ki: (b, ki, k_at + g)),
+            _vmem_spec((1, block_k, lanes), lambda b, g, qi, ki: (b, ki, v_at + g)),
         ],
         out_specs=[
-            _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
+            _vmem_spec((1, block_q, lanes), lambda b, g, qi, ki: (b, qi, g)),
+            _vmem_spec((heads, 8, block_q), lambda b, g, qi, ki: (b * groups + g, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, s_q), jnp.float32),
+            jax.ShapeDtypeStruct((batch, s_q, groups * lanes), q.dtype),
+            jax.ShapeDtypeStruct((batch * groups * heads, 8, s_q), jnp.float32),
         ],
         scratch_shapes=[
-            _scratch((block_q, d)),
-            _scratch((block_q, _stat_lanes(block_k))),
-            _scratch((block_q, _stat_lanes(block_k))),
+            _scratch((block_q, lanes)),
+            _scratch((heads, block_q, _stat_lanes(block_k))),
+            _scratch((heads, block_q, _stat_lanes(block_k))),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -428,7 +505,7 @@ def _stream_fwd_kernel(qs_ref, ks_ref, kstop_ref, pred_ref, nbr_ref,
                        q_ref, k_ref, v_ref, ksend_ref, vsend_ref,
                        o_ref, lse_ref, knext_ref, vnext_ref,
                        acc, m_scr, l_scr, send_sem, recv_sem, *,
-                       scale, causal, block_q, block_k, q_blocks, kv_blocks,
+                       head_dim, causal, block_q, block_k, q_blocks, kv_blocks,
                        n_bh, mask_kv, barrier):
     """:func:`_fwd_kernel` with the ring hop absorbed: at the FIRST grid
     step the resident KV shard starts a remote async copy into the
@@ -475,7 +552,7 @@ def _stream_fwd_kernel(qs_ref, ks_ref, kstop_ref, pred_ref, nbr_ref,
     @pl.when(pred_ref[0] != 0)
     def _math():
         _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref,
-                    o_ref, lse_ref, acc, m_scr, l_scr, scale=scale,
+                    o_ref, lse_ref, acc, m_scr, l_scr, head_dim=head_dim,
                     causal=causal, block_q=block_q, block_k=block_k,
                     kv_blocks=kv_blocks, mask_kv=mask_kv, qi=qi, ki=ki)
 
@@ -537,18 +614,13 @@ def flash_stream_hop(
     if interpret is None:
         interpret = _interpret_default()
     mask_kv = pk != s_kv
-    qf, kf, vf = _flat3(q), _flat3(k), _flat3(v)
-    ksend, vsend = kf, vf  # unpadded residents are what tours the ring
-    if pq != s_q:
-        qf = jnp.pad(qf, ((0, 0), (0, pq - s_q), (0, 0)))
-    if mask_kv:
-        kf = jnp.pad(kf, ((0, 0), (0, pk - s_kv), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, pk - s_kv), (0, 0)))
+    ksend, vsend = _flat3(k), _flat3(v)  # unpadded residents are what tours the ring
+    qf, kf, vf = _pad_rows(_flat3(q), pq), _pad_rows(ksend, pk), _pad_rows(vsend, pk)
     kv_stop = k_start + s_kv
     bh = qf.shape[0]
     q_blocks, kv_blocks = pq // bq, pk // bk
     kernel = functools.partial(
-        _stream_fwd_kernel, scale=d ** -0.5, causal=causal, block_q=bq,
+        _stream_fwd_kernel, head_dim=d, causal=causal, block_q=bq,
         block_k=bk, q_blocks=q_blocks, kv_blocks=kv_blocks, n_bh=bh,
         mask_kv=mask_kv, barrier=not interpret,
     )
@@ -578,7 +650,7 @@ def flash_stream_hop(
             jax.ShapeDtypeStruct(vsend.shape, vsend.dtype),
         ],
         scratch_shapes=[
-            _scratch((bq, d)), _scratch((bq, _stat_lanes(bk))), _scratch((bq, _stat_lanes(bk))),
+            _scratch((bq, d)), _scratch((1, bq, _stat_lanes(bk))), _scratch((1, bq, _stat_lanes(bk))),
             pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -599,12 +671,14 @@ def flash_stream_hop(
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, scale, causal, block_q, block_k, kv_blocks, mask_kv):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv):
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
+    scale = head_dim**-0.5
     fold = _scale_folds(scale)
+    heads = _head_lanes(acc.shape[1], head_dim)
 
     @pl.when(ki == 0)
     def _init():
@@ -614,17 +688,20 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         if fold:
             q = q * scale
-        s = _dot(q, k, _NT)
-        if not fold:
-            s = s * scale
-        if causal or mask_kv:
-            s = _mask(s, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
-        # the row statistics arrive lane-major over seq and are relaid as
-        # columns on every tile: keeping the columns in scratch across the
-        # kv blocks measured slower (PERF.md §6)
-        p = jnp.exp(s - lse_ref[0, 0].reshape(block_q, 1))
-        ds = p * (_dot(do, v, _NT) - dd_ref[0, 0].reshape(block_q, 1))
-        acc[:] = acc[:] + _dot(ds.astype(k.dtype), k, _NN)
+        dqs = []
+        for j, own in enumerate(heads):
+            s = _dot(_only(q, own), k, _NT)
+            if not fold:
+                s = s * scale
+            if causal or mask_kv:
+                s = _mask(s, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
+            # the row statistics arrive lane-major over seq and are relaid as
+            # columns on every tile: keeping the columns in scratch across the
+            # kv blocks measured slower (PERF.md §6)
+            p = jnp.exp(s - lse_ref[j, 0].reshape(block_q, 1))
+            ds = p * (_dot(_only(do, own), v, _NT) - dd_ref[j, 0].reshape(block_q, 1))
+            dqs.append(_dot(ds.astype(k.dtype), k, _NN))
+        acc[:] = acc[:] + _weave(dqs, heads)
 
     if causal:
         pl.when(_seen(q0, k0, block_q))(compute)
@@ -636,36 +713,41 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, scale, causal, block_q, block_k, q_blocks, kv_blocks, mask_kv):
+def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, q_blocks, kv_blocks, mask_kv):
     """``dk`` and ``dv`` of one kv block, accumulated over the q blocks; given
     a third output and scratch (``dq_ref``, ``dq_acc``) also ``dq``, from the
     tile it already holds.
 
-    All three accumulate TRANSPOSED, ``[d, block]`` float32: each gradient
-    dot then yields a block-wide result from ``d`` rows where ``[block, d]``
-    would leave the columns past ``d`` of the 128-wide MXU idle (head 64),
-    and what the dot relays is a ``[block, d]`` operand block, never the
-    tile (kernel-only at ``[48, 8192, 64]``: 13.94 ms a call with
+    All three accumulate TRANSPOSED, ``[lanes, block]`` float32: each gradient
+    dot then yields a block-wide result from a head's ``d`` rows where
+    ``[block, d]`` would leave the columns past ``d`` of the 128-wide MXU idle
+    (head 64), and what is transposed is a ``[block, lanes]`` operand block,
+    never the tile (kernel-only at ``[48, 8192, 64]``: 13.94 ms a call with
     ``dq += ds·k``, 12.58 with ``dqᵀ += kᵀ·dsᵀ``, 10.82 with ``dk`` and
-    ``dv`` transposed too; PERF.md §6). Each is transposed once, on its way
-    out.
+    ``dv`` transposed too; PERF.md §6). Each is transposed back once, on its
+    way out. Of a transposed operand block the heads are row ranges, aligned
+    slices: every gradient dot takes ONE head's ``[d, block]`` and yields
+    that head's rows of the accumulator, so a block of two heads does the
+    dots two blocks of one head do.
 
     ``dq`` sums over ``ki``, the OUTER of the two inner grid axes, so its
-    accumulator is the whole query length of one (batch, head),
-    ``[q_blocks, d, block_q]``, resident across that walk: block ``qi`` is
+    accumulator is the whole query length of one (batch, lane block),
+    ``[q_blocks, lanes, block_q]``, resident across that walk: block ``qi`` is
     zeroed at ``ki == 0`` and written out at the last ``ki``, both outside
     the ``_seen`` predicate (offsets are traced: a q block may be skipped on
     every step and still owes its zeros). ``dq_ref`` is the whole query
-    length too, in ``q``'s dtype, and goes to HBM once a (batch, head)."""
+    length too, in ``q``'s dtype, and goes to HBM once a (batch, lane block)."""
     if len(rest) == 2:
         (dk_acc, dv_acc), dq_ref, dq_acc = rest, None, None
     else:
         dq_ref, dk_acc, dv_acc, dq_acc = rest
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
+    scale = head_dim**-0.5
     fold = _scale_folds(scale)
+    heads = _head_lanes(dk_acc.shape[0], head_dim)
 
     @pl.when(qi == 0)
     def _init():
@@ -685,17 +767,21 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         if fold:
             q = q * scale  # scales sᵀ here and dk below: dk = scale · dsᵀ·q
-        st = _dot(k, q, _NT)
-        if not fold:
-            st = st * scale
-        if causal or mask_kv:
-            st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1)
-        pt = jnp.exp(st - lse_ref[0, :1])
-        dv_acc[:] = dv_acc[:] + _dot(do, pt.astype(do.dtype), _TT)  # dvᵀ += doᵀ·p
-        dst = (pt * (_dot(v, do, _NT) - dd_ref[0, :1])).astype(q.dtype)  # cast once, feeds both its dots
-        dk_acc[:] = dk_acc[:] + _dot(q, dst, _TT)  # dkᵀ += qᵀ·ds
-        if dq_ref is not None:
-            dq_acc[qi] = dq_acc[qi] + _dot(k, dst, _TN)  # dqᵀ[q block] += kᵀ·dsᵀ
+        q_t, k_t, do_t = q.T, k.T, do.T  # [lanes, block]: a head is a row range
+        for j, own in enumerate(heads):
+            mine = slice(j * head_dim, (j + 1) * head_dim)
+            st = _dot(k, _only(q, own), _NT)
+            if not fold:
+                st = st * scale
+            if causal or mask_kv:
+                st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1)
+            pt = jnp.exp(st - lse_ref[j, :1])
+            dv_acc[mine] = dv_acc[mine] + _dot(do_t[mine], pt.astype(do.dtype), _NT)  # dvᵀ += doᵀ·p
+            # cast once, feeds both its dots
+            dst = (pt * (_dot(v, _only(do, own), _NT) - dd_ref[j, :1])).astype(q.dtype)
+            dk_acc[mine] = dk_acc[mine] + _dot(q_t[mine], dst, _NT)  # dkᵀ += qᵀ·ds
+            if dq_ref is not None:
+                dq_acc[qi, mine] = dq_acc[qi, mine] + _dot(k_t[mine], dst, _NN)  # dqᵀ[q block] += kᵀ·dsᵀ
 
     if causal:  # q blocks entirely before this kv block see none of it
         pl.when(_seen(q0, k0, block_q))(compute)
@@ -741,81 +827,98 @@ def _fused_bwd_vmem(s_q: int, d: int, block_q: int, block_k: int, itemsize: int)
     return resident + tile + blocks
 
 
-def _flash_bwd(q, k, v, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
-    """``(dq, dk, dv)`` in the inputs' dtypes. One kernel (``flash_dkv``, ``dq``
-    riding it) wherever the resident ``dq`` of one (batch, head) fits VMEM
-    beside the tile: a rule on the shapes in hand and nothing else. A longer
-    query keeps the pair, ``flash_dq`` then ``flash_dkv``."""
-    bh, s_q, d = q.shape
+def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+    """``(dq, dk, dv)`` in the inputs' dtypes and layout, ``[B, S, W]`` each;
+    where q, k, v came as one ``[B, S, 3·W]`` array ``dq`` is such an array
+    too, written in q's lanes alone: the cotangent-to-be, whose other lanes
+    the caller fills with ``dk`` and ``dv`` (two in-place updates where a
+    concatenation is three passes and a buffer more). One kernel
+    (``flash_dkv``, ``dq`` riding it) wherever the resident ``dq`` of one
+    (batch, lane block) fits VMEM beside the tile: a rule on the shapes in
+    hand and nothing else. A longer query keeps the pair, ``flash_dq`` then
+    ``flash_dkv``."""
+    q, _, _, _, lanes, _ = _operands(qkv, head_dim)
+    vmem = _fused_bwd_vmem(q.shape[1], lanes, block_q, block_k, q.dtype.itemsize)
+    return _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k,
+                            interpret, mask_kv, head_dim, vmem if vmem <= _VMEM_BUDGET else None)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14))
+def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, vmem):
+    """:func:`_flash_bwd`'s kernels, ``dq`` riding ``flash_dkv`` in ``vmem``
+    bytes of VMEM or, with ``None``, the pair. Jitted, like ``_flash_fwd``:
+    a model's like layers trace and lower each kernel once."""
+    q, k, v, (q_at, k_at, v_at), lanes, groups = _operands(qkv, head_dim)
+    batch, s_q = q.shape[:2]
     s_kv = k.shape[1]
-    scale = d**-0.5
+    heads = lanes // head_dim
     q_blocks, kv_blocks = s_q // block_q, s_kv // block_k
-    # ds = p · (dp − delta + g_lse): delta = Σ do·o, and g_lse is the
-    # cotangent of the lse output. Both are per query row, so their
-    # difference is taken here once, not on every score tile
-    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1) - glse  # [bh, s_q]
-    dd = jnp.broadcast_to(dd[:, None, :], (bh, 8, s_q))  # sublane-aligned like lse
+    # ds = p · (dp − delta + g_lse): delta = Σ do·o over a head's lanes, and
+    # g_lse is the cotangent of the lse output. Both are per query row, so
+    # their difference is taken here once, not on every score tile. The sum is
+    # a matmul with the heads' 0/1 lane masks: it reads do and o where they
+    # lie and lands a row a head, lane-major over seq like lse (a reshape to
+    # [.., heads, head_dim] and a sum has XLA relayout the float32 products
+    # first); the products of two bf16 are exact in float32 and HIGHEST keeps them so
+    width = groups * lanes
+    own = (jnp.arange(width)[:, None] // head_dim == jnp.arange(width // head_dim)).astype(jnp.float32)
+    delta = jnp.einsum("bsw,wh->bhs", do.astype(jnp.float32) * o.astype(jnp.float32), own,
+                       precision=lax.Precision.HIGHEST)
+    dd = delta.reshape(-1, s_q) - glse  # [B·heads, s_q]
+    dd = jnp.broadcast_to(dd[:, None, :], (dd.shape[0], 8, s_q))  # sublane-aligned like lse
     scalars = (_scalar(q_start), _scalar(k_start), _scalar(kv_stop))
-    vmem = _fused_bwd_vmem(s_q, d, block_q, block_k, q.dtype.itemsize)
-    fused = vmem <= _VMEM_BUDGET
+    fused = vmem is not None
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)  # q's own array: all three where they came as one
+    kv_shape = jax.ShapeDtypeStruct((batch, s_kv, groups * lanes), k.dtype)
+
+    def specs(q_axis):
+        """Operand specs on a grid ``(b, g, i, j)`` whose inner axis
+        ``q_axis`` (0 or 1) walks the q blocks and whose other walks the kv
+        blocks, and the maker of a q-side (``of_q=True``) or kv-side block
+        spec on that grid (the outputs')."""
+        def side(of_q, at=0):
+            block, axis = (block_q, q_axis) if of_q else (block_k, 1 - q_axis)
+            return _vmem_spec((1, block, lanes), lambda b, g, *ij: (b, ij[axis], at + g))
+
+        stat = _vmem_spec((heads, 8, block_q), lambda b, g, *ij: (b * groups + g, 0, ij[q_axis]))
+        operands = [_smem_spec(), _smem_spec(), _smem_spec(),
+                    side(True, q_at), side(False, k_at), side(False, v_at), side(True), stat, stat]
+        return operands, side
 
     dq = None
     if not fused:
-        qrow = [
-            _smem_spec(),
-            _smem_spec(),
-            _smem_spec(),
-            _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            _vmem_spec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            _vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
-            _vmem_spec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
-        ]
+        qrow, side = specs(0)
         dq = pl.pallas_call(
             functools.partial(
-                _dq_kernel, scale=scale, causal=causal,
+                _dq_kernel, head_dim=head_dim, causal=causal,
                 block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv,
             ),
-            grid=(bh, q_blocks, kv_blocks),
+            grid=(batch, groups, q_blocks, kv_blocks),
             in_specs=qrow,
-            out_specs=_vmem_spec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            scratch_shapes=[_scratch((block_q, d))],
+            out_specs=side(True, q_at),
+            out_shape=dq_shape,
+            scratch_shapes=[_scratch((block_q, lanes))],
             interpret=interpret,
             name="flash_dq",
         )(*scalars, q, k, v, do, lse8, dd)
 
-    krow = [
-        _smem_spec(),
-        _smem_spec(),
-        _smem_spec(),
-        _vmem_spec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-        _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        _vmem_spec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-        _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
-        _vmem_spec((1, 8, block_q), lambda b, ki, qi: (b, 0, qi)),
-    ]
-    out_specs = [
-        _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        _vmem_spec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-    ]
-    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)]
-    scratch_shapes = [_scratch((d, block_k)), _scratch((d, block_k))]
+    krow, side = specs(1)
+    out_specs = [side(False), side(False)]
+    out_shape = [kv_shape, kv_shape]
+    scratch_shapes = [_scratch((lanes, block_k)), _scratch((lanes, block_k))]
     compiler_params = None
     if fused:
-        out_specs.append(_vmem_spec((1, s_q, d), lambda b, ki, qi: (b, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
-        scratch_shapes.append(_scratch((q_blocks, d, block_q)))
+        out_specs.append(_vmem_spec((1, s_q, lanes), lambda b, g, ki, qi: (b, 0, q_at + g)))
+        out_shape.append(dq_shape)
+        scratch_shapes.append(_scratch((q_blocks, lanes, block_q)))
         if not interpret:
             compiler_params = pltpu.CompilerParams(vmem_limit_bytes=max(vmem, _VMEM_DEFAULT))
     dk, dv, *riding = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            _dkv_kernel, head_dim=head_dim, causal=causal, block_q=block_q, block_k=block_k,
             q_blocks=q_blocks, kv_blocks=kv_blocks, mask_kv=mask_kv,
         ),
-        grid=(bh, kv_blocks, q_blocks),
+        grid=(batch, groups, kv_blocks, q_blocks),
         in_specs=krow,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -834,25 +937,31 @@ def _flash_bwd(q, k, v, o, lse8, do, glse, q_start, k_start, kv_stop, causal, bl
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def _flash(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
-    out, lse8 = _flash_fwd(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+    """``qkv`` is ``(q, k, v)`` or the one array that holds all three
+    (:func:`_operands`); ``(out [B, S, W], lse [B·heads, S])``."""
+    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim)
     return out, lse8[:, 0, :]
 
 
-def _flash_fwd_rule(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv):
-    out, lse8 = _flash_fwd(q, k, v, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv)
-    return (out, lse8[:, 0, :]), (q, k, v, out, lse8, q_start, k_start, kv_stop)
+def _flash_fwd_rule(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim)
+    return (out, lse8[:, 0, :]), (qkv, out, lse8, q_start, k_start, kv_stop)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, res, g):
-    q, k, v, out, lse8, q_start, k_start, kv_stop = res
+def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, head_dim, res, g):
+    qkv, out, lse8, q_start, k_start, kv_stop = res
     g_out, g_lse = g
-    dq, dk, dv = _flash_bwd(
-        q, k, v, out, lse8, g_out, g_lse.astype(jnp.float32), q_start, k_start, kv_stop, causal,
-        block_q, block_k, interpret, mask_kv
+    grads = _flash_bwd(
+        qkv, out, lse8, g_out, g_lse.astype(jnp.float32), q_start, k_start, kv_stop, causal,
+        block_q, block_k, interpret, mask_kv, head_dim,
     )
-    return dq, dk, dv, None, None, None
+    if len(qkv) == 1:  # one array in, one cotangent out: dk and dv set beside dq, as k and v lay beside q
+        dq, dk, dv = grads
+        width = dk.shape[2]
+        grads = (lax.dynamic_update_slice(lax.dynamic_update_slice(dq, dk, (0, 0, width)), dv, (0, 0, 2 * width)),)
+    return grads, None, None, None
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -866,6 +975,83 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def _flat3(t):
     b, h, s, d = t.shape
     return t.reshape(b * h, s, d)
+
+
+def _pad_rows(t, rows: int):
+    """``t`` [B, S, ...] zero-padded along S to ``rows`` (as it is when it has them)."""
+    if t.shape[1] == rows:
+        return t
+    return jnp.pad(t, [(0, 0), (0, rows - t.shape[1])] + [(0, 0)] * (t.ndim - 2))
+
+
+def _attend(qkv, head_dim, causal, q_start, k_start, block_q, block_k, interpret):
+    """The differentiable call at ANY length on ``(q, k, v)``, ``[B, S, W]``
+    each, or the one array ``[B, S, 3·W]`` that holds all three
+    (:func:`_operands`): ``(out [B, S, W], lse [B·heads, S])``. Lengths the
+    block ladder can't tile are zero-padded up to a block multiple (≤ 25%
+    waste), the padded kv tail masked off inside the kernels via the
+    ``kv_stop`` SMEM scalar and the padded q rows sliced away."""
+    s_q, s_kv = qkv[0].shape[1], qkv[-1].shape[1]
+    block_q, block_k = _default_blocks(s_q, s_kv, block_q, block_k, head_dim)
+    bq, pq = _pad_choice(s_q, block_q)
+    bk, pk = _pad_choice(s_kv, block_k)
+    if interpret is None:
+        interpret = _interpret_default()
+    if (pq, pk) != (s_q, s_kv):
+        if len(qkv) == 1:
+            qkv = tuple(jnp.split(qkv[0], 3, axis=-1))  # q and kv pad apart
+        # padded q rows are ZERO (s = 0·k exactly — no overflow risk in the
+        # backward's p = exp(s − lse)) and sliced off below; the slice's
+        # transpose zero-pads their cotangent, so autodiff needs no help
+        qkv = (_pad_rows(qkv[0], pq), _pad_rows(qkv[1], pk), _pad_rows(qkv[2], pk))
+    kv_stop = k_start + s_kv  # global position the REAL kv columns end at
+    out, lse = _flash(qkv, q_start, k_start, kv_stop, causal, bq, bk, interpret, pk != s_kv, head_dim)
+    return out[:, :s_q], lse[:, :s_q]
+
+
+def flash_packs(n_head: int, head_dim: int) -> bool:
+    """True where a model should hand the kernels its projections' own
+    ``[batch, seq, n_head·head_dim]`` arrays: two whole heads share each
+    128-lane block (head 64) and whole blocks fill the array. There the
+    packed layout also stores nothing at twice its bytes and halves the grid
+    steps, and the kernels themselves run 10-19% faster than on head-major
+    arrays. A rule on shapes, from what the chip measured (PERF.md §6, PR
+    33): a head of 128 CAN be read the same way (:func:`flash_attention_packed`
+    takes it) but fills its block alone, and at ``[1, 8192, 20x128]`` the
+    backward kernel ran 7.6% slower on the strided blocks than on head-major
+    ones, more than the copies it saves; narrower heads (four or more to a
+    block) no chip has measured. Both stay head-major, as does any sharded
+    sequence."""
+    return head_dim == 64 and n_head % 2 == 0
+
+
+def flash_attention_packed(
+    qkv,
+    head_dim: int,
+    causal: bool = True,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool | None = None,
+):
+    """Flash attention in the projections' own layout: no head-major copy of
+    q, k, v on the way in, nor of the output (and of ``do``, ``dq``, ``dk``,
+    ``dv`` in the backward) on the way out.
+
+    ``qkv`` is ``(q, k, v)``, each ``[batch, seq, heads·head_dim]`` (grouped
+    keys and values repeated to the query heads by the caller), or ONE array
+    ``[batch, seq, 3·heads·head_dim]`` holding q, k and v side by side as a
+    fused projection leaves them (its cotangent is one array too). Returns ``(out [batch, seq, heads·head_dim],
+    lse [batch, heads, seq])``, both differentiable. Whole heads must fill
+    128-lane blocks and whole blocks the arrays (heads of 64 in pairs, heads
+    of 128); the same kernel bodies run as under :func:`flash_attention_lse`,
+    a grid step taking the ``128 // head_dim`` heads of one block."""
+    qkv = tuple(qkv) if isinstance(qkv, (tuple, list)) else (qkv,)
+    batch, seq, width = qkv[0].shape
+    n_head, rest = divmod(width, head_dim * (3 if len(qkv) == 1 else 1))
+    if rest or head_dim not in (64, 128) or (n_head * head_dim) % 128:
+        raise ValueError(f"{n_head} heads of {head_dim} do not fill 128-lane blocks: got {qkv[0].shape}")
+    out, lse = _attend(qkv, head_dim, causal, 0, 0, block_q, block_k, interpret)
+    return out, lse.reshape(batch, n_head, seq)
 
 
 def flash_attention_lse(
@@ -894,27 +1080,7 @@ def flash_attention_lse(
     XLA fallback must own them.
     """
     b, h, s_q, d = q.shape
-    s_kv = k.shape[2]
-    block_q, block_k = _default_blocks(s_q, s_kv, block_q, block_k, d)
-    bq, pq = _pad_choice(s_q, block_q)
-    bk, pk = _pad_choice(s_kv, block_k)
-    if interpret is None:
-        interpret = _interpret_default()
-    mask_kv = pk != s_kv
-    qf, kf, vf = _flat3(q), _flat3(k), _flat3(v)
-    if pq != s_q:
-        # padded q rows are ZERO (s = 0·k exactly — no overflow risk in the
-        # backward's p = exp(s − lse)) and sliced off below; the slice's
-        # transpose zero-pads their cotangent, so autodiff needs no help
-        qf = jnp.pad(qf, ((0, 0), (0, pq - s_q), (0, 0)))
-    if mask_kv:
-        kf = jnp.pad(kf, ((0, 0), (0, pk - s_kv), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, pk - s_kv), (0, 0)))
-    kv_stop = k_start + s_kv  # global position the REAL kv columns end at
-    out, lse = _flash(qf, kf, vf, q_start, k_start, kv_stop, causal, bq, bk, interpret, mask_kv)
-    if pq != s_q:
-        out = out[:, :s_q]
-        lse = lse[:, :s_q]
+    out, lse = _attend((_flat3(q), _flat3(k), _flat3(v)), d, causal, q_start, k_start, block_q, block_k, interpret)
     return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
 
 
@@ -982,29 +1148,20 @@ def flash_block_grads(
     bk, pk = _pad_choice(s_kv, block_k)
     if interpret is None:
         interpret = _interpret_default()
-    mask_kv = pk != s_kv
-    qf, of, dof = _flat3(q), _flat3(out), _flat3(do)
-    kf, vf = _flat3(k), _flat3(v)
-    lse_f = lse.reshape(b * h, s_q).astype(jnp.float32)
+    # padded q rows: q = 0 ⇒ s = 0 exactly and do = 0 ⇒ ds = 0, so a
+    # zero-padded lse (p = exp(0 − 0) = 1) contributes nothing anywhere
+    # a real gradient lands; their dq rows are sliced off below
+    qf, of, dof = (_pad_rows(_flat3(t), pq) for t in (q, out, do))
+    kf, vf = (_pad_rows(_flat3(t), pk) for t in (k, v))
+    lse_f = _pad_rows(lse.reshape(b * h, s_q).astype(jnp.float32), pq)
     glse_f = (
         jnp.zeros_like(lse_f) if g_lse is None
-        else g_lse.reshape(b * h, s_q).astype(jnp.float32)
+        else _pad_rows(g_lse.reshape(b * h, s_q).astype(jnp.float32), pq)
     )
-    if pq != s_q:
-        pad3 = ((0, 0), (0, pq - s_q), (0, 0))
-        qf, of, dof = (jnp.pad(t, pad3) for t in (qf, of, dof))
-        # padded q rows: q = 0 ⇒ s = 0 exactly and do = 0 ⇒ ds = 0, so a
-        # zero-padded lse (p = exp(0 − 0) = 1) contributes nothing anywhere
-        # a real gradient lands; their dq rows are sliced off below
-        lse_f = jnp.pad(lse_f, ((0, 0), (0, pq - s_q)))
-        glse_f = jnp.pad(glse_f, ((0, 0), (0, pq - s_q)))
-    if mask_kv:
-        pad3 = ((0, 0), (0, pk - s_kv), (0, 0))
-        kf, vf = jnp.pad(kf, pad3), jnp.pad(vf, pad3)
     lse8 = jnp.broadcast_to(lse_f[:, None, :], (b * h, 8, pq))
     dq, dk, dv = _flash_bwd(
-        qf, kf, vf, of, lse8, dof, glse_f, q_start, k_start, k_start + s_kv,
-        causal, bq, bk, interpret, mask_kv,
+        (qf, kf, vf), of, lse8, dof, glse_f, q_start, k_start, k_start + s_kv,
+        causal, bq, bk, interpret, pk != s_kv, d,
     )
     dq = dq[:, :s_q].astype(jnp.float32).reshape(b, h, s_q, d)
     dk = dk[:, :s_kv].astype(jnp.float32).reshape(b, h, s_kv, d)

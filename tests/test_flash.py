@@ -344,8 +344,18 @@ def test_flash_bf16_matches_float32_reference(causal, seq, d):
         )
 
 
+@pytest.fixture
+def fresh_traces():
+    """The kernels' calls are jitted (``_flash_fwd``, ``_flash_bwd_calls``), so a
+    like call is traced once a process. A test that patches what a kernel body
+    reads while it is traced drops the traces before its second run, and the
+    ones it leaves behind."""
+    yield jax.clear_caches
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_tile_classes_equal_masked_body(monkeypatch, dtype):
+def test_flash_tile_classes_equal_masked_body(monkeypatch, fresh_traces, dtype):
     """Traced offsets that put a tile in each class — q rows 256..511
     against k columns 128..639 in 128-blocks: (0,0) clear, (0,1) crossed,
     (0,2) and (0,3) skipped, (1,0) and (1,1) clear, (1,2) crossed, (1,3)
@@ -374,6 +384,7 @@ def test_flash_tile_classes_equal_masked_body(monkeypatch, dtype):
         flash.pl.when(flash._seen(q0, k0, block_q))(lambda: compute(True))
 
     monkeypatch.setattr(flash, "_per_tile_class", every_tile_masked)
+    fresh_traces()
     for got, masked in zip(by_class, run()):
         np.testing.assert_allclose(got, masked, rtol=1e-6, atol=1e-6)
 
@@ -540,3 +551,128 @@ def test_fused_backward_through_the_vjp_matches_attention(causal):
     for g, e in zip(jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2)))(q, k, v),
                     jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the projections' own layout: heads indexed out of [batch, seq, heads·head_dim]
+# ---------------------------------------------------------------------------
+
+# heads, head_dim, kv heads, seq, causal, q/k/v as one fused array, the backward as the pair
+_PACKED = {
+    "2-heads-of-64": (2, 64, 2, 256, True, True, False),
+    "4-heads-of-64": (4, 64, 4, 256, True, True, False),  # two lane blocks
+    "2-heads-of-128": (2, 128, 2, 256, True, True, False),  # one head a block: the head-major body exactly
+    "grouped-4-on-2": (4, 64, 2, 256, True, False, False),  # kv heads repeated by the caller, three arrays
+    "not-causal": (2, 64, 2, 256, False, True, False),
+    "padded-203": (2, 64, 2, 203, True, True, False),  # the blocks do not divide it: split, padded, kv tail masked
+    "pair-2-heads-of-64": (2, 64, 2, 256, True, False, True),  # flash_dq + flash_dkv, both two heads a block
+}
+
+
+def _packed_case(name):
+    """(inputs, loss through the packed entry, through the head-major entry,
+    through plain attention): each loss weighs ``out`` AND ``lse``."""
+    from dsml_tpu.ops.flash import flash_attention_lse, flash_attention_packed
+
+    heads, hd, kv, seq, causal, fused, _ = _PACKED[name]
+    rng = np.random.default_rng(heads * hd + seq)
+    widths = (heads * hd, kv * hd, kv * hd)
+    inputs = [jnp.asarray(rng.standard_normal((2, seq, w)), jnp.float32) for w in widths]
+    if fused:
+        inputs = [jnp.concatenate(inputs, -1)]
+    w_out = jnp.asarray(rng.standard_normal((2, seq, heads * hd)), jnp.float32)
+    w_lse = jnp.asarray(rng.standard_normal((2, heads, seq)), jnp.float32)
+
+    def split(inputs):  # q, k, v as [b, s, heads, hd], grouped k and v repeated
+        q, k, v = jnp.split(inputs[0], 3, -1) if fused else inputs
+        q, k, v = (t.reshape(2, seq, -1, hd) for t in (q, k, v))
+        return q, jnp.repeat(k, heads // kv, 2), jnp.repeat(v, heads // kv, 2)
+
+    def weigh(out, lse):
+        return (out * w_out).sum() + (lse * w_lse).sum()
+
+    def packed(*inputs):
+        qkv = inputs[0] if fused else [t.reshape(2, seq, -1) for t in split(inputs)]
+        return weigh(*flash_attention_packed(qkv, hd, causal, block_q=128, block_k=128))
+
+    def head_major(*inputs):
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in split(inputs))
+        out, lse = flash_attention_lse(q, k, v, causal, block_q=128, block_k=128)
+        return weigh(out.transpose(0, 2, 1, 3).reshape(2, seq, -1), lse)
+
+    def plain(*inputs):
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in split(inputs))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * hd**-0.5
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -1e30)
+        out = attention(q, k, v, causal).transpose(0, 2, 1, 3).reshape(2, seq, -1)
+        return weigh(out, jax.scipy.special.logsumexp(s, -1))
+
+    return inputs, packed, head_major, plain
+
+
+@pytest.mark.parametrize("against", ["attention", "head_major"])
+@pytest.mark.parametrize("case", list(_PACKED))
+def test_packed_entry_matches(monkeypatch, case, against):
+    """``flash_attention_packed`` (q, k, v read out of the projections' own
+    ``[b, s, heads·hd]``, ``128 // hd`` heads a grid step) against plain
+    attention and against the head-major entry: ``out``, ``lse`` and the
+    gradients of q, k, v under a cotangent on both."""
+    from dsml_tpu.ops import flash
+
+    if _PACKED[case][-1]:
+        monkeypatch.setattr(flash, "_VMEM_BUDGET", 0)
+    inputs, packed, head_major, plain = _packed_case(case)
+    argnums = tuple(range(len(inputs)))
+    wanted = ["flash_fwd", "flash_dq", "flash_dkv"] if _PACKED[case][-1] else ["flash_fwd", "flash_dkv"]
+    assert _bwd_kernels(jax.grad(packed, argnums), *inputs) == wanted
+    got = jax.jit(jax.value_and_grad(packed, argnums))(*inputs)
+    other = plain if against == "attention" else head_major
+    want = jax.jit(jax.value_and_grad(other, argnums))(*inputs)
+    # the same operations in the same order as the head-major kernels; plain attention sums another way
+    tol = 1e-4 if against == "attention" else 1e-5
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=tol)
+    for g, e in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=tol, atol=tol)
+
+
+def test_packed_entry_refuses_heads_that_do_not_fill_lane_blocks():
+    from dsml_tpu.ops.flash import flash_attention_packed, flash_packs
+
+    assert flash_packs(12, 64) and flash_packs(20, 64) and flash_packs(2, 64)
+    assert not flash_packs(3, 64)  # 192 lanes: half a block left over
+    assert not flash_packs(20, 128)  # one head a block: readable packed, measured slower (PERF.md §6)
+    assert not flash_packs(8, 8) and not flash_packs(4, 32) and not flash_packs(4, 96) and not flash_packs(2, 256)
+    with pytest.raises(ValueError, match="128-lane blocks"):
+        flash_attention_packed(jnp.zeros((1, 128, 3 * 192)), 64)
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_model_at_head_64_equals_the_head_major_route(monkeypatch, family):
+    """A tiny model whose heads fill 128-lane blocks takes the packed entry
+    (``_flash_packs``: chosen from shapes); its loss and every gradient equal
+    what the head-major route gives, forced by denying the rule."""
+    from dsml_tpu.models import gpt2, llama
+    from dsml_tpu.ops import flash
+
+    sizes = dict(vocab_size=512, max_seq=128, n_layer=2, n_head=4, d_model=256, d_ff=256)
+    model = (gpt2.GPT2(gpt2.GPT2Config(**sizes)) if family == "gpt2"
+             else llama.Llama(llama.LlamaConfig(n_kv_head=2, **sizes)))
+    params = model.init(0)
+    rng = np.random.default_rng(33)
+    tokens, targets = (jnp.asarray(rng.integers(0, 512, size=(2, 128)), jnp.int32) for _ in range(2))
+
+    def run():
+        fn = jax.value_and_grad(lambda p: model.loss_spmd(p, tokens, targets, attn_impl="flash"))
+        text = str(jax.make_jaxpr(fn)(params))
+        return jax.jit(fn)(params), text.count("transpose[")
+
+    (loss, grads), transposes = run()
+    monkeypatch.setattr(flash, "flash_packs", lambda n_head, head_dim: False)
+    (loss_major, grads_major), transposes_major = run()
+    assert transposes < transposes_major  # the head-major copies are what the packed route leaves out
+    np.testing.assert_allclose(float(loss), float(loss_major), rtol=1e-6)
+    flat, flat_major = jax.tree.leaves(grads), jax.tree.leaves(grads_major)
+    assert len(flat) == len(flat_major)
+    for g, e in zip(flat, flat_major):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-6)

@@ -154,6 +154,72 @@ def test_flash_compiles_at_other_geometries(topo, shape, what):
     assert _flash_kernels_in(text) == (["flash_fwd"] if what == "fwd" else ["flash_fwd", "flash_dkv"])
 
 
+# the packed entry at the cells' four layers: q, k, v read out of the projections' own
+# [batch, seq, heads·head_dim] by 128-lane blocks (two heads of 64 a grid step at 512x512
+# and at 1024x1024 blocks; one head of 128 on a repeated key-value head, which the models'
+# rule leaves head-major and scripts/attn_layer_check.py still times packed)
+@pytest.mark.parametrize("batch,seq,heads,head_dim", [
+    (32, 1024, 12, 64), (4, 8192, 12, 64), (4, 1024, 20, 64), (1, 8192, 20, 128),
+], ids=["gpt2s-1k", "gpt2s-8k", "gpt2l-1k", "jamba2-3b-8k"])
+def test_packed_flash_compiles_at_the_cells_geometries(topo, batch, seq, heads, head_dim):
+    from dsml_tpu.ops.flash import flash_attention_packed
+
+    def loss(*qkv):
+        out, _ = flash_attention_packed(qkv[0] if len(qkv) == 1 else qkv, head_dim, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    d = heads * head_dim
+    # GPT-2: the fused projection's one array; Jamba: three (k and v repeated to the query heads)
+    qkv = [_sds((batch, seq, 3 * d), jnp.bfloat16)] if head_dim == 64 else [_sds((batch, seq, d), jnp.bfloat16)] * 3
+    text = _compile(topo, jax.grad(loss, argnums=tuple(range(len(qkv)))), *qkv)
+    calls = _kernel_calls(text)
+    assert _flash_kernels_in(text) == ["flash_fwd", "flash_dkv"]
+    assert all(kernel in name for (name, _), kernel in zip(calls, ["flash_fwd", "flash_dkv"])), calls
+    # nothing head-major is made on either side of the kernels
+    assert not re.findall(rf"\[{batch},{heads},{seq},{head_dim}\]|\[{batch * heads},{seq},{head_dim}\]", text)
+
+
+def _head_layout_copies(text, heads, head_dim):
+    """The ``copy`` / ``transpose`` instructions under ``attn`` in compiled
+    ``text`` whose result is a 4-D array holding ``heads`` and ``head_dim``
+    as dimensions of their own: the head-major relayouts round the kernels."""
+    found = []
+    for line in text.splitlines():
+        hit = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        if hit and "attn" in line:
+            dims = [int(n) for n in hit.group(1).split(",")]
+            if len(dims) == 4 and heads in dims and head_dim in dims:
+                found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("heads,head_dim,packs", [(12, 64, True), (24, 32, False), (6, 128, False)],
+                         ids=["head64-packed", "head32-head-major", "head128-head-major"])
+def test_gpt2_block_holds_head_layout_copies_only_off_the_packed_path(topo, monkeypatch, heads, head_dim, packs):
+    """The engagement counter of the packed path, as a test: the compiled
+    ``jax.grad`` of one GPT-2 attention block at ``[4, 1024, 768]`` holds no
+    copy or transpose of a head-major 4-D array where the shape rule packs
+    (12 heads of 64) and still holds the parent's eight where it does not
+    (24 heads of 32, 6 of 128)."""
+    from dsml_tpu.models.gpt2 import GPT2, GPT2Config
+    from dsml_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_interpret_default", lambda: False)  # compile the kernels, not the interpreter
+    assert flash.flash_packs(heads, head_dim) == packs
+    model = GPT2(GPT2Config(vocab_size=512, max_seq=1024, n_layer=1, n_head=heads, d_model=768, d_ff=3072))
+    layer = jax.tree.map(lambda leaf: _sds(leaf.shape, jnp.bfloat16),
+                         jax.eval_shape(lambda: model.init(0))["layers"][0])
+
+    def loss(layer, x):
+        with jax.named_scope("attn"):
+            return (x + model._attn_block(layer, x, heads, None, None, "flash")).astype(jnp.float32).sum()
+
+    text = _compile(topo, jax.grad(loss, argnums=(0, 1)), layer, _sds((4, 1024, 768), jnp.bfloat16))
+    assert _flash_kernels_in(text) == ["flash_fwd", "flash_dkv"]
+    copies = _head_layout_copies(text, heads, head_dim)
+    assert (not copies) if packs else len(copies) == 8, copies
+
+
 # the selective-scan pair at Jamba2-3B's Mamba geometry and the cell's length: one row of
 # 8192, 5120 channels (40 lane tiles), state 16 (two float32 sublane tiles), bf16 in and out
 @pytest.fixture(scope="module")
