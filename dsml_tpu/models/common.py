@@ -43,6 +43,12 @@ def transformer_train_flops(cfg, n_tokens: int, seq: int,
     return 3 * fwd
 
 
+def head_dim(cfg) -> int:
+    """A head's width: the config's own ``head_dim`` where it names one, else
+    ``d_model // n_head``."""
+    return getattr(cfg, "head_dim", 0) or cfg.d_model // cfg.n_head
+
+
 def mlp_train_flops(n_params: int, n_samples: int) -> int:
     """The dense-MLP rule the reference baseline is scored by: 6 FLOPs per
     parameter per sample (fwd 2 + bwd 4)."""

@@ -1,0 +1,167 @@
+"""A layer of sparse gated experts as it is deployed: every token goes to its
+``top_k`` experts, none is dropped, and the work is a function of shapes alone.
+
+``y_t = Σ_e w_te · (SiLU(x_t·Wg_e) ⊙ (x_t·Wu_e))·Wd_e`` over the ``top_k`` experts
+``e`` with the largest router probabilities ``softmax(x_t·Wr)``, their weights
+renormalised to sum to one. Four steps, each under its own scope:
+
+- ``moe_route``: the router in float32, ``top_k``, and the plan: the ``T·top_k``
+  (token, expert) pairs stable-sorted by expert into one row buffer, each
+  expert's rows padded to whole tiles (:func:`plan`). The plan is integers only
+  and is tagged (:data:`PLAN_NAMES`) so that a block recomputed in the backward
+  can keep it and not sort twice.
+- ``moe_dispatch``: the rows gathered into the buffer ``[rows, d]``.
+- ``experts``: three grouped matmuls (``ops/grouped_matmul.py``: ``gmm_fwd``,
+  and ``gmm_dx`` / ``gmm_dw`` in the backward) with the gate between them.
+- ``moe_combine``: each token's ``top_k`` rows gathered back and summed under
+  its weights.
+
+Dispatch and combine are gathers in both directions (a row's cotangent is read
+from where its pair went; nothing is scattered), so they carry their own
+backward. The buffer holds ``T·min(top_k, held) / tile + held`` tiles whatever
+the router does (``n_row_tiles``) and every one is computed: at uniform routing
+and with every token on one set of ``top_k`` experts the layer does the same
+work (PERF.md §6, PR 35: a cell whose work followed the router could not be
+measured).
+
+``experts_held = (first, count)`` is the chip's share of a deployment that
+spreads a layer's experts over several chips: the router stays as wide as
+published and picks over all experts; the pairs of experts that are not held
+get no row, and the layer returns the held experts' part of ``y``. The shares
+of all chips add up to the whole layer (``tests/test_mellum.py``). No code
+stands in for the other chips or their exchange.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from dsml_tpu.ops.grouped_matmul import grouped_matmul, n_row_tiles
+
+__all__ = ["PLAN_NAMES", "expert_layer", "plan", "route"]
+
+# the integers of a layer's routing: under `jax.checkpoint` with
+# `save_only_these_names(*PLAN_NAMES)` the recomputed forward reads them back (0.9 MB a layer at
+# 8,192 tokens) where it would run top-k, the sort and the index arithmetic a second time
+PLAN_NAMES = ("moe_top_e", "moe_row_pair", "moe_dest", "moe_tile_group")
+
+
+def route(x, w_router, top_k: int):
+    """``(top_e [T, k] int32, w [T, k] float32)``: each token's ``top_k``
+    experts by router probability (float32 logits and softmax; ties to the
+    lower index) and their probabilities renormalised to sum to one."""
+    logits = jnp.dot(x, w_router, preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(logits, axis=-1)
+    top_e = checkpoint_name(lax.top_k(lax.stop_gradient(p), top_k)[1].astype(jnp.int32), "moe_top_e")
+    # the chosen probabilities by a 0/1 product, not a gather: its transpose is a product too
+    chosen = top_e[:, :, None] == jnp.arange(p.shape[-1], dtype=jnp.int32)
+    top_p = jnp.sum(jnp.where(chosen, p[:, None, :], 0.0), axis=-1)
+    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def plan(top_e, held: tuple[int, int], tile: int):
+    """Where each (token, expert) pair goes, from ``top_e [T, k]`` alone:
+    ``(row_pair [rows], dest [T, k], tile_group [rows / tile])``, all int32.
+
+    The pairs of the ``count`` experts from ``first`` on, stable-sorted by
+    expert, lie in one buffer, each expert's run starting on a tile boundary
+    and owning a tile at least; the tiles past the last run belong to the last
+    expert and hold no pair. ``row_pair[r]`` is the pair ``t·k + j`` in row
+    ``r`` (-1: padding), ``dest[t, j]`` the row of pair ``(t, j)`` (-1: its
+    expert is not held), ``tile_group[i]`` the expert (counted from ``first``)
+    whose matrix tile ``i`` multiplies."""
+    first, count = held
+    (n_tokens, k), pairs = top_e.shape, top_e.size
+    n_tiles = n_row_tiles(n_tokens * min(k, count), count, tile)
+    expert = top_e.reshape(pairs) - first
+    is_held = (expert >= 0) & (expert < count)
+    key = jnp.where(is_held, expert, count)  # absent experts sort last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # the pair at each sorted position
+    member = key[:, None] == jnp.arange(count, dtype=jnp.int32)  # [pairs, count]: the pair's expert, one-hot
+    sizes = jnp.sum(member, axis=0, dtype=jnp.int32)
+    start = jnp.cumsum(sizes) - sizes  # of each group among the sorted pairs
+    tiles = jnp.maximum(1, -(-sizes // tile))
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tile  # of each group in the buffer
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32), side="right"), count - 1
+    ).astype(jnp.int32)
+    group = jnp.repeat(tile_group, tile)
+    offset = jnp.arange(n_tiles * tile, dtype=jnp.int32) - row_start[group]
+    row_pair = jnp.where(offset < sizes[group], order[jnp.clip(start[group] + offset, 0, pairs - 1)], -1)
+    # a pair's place in its group is its rank among the pairs of that expert: the sort is stable
+    rank = jnp.sum(jnp.where(member, jnp.cumsum(member, axis=0, dtype=jnp.int32), 0), axis=1) - 1
+    dest = jnp.where(is_held, row_start[jnp.minimum(key, count - 1)] + rank, -1).reshape(n_tokens, k)
+    return (checkpoint_name(row_pair, "moe_row_pair"), checkpoint_name(dest, "moe_dest"),
+            checkpoint_name(tile_group, "moe_tile_group"))
+
+
+@jax.custom_vjp
+def _dispatch(x, row_pair, dest):
+    """``x [T, d]`` -> the buffer ``[rows, d]``; padding rows hold token 0's
+    (no one reads them, and their cotangent arrives as zeros)."""
+    return x[jnp.maximum(row_pair, 0) // dest.shape[1]]
+
+
+def _dispatch_fwd(x, row_pair, dest):
+    return _dispatch(x, row_pair, dest), dest
+
+
+def _dispatch_bwd(dest, d_rows):
+    with jax.named_scope("moe_dispatch"):
+        picked = d_rows[jnp.maximum(dest, 0)].astype(jnp.float32)  # [T, k, d]
+        dx = jnp.sum(jnp.where((dest >= 0)[:, :, None], picked, 0.0), axis=1)
+    return dx.astype(d_rows.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, w, row_pair, dest):
+    """``y [T, d] = Σ_j w[t, j] · rows[dest[t, j]]`` in float32, over the
+    pairs that have a row."""
+    picked = rows[jnp.maximum(dest, 0)].astype(jnp.float32)
+    return jnp.sum(jnp.where(dest >= 0, w, 0.0)[:, :, None] * picked, axis=1).astype(rows.dtype)
+
+
+def _combine_fwd(rows, w, row_pair, dest):
+    return _combine(rows, w, row_pair, dest), (rows, w, row_pair, dest)
+
+
+def _combine_bwd(res, dy):
+    rows, w, row_pair, dest = res
+    with jax.named_scope("moe_combine"):
+        pair = jnp.maximum(row_pair, 0)
+        row_w = jnp.where(row_pair >= 0, w.reshape(-1)[pair], 0.0)
+        d_rows = (row_w[:, None] * dy[pair // dest.shape[1]].astype(jnp.float32)).astype(rows.dtype)
+        picked = rows[jnp.maximum(dest, 0)].astype(jnp.float32)
+        dw = jnp.where(dest >= 0, jnp.sum(picked * dy.astype(jnp.float32)[:, None, :], axis=-1), 0.0)
+    return d_rows, dw.astype(w.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def expert_layer(p: dict, x, *, top_k: int, tile: int, experts_held: tuple[int, int] | None = None):
+    """``x [T, d]`` -> the held experts' part of the layer's output ``[T, d]``.
+    ``p``: ``router [d, E]``, and of the experts held ``w_gate``, ``w_up``
+    ``[held, d, f]``, ``w_down [held, f, d]``. ``tile`` is the row tile of the
+    grouped matmuls; ``experts_held = (first, count)``, by default all."""
+    held = experts_held or (0, p["router"].shape[1])
+    if p["w_gate"].shape[0] != held[1]:
+        raise ValueError(f"{p['w_gate'].shape[0]} experts' weights for experts_held={held}")
+    with jax.named_scope("moe_route"):
+        top_e, w = route(x, p["router"], top_k)
+        row_pair, dest, tile_group = plan(top_e, held, tile)
+    with jax.named_scope("moe_dispatch"):
+        rows = _dispatch(x, row_pair, dest)
+    with jax.named_scope("experts"):
+        mid = jax.nn.silu(grouped_matmul(rows, p["w_gate"], tile_group, tile)) * grouped_matmul(
+            rows, p["w_up"], tile_group, tile)
+        rows = grouped_matmul(mid, p["w_down"], tile_group, tile)
+    with jax.named_scope("moe_combine"):
+        return _combine(rows, w, row_pair, dest)

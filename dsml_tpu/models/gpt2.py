@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dsml_tpu.models.common import fsdp_spec_fn, maybe_dequant, qmatmul
+from dsml_tpu.models.common import fsdp_spec_fn, head_dim, maybe_dequant, qmatmul
 from dsml_tpu.ops.attention import _NEG_INF, attention, ring_attention, ulysses_attention
 
 __all__ = ["GPT2Config", "GPT2"]
@@ -523,8 +523,7 @@ class GPT2:
         from dsml_tpu.ops.flash import flash_packs
 
         unsharded = not sp_axis or lax.axis_size(sp_axis) == 1
-        head_dim = self.config.d_model // self.config.n_head
-        return unsharded and attn_impl in self._FLASH_IMPLS and flash_packs(n_head_local, head_dim)
+        return unsharded and attn_impl in self._FLASH_IMPLS and flash_packs(n_head_local, head_dim(self.config))
 
     def _attn_block(self, layer, h, n_head_local, tp_axis, sp_axis, attn_impl):
         x = _layer_norm(h, **layer["ln_1"])
@@ -936,7 +935,7 @@ class GPT2:
 
     def _cache_entry(self, batch: int, n_heads: int) -> dict:
         cfg = self.config
-        hd = cfg.d_model // cfg.n_head
+        hd = head_dim(cfg)
         mode = self._kv_mode()
         if mode:
             if mode == "int4":
@@ -1378,7 +1377,7 @@ class GPT2:
                 f"need n_pages >= 2 (page 0 is the scratch page), got {n_pages}"
             )
         mode = self._page_mode(quant)
-        hd = cfg.d_model // cfg.n_head
+        hd = head_dim(cfg)
         n_heads = getattr(cfg, "n_kv_head", cfg.n_head) // tp_size
         if mode == "int4":
             if hd % 2:
@@ -1457,7 +1456,7 @@ class GPT2:
         # of dying inside Mosaic at compile time)
         use_pallas = paged_attn_impl(
             page_size=pool[0]["k"].shape[2],
-            head_dim=self.config.d_model // self.config.n_head,
+            head_dim=head_dim(self.config),
             mode=mode,
         ) == "pallas"
         b_q, c_q = h.shape[0], h.shape[1]

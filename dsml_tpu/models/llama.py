@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dsml_tpu.models.common import maybe_dequant, qmatmul
+from dsml_tpu.models.common import head_dim, maybe_dequant, qmatmul
 from dsml_tpu.models.gpt2 import GPT2
 from dsml_tpu.ops.attention import _NEG_INF
 
@@ -56,6 +56,7 @@ class LlamaConfig:
     n_kv_head: int = 4  # GQA: kv heads grouped under query heads
     d_model: int = 2048
     d_ff: int = 5632  # SwiGLU hidden width
+    head_dim: int = 0  # a head's width where it is not d_model // n_head (0): q is then n_head·head_dim wide
     dtype: str = "float32"
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
@@ -160,8 +161,7 @@ class Llama(GPT2):
         cfg = self.config
         rng = np.random.default_rng(seed)
         dt = jnp.dtype(cfg.dtype)
-        hd = cfg.d_model // cfg.n_head
-        kv_d = cfg.n_kv_head * hd
+        q_d, kv_d = cfg.n_head * head_dim(cfg), cfg.n_kv_head * head_dim(cfg)
 
         def normal(*shape, std=0.02):
             return jnp.asarray(rng.standard_normal(shape) * std, dt)
@@ -176,10 +176,10 @@ class Llama(GPT2):
                     "rms_1": {"scale": jnp.ones(cfg.d_model, dt)},
                     "rms_2": {"scale": jnp.ones(cfg.d_model, dt)},
                     "attn": {
-                        "wq": normal(cfg.d_model, cfg.d_model),
+                        "wq": normal(cfg.d_model, q_d),
                         "wk": normal(cfg.d_model, kv_d),
                         "wv": normal(cfg.d_model, kv_d),
-                        "wo": normal(cfg.d_model, cfg.d_model, std=res_std),
+                        "wo": normal(q_d, cfg.d_model, std=res_std),
                     },
                     **(
                         {"moe": self._moe_param_init(normal, res_std)}
@@ -209,16 +209,16 @@ class Llama(GPT2):
 
         cfg = self.config
         d, ff, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
-        kv_d = cfg.n_kv_head * (cfg.d_model // cfg.n_head)
+        q_d, kv_d = cfg.n_head * head_dim(cfg), cfg.n_kv_head * head_dim(cfg)
         F = fsdp_spec_fn(fsdp)
         layer_spec = {
             "rms_1": {"scale": F(P(), d)},
             "rms_2": {"scale": F(P(), d)},
             "attn": {
-                "wq": F(P(None, "tp"), d, d),
+                "wq": F(P(None, "tp"), d, q_d),
                 "wk": F(P(None, "tp"), d, kv_d),
                 "wv": F(P(None, "tp"), d, kv_d),
-                "wo": F(P("tp", None), d, d),
+                "wo": F(P("tp", None), q_d, d),
             },
         }
         if cfg.n_experts:
@@ -284,7 +284,7 @@ class Llama(GPT2):
         both the training and serving paths. Head-major ``[b, h, s, hd]``,
         or with ``head_axis=2`` the projections' own ``[b, s, h, hd]`` (a
         reshape, no copy: what the packed flash entry reads)."""
-        hd = self.config.d_model // self.config.n_head
+        hd = head_dim(self.config)
 
         def heads(t, n):
             b, s, _ = t.shape

@@ -18,6 +18,7 @@ from dsml_tpu.ops.flash import (  # noqa: F401
     flash_block_grads,
     ring_flash_attention,
 )
+from dsml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401
 from dsml_tpu.ops.ring_attention import (  # noqa: F401
     causal_keep_fraction,
     ring_kv_wire_bytes,

@@ -89,6 +89,11 @@ cp/ring shards make odd residual lengths the common case.
 Causal blocks entirely above the diagonal are skipped via ``pl.when``
 predication (a dynamic predicate when offsets are traced); the forward also
 runs blocks entirely below it, and before ``kv_stop``, with no mask at all.
+A sliding ``window`` (one more static argument of every entry point; ``None``
+leaves the kernels as they were) adds the second edge: blocks wholly older
+than every query's window are skipped the same way, in the forward and in the
+backward, and a block that neither edge crosses runs the unmasked body. The
+grid still steps over a skipped block and fetches its operands.
 On non-TPU backends the same kernels run under the Pallas interpreter
 (``interpret=True``), which is how tests validate them on the CI CPU mesh;
 on TPU they compile through Mosaic.
@@ -323,14 +328,15 @@ def _weave(parts, heads):
     return out
 
 
-def _mask(s, q0, k0, kv_stop, causal, mask_kv, q_axis):
+def _mask(s, q0, k0, kv_stop, causal, mask_kv, q_axis, window=None):
     """Mask the score tile ``s`` whose first query / key sit at global
     positions ``q0`` / ``k0``; queries lie along ``q_axis`` of ``s`` (0, or
     1 for the transposed tile of the dkv kernel). A key survives when it is
     at or before its query (causal) and before ``kv_stop`` (zero-padded kv
     tail: its columns must not enter the softmax denominator). Positions
     are one column and one row vector, so the tile itself sees one compare
-    and one select."""
+    and one select; a ``window`` (the last ``window`` keys, the query's own
+    among them) is a second compare."""
     def pos(start, axis):
         shape = [1, 1]
         shape[axis] = s.shape[axis]
@@ -341,19 +347,28 @@ def _mask(s, q0, k0, kv_stop, causal, mask_kv, q_axis):
         last = pos(q0, q_axis)
     if mask_kv:
         last = kv_stop - 1 if last is None else jnp.minimum(last, kv_stop - 1)
-    return jnp.where(pos(k0, 1 - q_axis) <= last, s, _NEG_INF)
+    keys = pos(k0, 1 - q_axis)
+    kept = keys <= last
+    if window is not None:
+        kept = jnp.logical_and(kept, keys > pos(q0, q_axis) - window)
+    return jnp.where(kept, s, _NEG_INF)
 
 
-def _seen(q0, k0, block_q):
-    """False for a tile whose every key is in the future of its every query:
-    a causal kernel skips it (a dynamic predicate: offsets are traced)."""
-    return k0 <= q0 + block_q - 1
+def _seen(q0, k0, block_q, block_k=None, window=None):
+    """False for a tile whose every key is in the future of its every query,
+    or (``window``) older than the window of its every query: a causal
+    kernel skips it (a dynamic predicate: offsets are traced)."""
+    seen = k0 <= q0 + block_q - 1
+    if window is not None:
+        seen = jnp.logical_and(seen, k0 + block_k - 1 > q0 - window)
+    return seen
 
 
-def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k):
+def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k, window=None):
     """Run the forward's ``compute(masked)`` as the tile's place demands.
     Three classes, told apart by SMEM scalars: every key in the future of
-    every query — skipped; every key at or before every query and before
+    every query, or older than every query's ``window`` — skipped; every key
+    at or before every query, inside every query's window and before
     ``kv_stop`` — the body with no mask at all; anything else — the masked
     body. The backward kernels run the masked body on every tile they do
     not skip: a second body measured no faster there and every body is
@@ -364,11 +379,13 @@ def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k)
     clear = True  # nothing in the tile is masked
     if causal:
         clear = k0 + block_k - 1 <= q0
+    if window is not None:
+        clear = jnp.logical_and(clear, k0 > q0 + block_q - 1 - window)
     if mask_kv:
         clear = jnp.logical_and(clear, k0 + block_k <= kv_stop)
     crossed = jnp.logical_not(clear)
     if causal:
-        crossed = jnp.logical_and(crossed, _seen(q0, k0, block_q))
+        crossed = jnp.logical_and(crossed, _seen(q0, k0, block_q, block_k, window))
     pl.when(clear)(lambda: compute(False))
     pl.when(crossed)(lambda: compute(True))
 
@@ -378,7 +395,7 @@ def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k)
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv, qi=None, ki=None):
+def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv, window=None, qi=None, ki=None):
     # qi/ki may be pre-read grid indices: a wrapping kernel that delegates
     # here from inside pl.when must hoist its program_id reads to the top
     # level — interpret mode substitutes the primitive only when it's bound
@@ -410,7 +427,7 @@ def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, 
                 if not fold:
                     s = s * scale
                 if masked:
-                    s = _mask(s, q0 + rows.start, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
+                    s = _mask(s, q0 + rows.start, k0, kstop_ref[0], causal, mask_kv, q_axis=0, window=window)
                 m_prev = m_scr[j, rows]
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
                 corr = jnp.exp(m_prev - m_new)
@@ -421,7 +438,7 @@ def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, 
                 pvs.append(_dot(p.astype(v.dtype), v, _NN))
             acc[rows] = acc[rows] * _weave(corrs, heads) + _weave(pvs, heads)
 
-    _per_tile_class(compute, q0, k0, kstop_ref[0], causal, mask_kv, block_q, block_k)
+    _per_tile_class(compute, q0, k0, kstop_ref[0], causal, mask_kv, block_q, block_k, window)
 
     @pl.when(ki == kv_blocks - 1)
     def _finish():
@@ -451,8 +468,8 @@ def _operands(qkv, head_dim):
     return q, k, v, (0, 0, 0), lanes, q.shape[2] // lanes
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None):
     q, k, v, (q_at, k_at, v_at), lanes, groups = _operands(qkv, head_dim)
     batch, s_q = q.shape[:2]
     heads = lanes // head_dim
@@ -460,7 +477,7 @@ def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpr
 
     kernel = functools.partial(
         _fwd_kernel, head_dim=head_dim, causal=causal,
-        block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv,
+        block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv, window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -671,7 +688,7 @@ def flash_stream_hop(
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv):
+def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv, window=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     q0 = qs_ref[0] + qi * block_q
@@ -694,7 +711,7 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
             if not fold:
                 s = s * scale
             if causal or mask_kv:
-                s = _mask(s, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=0)
+                s = _mask(s, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=0, window=window)
             # the row statistics arrive lane-major over seq and are relaid as
             # columns on every tile: keeping the columns in scratch across the
             # kv blocks measured slower (PERF.md §6)
@@ -704,7 +721,7 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         acc[:] = acc[:] + _weave(dqs, heads)
 
     if causal:
-        pl.when(_seen(q0, k0, block_q))(compute)
+        pl.when(_seen(q0, k0, block_q, block_k, window))(compute)
     else:
         compute()
 
@@ -713,7 +730,7 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, q_blocks, kv_blocks, mask_kv):
+def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, q_blocks, kv_blocks, mask_kv, window=None):
     """``dk`` and ``dv`` of one kv block, accumulated over the q blocks; given
     a third output and scratch (``dq_ref``, ``dq_acc``) also ``dq``, from the
     tile it already holds.
@@ -774,7 +791,7 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             if not fold:
                 st = st * scale
             if causal or mask_kv:
-                st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1)
+                st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1, window=window)
             pt = jnp.exp(st - lse_ref[j, :1])
             dv_acc[mine] = dv_acc[mine] + _dot(do_t[mine], pt.astype(do.dtype), _NT)  # dvᵀ += doᵀ·p
             # cast once, feeds both its dots
@@ -783,8 +800,8 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             if dq_ref is not None:
                 dq_acc[qi, mine] = dq_acc[qi, mine] + _dot(k_t[mine], dst, _NN)  # dqᵀ[q block] += kᵀ·dsᵀ
 
-    if causal:  # q blocks entirely before this kv block see none of it
-        pl.when(_seen(q0, k0, block_q))(compute)
+    if causal:  # q blocks entirely before this kv block, or whose windows end before it, see none of it
+        pl.when(_seen(q0, k0, block_q, block_k, window))(compute)
     else:
         compute()
 
@@ -827,7 +844,7 @@ def _fused_bwd_vmem(s_q: int, d: int, block_q: int, block_k: int, itemsize: int)
     return resident + tile + blocks
 
 
-def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None):
     """``(dq, dk, dv)`` in the inputs' dtypes and layout, ``[B, S, W]`` each;
     where q, k, v came as one ``[B, S, 3·W]`` array ``dq`` is such an array
     too, written in q's lanes alone: the cotangent-to-be, whose other lanes
@@ -840,11 +857,11 @@ def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_
     q, _, _, _, lanes, _ = _operands(qkv, head_dim)
     vmem = _fused_bwd_vmem(q.shape[1], lanes, block_q, block_k, q.dtype.itemsize)
     return _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k,
-                            interpret, mask_kv, head_dim, vmem if vmem <= _VMEM_BUDGET else None)
+                            interpret, mask_kv, head_dim, vmem if vmem <= _VMEM_BUDGET else None, window)
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14))
-def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, vmem):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
+def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, vmem, window=None):
     """:func:`_flash_bwd`'s kernels, ``dq`` riding ``flash_dkv`` in ``vmem``
     bytes of VMEM or, with ``None``, the pair. Jitted, like ``_flash_fwd``:
     a model's like layers trace and lower each kernel once."""
@@ -891,7 +908,7 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
         dq = pl.pallas_call(
             functools.partial(
                 _dq_kernel, head_dim=head_dim, causal=causal,
-                block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv,
+                block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv, window=window,
             ),
             grid=(batch, groups, q_blocks, kv_blocks),
             in_specs=qrow,
@@ -916,7 +933,7 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
     dk, dv, *riding = pl.pallas_call(
         functools.partial(
             _dkv_kernel, head_dim=head_dim, causal=causal, block_q=block_q, block_k=block_k,
-            q_blocks=q_blocks, kv_blocks=kv_blocks, mask_kv=mask_kv,
+            q_blocks=q_blocks, kv_blocks=kv_blocks, mask_kv=mask_kv, window=window,
         ),
         grid=(batch, groups, kv_blocks, q_blocks),
         in_specs=krow,
@@ -937,25 +954,25 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None):
     """``qkv`` is ``(q, k, v)`` or the one array that holds all three
     (:func:`_operands`); ``(out [B, S, W], lse [B·heads, S])``."""
-    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim)
+    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window)
     return out, lse8[:, 0, :]
 
 
-def _flash_fwd_rule(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim):
-    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim)
+def _flash_fwd_rule(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window):
+    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window)
     return (out, lse8[:, 0, :]), (qkv, out, lse8, q_start, k_start, kv_stop)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, head_dim, res, g):
+def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, head_dim, window, res, g):
     qkv, out, lse8, q_start, k_start, kv_stop = res
     g_out, g_lse = g
     grads = _flash_bwd(
         qkv, out, lse8, g_out, g_lse.astype(jnp.float32), q_start, k_start, kv_stop, causal,
-        block_q, block_k, interpret, mask_kv, head_dim,
+        block_q, block_k, interpret, mask_kv, head_dim, window,
     )
     if len(qkv) == 1:  # one array in, one cotangent out: dk and dv set beside dq, as k and v lay beside q
         dq, dk, dv = grads
@@ -984,13 +1001,18 @@ def _pad_rows(t, rows: int):
     return jnp.pad(t, [(0, 0), (0, rows - t.shape[1])] + [(0, 0)] * (t.ndim - 2))
 
 
-def _attend(qkv, head_dim, causal, q_start, k_start, block_q, block_k, interpret):
+def _attend(qkv, head_dim, causal, q_start, k_start, block_q, block_k, interpret, window=None):
     """The differentiable call at ANY length on ``(q, k, v)``, ``[B, S, W]``
     each, or the one array ``[B, S, 3·W]`` that holds all three
     (:func:`_operands`): ``(out [B, S, W], lse [B·heads, S])``. Lengths the
     block ladder can't tile are zero-padded up to a block multiple (≤ 25%
     waste), the padded kv tail masked off inside the kernels via the
-    ``kv_stop`` SMEM scalar and the padded q rows sliced away."""
+    ``kv_stop`` SMEM scalar and the padded q rows sliced away. ``window``
+    (static; ``None`` = every earlier key) keeps of each query's keys the last
+    ``window``, its own among them: tiles wholly older than it are skipped as
+    the causal-future ones are."""
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"a window of {window} keys needs causal attention and at least the query's own key")
     s_q, s_kv = qkv[0].shape[1], qkv[-1].shape[1]
     block_q, block_k = _default_blocks(s_q, s_kv, block_q, block_k, head_dim)
     bq, pq = _pad_choice(s_q, block_q)
@@ -1005,7 +1027,7 @@ def _attend(qkv, head_dim, causal, q_start, k_start, block_q, block_k, interpret
         # transpose zero-pads their cotangent, so autodiff needs no help
         qkv = (_pad_rows(qkv[0], pq), _pad_rows(qkv[1], pk), _pad_rows(qkv[2], pk))
     kv_stop = k_start + s_kv  # global position the REAL kv columns end at
-    out, lse = _flash(qkv, q_start, k_start, kv_stop, causal, bq, bk, interpret, pk != s_kv, head_dim)
+    out, lse = _flash(qkv, q_start, k_start, kv_stop, causal, bq, bk, interpret, pk != s_kv, head_dim, window)
     return out[:, :s_q], lse[:, :s_q]
 
 
@@ -1032,6 +1054,7 @@ def flash_attention_packed(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ):
     """Flash attention in the projections' own layout: no head-major copy of
     q, k, v on the way in, nor of the output (and of ``do``, ``dq``, ``dk``,
@@ -1050,7 +1073,7 @@ def flash_attention_packed(
     n_head, rest = divmod(width, head_dim * (3 if len(qkv) == 1 else 1))
     if rest or head_dim not in (64, 128) or (n_head * head_dim) % 128:
         raise ValueError(f"{n_head} heads of {head_dim} do not fill 128-lane blocks: got {qkv[0].shape}")
-    out, lse = _attend(qkv, head_dim, causal, 0, 0, block_q, block_k, interpret)
+    out, lse = _attend(qkv, head_dim, causal, 0, 0, block_q, block_k, interpret, window)
     return out, lse.reshape(batch, n_head, seq)
 
 
@@ -1064,6 +1087,7 @@ def flash_attention_lse(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ):
     """Flash attention returning ``(out, lse)``. Shapes: q/k/v
     [batch, heads, seq, head_dim] → out same-as-q, lse [batch, heads, seq_q]
@@ -1077,10 +1101,11 @@ def flash_attention_lse(
     (≤ 25% waste), with the padded kv tail masked off inside the kernels via
     a ``kv_stop`` SMEM scalar and padded q rows sliced away — cp/ring shards
     make odd residual lengths the common case, so the kernel rather than an
-    XLA fallback must own them.
+    XLA fallback must own them. With ``window`` (causal only) query ``i`` sees
+    key ``j`` iff ``0 <= i - j < window``, by the same global positions.
     """
     b, h, s_q, d = q.shape
-    out, lse = _attend((_flat3(q), _flat3(k), _flat3(v)), d, causal, q_start, k_start, block_q, block_k, interpret)
+    out, lse = _attend((_flat3(q), _flat3(k), _flat3(v)), d, causal, q_start, k_start, block_q, block_k, interpret, window)
     return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
 
 
@@ -1092,6 +1117,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention. Shapes: [batch, heads, seq, head_dim].
 
@@ -1104,7 +1130,7 @@ def flash_attention(
     """
     if q.ndim != 4:
         raise ValueError(f"expected [batch, heads, seq, head_dim], got {q.shape}")
-    out, _ = flash_attention_lse(q, k, v, causal, 0, 0, block_q, block_k, interpret)
+    out, _ = flash_attention_lse(q, k, v, causal, 0, 0, block_q, block_k, interpret, window)
     return out
 
 
@@ -1122,6 +1148,7 @@ def flash_block_grads(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Raw flash backward of ONE (q-shard, kv-block) pair given MERGED
     statistics — the primitive ring attention's own backward re-streams KV
@@ -1161,7 +1188,7 @@ def flash_block_grads(
     lse8 = jnp.broadcast_to(lse_f[:, None, :], (b * h, 8, pq))
     dq, dk, dv = _flash_bwd(
         (qf, kf, vf), of, lse8, dof, glse_f, q_start, k_start, k_start + s_kv,
-        causal, bq, bk, interpret, pk != s_kv, d,
+        causal, bq, bk, interpret, pk != s_kv, d, window,
     )
     dq = dq[:, :s_q].astype(jnp.float32).reshape(b, h, s_q, d)
     dk = dk[:, :s_kv].astype(jnp.float32).reshape(b, h, s_kv, d)

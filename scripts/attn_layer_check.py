@@ -2,7 +2,7 @@
 shapes: the flash kernels reading the projections' own layout beside the head-major
 form they read before (PR 33), values and time.
 
-    chiprun -- python3 scripts/attn_layer_check.py [--parent-tree DIR]
+    chiprun -- python3 scripts/attn_layer_check.py [--parent-tree DIR] [--forms layout|head_major|window]
 
 The unit is what lies between ``wqkv`` and ``wo``: ``[B, S, 3·d]`` in (q, k, v side by
 side as the fused projection leaves them; for the grouped-query shape q ``[B, S, d]``
@@ -24,6 +24,14 @@ the kernels by themselves (the host clock where the trace holds no device plane,
 a CPU rehearsal: ``--rehearse``). The last line is one JSON object; exit 1 where the
 loss differs from the parent form's by more than float32 rounding or a gradient by
 more than ``--tolerance`` of the parent's largest magnitude.
+
+``--forms`` picks the pair that is compared. ``layout`` (the default) is the above.
+``head_major``: the head-major form on the parent's kernels beside the same form on
+this tree's, for a change to the kernels that must cost a shape nothing (the window
+of PR 35: ``jamba2-3b-8k`` within 1%). ``window``: this tree's head-major form with
+every earlier key beside the same with ``--window`` keys, at ``mellum2-8k``'s ``[1,
+8192, 32x128]`` on 4 key-value heads; the two compute different things, so only the
+times are compared (values: ``tests/test_flash_window.py`` and the cell's own check).
 """
 
 from __future__ import annotations
@@ -41,13 +49,14 @@ sys.path.insert(0, str(REPO))
 
 # batch, seq, query heads, head_dim, key-value heads
 SHAPES = {"gpt2s-1k": (32, 1024, 12, 64, 12), "gpt2s-8k": (4, 8192, 12, 64, 12),
-          "gpt2l-1k": (4, 1024, 20, 64, 20), "jamba2-3b-8k": (1, 8192, 20, 128, 1)}
+          "gpt2l-1k": (4, 1024, 20, 64, 20), "jamba2-3b-8k": (1, 8192, 20, 128, 1),
+          "mellum2-8k": (1, 8192, 32, 128, 4)}
 TRACE_DIR = REPO / ".bench_trace" / "attn_layer"
 KERNELS = ("flash_fwd", "flash_dkv", "flash_dq")
 
 
-def layer_forms(flash, parent_flash, n_head: int, head_dim: int, n_kv: int):
-    """``(head_major, packed)``: each maps the layer's inputs to ``[B, S, d]``."""
+def layer_forms(flash, parent_flash, n_head: int, head_dim: int, n_kv: int, forms: str = "layout", window=None):
+    """``(parent, change)`` of ``forms``: each maps the layer's inputs to ``[B, S, d]``."""
     import jax.numpy as jnp
 
     repeat = n_head // n_kv
@@ -57,17 +66,22 @@ def layer_forms(flash, parent_flash, n_head: int, head_dim: int, n_kv: int):
         q, k, v = (t.reshape(*t.shape[:2], -1, head_dim) for t in (q, k, v))
         return q, jnp.repeat(k, repeat, axis=2), jnp.repeat(v, repeat, axis=2)
 
-    def head_major(*inputs):
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in split(inputs))
-        out = parent_flash.flash_attention(q, k, v, causal=True).transpose(0, 2, 1, 3)
-        return out.reshape(*out.shape[:2], -1)
+    def head_major_on(kernels, **more):
+        def head_major(*inputs):
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in split(inputs))
+            out = kernels.flash_attention(q, k, v, causal=True, **more).transpose(0, 2, 1, 3)
+            return out.reshape(*out.shape[:2], -1)
+
+        return head_major
 
     def packed(*inputs):
         if len(inputs) == 3:
             inputs = [tuple(t.reshape(*t.shape[:2], -1) for t in split(inputs))]
         return flash.flash_attention_packed(inputs[0], head_dim, causal=True)[0]
 
-    return head_major, packed
+    if forms == "window":
+        return head_major_on(flash), head_major_on(flash, window=window)
+    return head_major_on(parent_flash), head_major_on(flash) if forms == "head_major" else packed
 
 
 def main(argv=None) -> int:
@@ -77,6 +91,8 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument("--tolerance", type=float, default=2**-7,
                     help="largest |change - parent| / max|parent| allowed of a gradient (bf16: 2^-8 a rounding)")
+    ap.add_argument("--forms", default="layout", choices=("layout", "head_major", "window"))
+    ap.add_argument("--window", type=int, default=1024, help="keys a query sees under --forms window")
     ap.add_argument("--parent-tree", help="a checkout of the parent commit: its ops/flash.py runs the head-major form")
     ap.add_argument("--rehearse", action="store_true", help="tiny shapes, for a run without the chip")
     args = ap.parse_args(argv)
@@ -134,7 +150,8 @@ def main(argv=None) -> int:
             return jax.jit(jax.value_and_grad(
                 lambda *inputs: jnp.sum(form(*inputs).astype(jnp.float32) * weight), argnums=tuple(range(len(operands)))))
 
-        parent, change = map(layer, layer_forms(flash, parent_flash, n_head, head_dim, n_kv))
+        window = min(args.window, seq // 4) if args.rehearse else args.window
+        parent, change = map(layer, layer_forms(flash, parent_flash, n_head, head_dim, n_kv, args.forms, window))
         (l_p, g_p), (l_c, g_c) = parent(*operands), change(*operands)
         loss_diff, diff = abs(float(l_c) - float(l_p)) / abs(float(l_p)), {}
         for key, a, b in zip(("dq", "dk", "dv") if len(operands) == 3 else ("dqkv",), g_c, g_p):
@@ -143,12 +160,12 @@ def main(argv=None) -> int:
         del g_p, g_c
         p_ms, p_kernels, clock = per_call_ms(parent, operands, f"{name}-parent")
         c_ms, c_kernels, _ = per_call_ms(change, operands, f"{name}-change")
-        row = {"shape": [batch, seq, n_head, head_dim, n_kv], "clock": clock,
+        row = {"shape": [batch, seq, n_head, head_dim, n_kv], "forms": args.forms, "clock": clock,
                "parent_ms": p_ms, "change_ms": c_ms, "ratio": c_ms / p_ms,
                "parent_kernels_ms": p_kernels, "change_kernels_ms": c_kernels,
                "kernels_ratio": sum(c_kernels.values()) / sum(p_kernels.values()) if p_kernels else None,
                "relative_difference": {"loss": loss_diff, **diff}}
-        ok = ok and loss_diff <= 1e-5 and max(diff.values()) <= args.tolerance
+        ok = ok and (args.forms == "window" or (loss_diff <= 1e-5 and max(diff.values()) <= args.tolerance))
         out[name] = row
         print(json.dumps({name: row}), flush=True)
     out["ok"] = ok

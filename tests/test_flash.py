@@ -380,7 +380,7 @@ def test_flash_tile_classes_equal_masked_body(monkeypatch, fresh_traces, dtype):
 
     by_class = run()
 
-    def every_tile_masked(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k):
+    def every_tile_masked(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k, window=None):
         flash.pl.when(flash._seen(q0, k0, block_q))(lambda: compute(True))
 
     monkeypatch.setattr(flash, "_per_tile_class", every_tile_masked)

@@ -12,6 +12,7 @@ import pytest
 
 from dsml_tpu.models.gpt2 import GPT2, GPT2Config
 from dsml_tpu.models.jamba import Jamba, JambaConfig
+from dsml_tpu.models.mellum import Mellum, MellumConfig
 from dsml_tpu.parallel.hybrid import make_hybrid_train_step
 from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
 
@@ -19,7 +20,8 @@ from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
 @functools.lru_cache(maxsize=None)
 def _op_names(dp: int, dp_sync: str = "xla", family: str = "gpt2") -> list[list[str]]:
     """The op names of the compiled tiny step, each split into its components."""
-    model = GPT2(GPT2Config.tiny()) if family == "gpt2" else Jamba(JambaConfig.tiny(remat=True))
+    model = {"gpt2": lambda: GPT2(GPT2Config.tiny()), "jamba": lambda: Jamba(JambaConfig.tiny(remat=True)),
+             "mellum": lambda: Mellum(MellumConfig.tiny(remat=True))}[family]()
     optimizer = optax.adamw(1e-3)
     mesh = build_mesh(MeshSpec(dp=dp), jax.devices()[:dp])
     step = make_hybrid_train_step(
@@ -69,3 +71,30 @@ def test_compiled_jamba_step_names_its_work(scope, backward):
     assert _has(names, scope, backward)
     if scope == "ssm_scan_bwd":
         assert not _has(names, scope, False)
+
+
+@pytest.mark.parametrize("scope,backward", [
+    # the expert layer's four steps inside `mlp`, each forward and backward; the plan of `moe_route` is
+    # integers, so its backward holds the router's alone
+    ("moe_route", False), ("moe_route", True), ("moe_dispatch", False), ("moe_dispatch", True),
+    ("experts", False), ("experts", True), ("moe_combine", False), ("moe_combine", True),
+    # the grouped matmuls by their own names: the forward kernel also in the recomputed forward
+    ("gmm_fwd", False), ("gmm_fwd", True), ("gmm_dx", True), ("gmm_dw", True),
+    # the flash calls of the two layer types, told apart by a scope nested in `attn`
+    ("attn_window", False), ("attn_window", True), ("attn_full", False), ("attn_full", True),
+    ("flash_fwd", False), ("flash_dq", None), ("flash_dkv", True),
+    ("embed", False), ("attn", False), ("attn", True), ("mlp", False), ("mlp", True),
+    ("loss_head", False), ("loss_head", True), ("optimizer", False),
+])
+def test_compiled_mellum_step_names_its_work(scope, backward):
+    names = _op_names(1, family="mellum")
+    if backward is None:
+        assert not _has(names, scope, True)
+        return
+    assert _has(names, scope, backward)
+    if scope in ("gmm_dx", "gmm_dw"):
+        assert not _has(names, scope, False)
+    if scope.startswith(("moe_", "experts", "gmm_")):  # all of them inside `mlp`
+        assert all("mlp" in tokens for tokens in names if scope in tokens)
+    if scope.startswith("attn_"):
+        assert all("attn" in tokens for tokens in names if scope in tokens)

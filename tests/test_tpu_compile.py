@@ -403,3 +403,38 @@ def test_flash_stream_hop_compiles(topo):
         (8, 12, 1024, 64), jnp.bfloat16, sharding=NamedSharding(mesh, spec)
     )
     assert "tpu_custom_call" in fn.lower(arg, arg, arg).compile().as_text()
+
+
+# -- the window, and the grouped matmuls of the expert layer, at `mellum2-8k`'s geometry ------------------
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+@pytest.mark.parametrize("what", ["fwd", "grad"])
+def test_flash_compiles_at_head_128_with_and_without_a_window(topo, window, what):
+    """[1, 32, 8192, 128], key-value heads already repeated: the sliding and the full layers' calls."""
+    from dsml_tpu.ops.flash import flash_attention
+
+    def out(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False, window=window)
+
+    fn = out if what == "fwd" else jax.grad(lambda q, k, v: out(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
+    qkv = _sds((1, 32, 8192, 128), jnp.bfloat16)
+    found = set(_flash_kernels_in(_compile(topo, fn, qkv, qkv, qkv)))
+    assert found == ({"flash_fwd"} if what == "fwd" else {"flash_fwd", "flash_dkv"})
+
+
+@pytest.mark.parametrize("tile", [128, 256, 512])
+def test_grouped_matmuls_compile_and_carry_their_names(topo, tile):
+    """The three kernels of an expert matmul and its backward at 65,536 pairs over 64 experts of
+    2304 x 896 and 896 x 2304 (each expert padded to whole tiles), by the names the benchmark reads."""
+    from dsml_tpu.ops.grouped_matmul import grouped_matmul, n_row_tiles
+
+    tiles = n_row_tiles(65536, 64, tile)
+    for k, n in ((2304, 896), (896, 2304)):
+        def loss(x, w, group):
+            return grouped_matmul(x, w, group, tile, interpret=False).astype(jnp.float32).sum()
+
+        text = _compile(topo, jax.grad(loss, (0, 1)), _sds((tiles * tile, k), jnp.bfloat16),
+                        _sds((64, k, n), jnp.bfloat16), _sds((tiles,), jnp.int32))
+        calls = [name for _, tokens in _kernel_calls(text) for name in ("gmm_fwd", "gmm_dx", "gmm_dw") if name in tokens]
+        # dx, and dw as two halves of the rows; the forward is no part of a gradient that keeps no output
+        assert sorted(calls) == ["gmm_dw", "gmm_dw", "gmm_dx"], calls
