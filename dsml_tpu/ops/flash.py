@@ -8,20 +8,20 @@ op, as hand-written Pallas kernels so the [seq, seq] score matrix never
 touches HBM:
 
 - forward: blockwise q·kᵀ on the MXU with online-softmax accumulators
-  (running row-max, running denominator) held in VMEM scratch across the
-  innermost kv-block grid dimension; emits the per-row logsumexp.
-- backward: ONE kernel (``flash_dkv``) on the grid ``(batch, lane blocks,
-  kv_blocks, q_blocks)``: the score tile is recomputed once, transposed
+  (running row-max, running denominator) held in VMEM scratch across a q
+  block's kv blocks; emits the per-row logsumexp.
+- backward: ONE kernel (``flash_dkv``), kv-major over the score tiles ("The
+  grid", below): the tile is recomputed once, transposed
   (``sᵀ = k·qᵀ``, ``p = exp(s − L)`` from the forward's saved logsumexp
   rather than stored probabilities), and feeds five dots: ``sᵀ``, ``dpᵀ``
   and the three gradients, each accumulated transposed (``dvᵀ += doᵀ·p``,
   ``dkᵀ += qᵀ·ds``, ``dqᵀ += kᵀ·dsᵀ``: a ``[d, block]`` result fills the
   MXU's width where ``[block, d]`` leaves half of it idle at head 64).
-  ``dk``/``dv`` accumulate over the inner q axis in block-sized scratch;
-  ``dq`` sums over the OUTER kv axis, so its float32 accumulator is the
-  whole query length of one (batch, lane block), resident in VMEM across
-  that walk (4 MB at 8192 rows of 128 lanes). A query whose resident
-  ``dq`` does not fit beside the tile (``_fused_bwd_vmem`` against
+  ``dk``/``dv`` accumulate over a kv block's q blocks in block-sized scratch;
+  ``dq`` sums over the kv blocks, which the walk leaves and comes back to,
+  so its float32 accumulator is the whole query length of one (batch, lane
+  block), resident in VMEM across that walk (4 MB at 8192 rows of 128
+  lanes). A query whose resident ``dq`` does not fit beside the tile (``_fused_bwd_vmem`` against
   ``_VMEM_BUDGET``: a rule on shapes alone, about 50k rows at head 64 in
   bf16) keeps the two-kernel split, ``flash_dq`` over kv blocks then the
   same ``flash_dkv`` without its ``dq`` part, seven dots. The logsumexp output is differentiable too (its
@@ -86,14 +86,29 @@ kernels via a ``kv_stop`` SMEM scalar, slice padded q rows off outputs —
 cp/ring shards make odd residual lengths the common case.
 ``DSML_FLASH_BLOCK`` overrides the swept block defaults (docs/TUNING.md).
 
-Causal blocks entirely above the diagonal are skipped via ``pl.when``
-predication (a dynamic predicate when offsets are traced); the forward also
-runs blocks entirely below it, and before ``kv_stop``, with no mask at all.
-A sliding ``window`` (one more static argument of every entry point; ``None``
-leaves the kernels as they were) adds the second edge: blocks wholly older
-than every query's window are skipped the same way, in the forward and in the
-backward, and a block that neither edge crosses runs the unmasked body. The
-grid still steps over a skipped block and fetches its operands.
+The grid. Every kernel here but the ring hop's runs on ``(batch, lane blocks,
+tiles)``: the last axis walks a LIST of score tiles (:func:`_walk`), whose
+tables (``q_of[t]``, ``kv_of[t]`` and a word of flags: first / last tile of
+its q block in a q-major walk, of its kv block in the kv-major one, first /
+last appearance of a q block for the riding ``dq``) are scalar-prefetched, so
+the index maps read them and Pallas fetches the blocks of the tiles on the
+list and of no other. Where a call's offsets are known as it is traced
+(``q_start`` and ``k_start`` Python ints: every single-chip caller, at 0 and
+0) the list is made then, in numpy, of the tiles :func:`_seen` lets through:
+causal tiles wholly above the diagonal are not on it, nor, under a sliding
+``window`` (one more static argument of every entry point), tiles wholly
+older than every query's window; a q block or kv block left with no tile
+keeps one, for its accumulator's zeros and its write. The order is the
+rectangle's own with those tiles left out (forward and ``flash_dq`` q-major,
+each q block's kv blocks ascending; ``flash_dkv`` kv-major, each kv block's q
+blocks ascending), so every sum is accumulated as it always was and values are
+bit-equal to the rectangle's. Where the offsets are traced (the ring and cp
+callers) the list is the whole rectangle in that order. One body either way:
+``pl.when(_seen(...))`` inside the step keeps the dots off a tile that sees
+nothing (on the rectangle it decides; on a made list it only ever says no to a
+block's one kept tile), and the forward runs a tile that neither edge crosses
+and that ends before ``kv_stop`` with no mask at all (:func:`_per_tile_class`).
+:func:`grid_steps` counts both for a call's shapes.
 On non-TPU backends the same kernels run under the Pallas interpreter
 (``interpret=True``), which is how tests validate them on the CI CPU mesh;
 on TPU they compile through Mosaic.
@@ -111,6 +126,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -128,6 +144,7 @@ __all__ = [
     "flash_packs",
     "flash_block_grads",
     "flash_stream_hop",
+    "grid_steps",
     "ring_flash_attention",
 ]
 
@@ -356,11 +373,13 @@ def _mask(s, q0, k0, kv_stop, causal, mask_kv, q_axis, window=None):
 
 def _seen(q0, k0, block_q, block_k=None, window=None):
     """False for a tile whose every key is in the future of its every query,
-    or (``window``) older than the window of its every query: a causal
-    kernel skips it (a dynamic predicate: offsets are traced)."""
+    or (``window``) older than the window of its every query: a causal kernel
+    does no dot on it. Python's operators only: the same test lists the live
+    tiles in numpy as a call is traced (:func:`_live_tiles`) and, where the
+    offsets are traced, decides inside the grid step."""
     seen = k0 <= q0 + block_q - 1
     if window is not None:
-        seen = jnp.logical_and(seen, k0 + block_k - 1 > q0 - window)
+        seen = seen & (k0 + block_k - 1 > q0 - window)
     return seen
 
 
@@ -391,26 +410,128 @@ def _per_tile_class(compute, q0, k0, kv_stop, causal, mask_kv, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
+# the walk: one grid axis over a list of tiles
+# ---------------------------------------------------------------------------
+
+# What a grid step needs to know of its place in the walk, one bit each: it is
+# the first / last tile of its block on the major axis (the q block of a
+# q-major walk, the kv block of a kv-major one: the block whose accumulator the
+# walk holds in scratch), and the first / last tile in which its block on the
+# other axis appears at all (the riding dq's q block in the kv-major walk).
+_ROW_FIRST, _ROW_LAST, _SEEN_FIRST, _SEEN_LAST = 1, 2, 4, 8
+
+
+def _static_offset(q_start, k_start) -> int | None:
+    """``q_start - k_start`` where both are known as the call is traced
+    (Python ints: every single-chip caller, at 0 and 0), else ``None`` (the
+    ring and cp callers hand tracers)."""
+    if isinstance(q_start, int) and isinstance(k_start, int):
+        return q_start - k_start
+    return None
+
+
+def _live_tiles(q_blocks, kv_blocks, block_q, block_k, causal, window, offset):
+    """``[q_blocks, kv_blocks]`` bool: the tiles a call's grid walks. With a
+    known ``offset`` between the first query and the first key, those
+    :func:`_seen` lets through, and for a q block or a kv block that has none
+    its first tile all the same (its accumulator owes its zeros and its
+    write; ``_seen`` still keeps the dots off it). With ``offset`` ``None``
+    (traced) or without ``causal``, the whole rectangle: the list no one could
+    shorten."""
+    live = np.ones((q_blocks, kv_blocks), bool)
+    if causal and offset is not None:
+        q0 = offset + np.arange(q_blocks)[:, None] * block_q
+        live &= _seen(q0, np.arange(kv_blocks)[None, :] * block_k, block_q, block_k, window)
+        live[~live.any(1), 0] = True
+        live[0, ~live.any(0)] = True
+    return live
+
+
+def _walk(live, kv_major: bool):
+    """The tables of a walk over ``live``: ``(q_of, kv_of, flags)``, int32
+    ``[n_live]`` each, scalar-prefetched so that the index maps read them
+    (as one interleaved array they read 0.03-0.05% slower in four cells and
+    no faster in any: PERF.md §6, PR 36).
+    q-major (the forward, ``flash_dq``): each q block's kv blocks ascending;
+    kv-major (``flash_dkv``): each kv block's q blocks ascending. Either is
+    the rectangle's own order with the dead tiles left out, so every sum is
+    accumulated in the order it always was."""
+    major, minor = np.nonzero(live.T if kv_major else live)
+    n = major.size
+    edge = major[1:] != major[:-1]
+    flags = np.zeros(n, np.int32)
+    flags[np.r_[True, edge]] |= _ROW_FIRST
+    flags[np.r_[edge, True]] |= _ROW_LAST
+    flags[np.unique(minor, return_index=True)[1]] |= _SEEN_FIRST
+    flags[n - 1 - np.unique(minor[::-1], return_index=True)[1]] |= _SEEN_LAST
+    q_of, kv_of = (minor, major) if kv_major else (major, minor)
+    return tuple(jnp.asarray(t, jnp.int32) for t in (q_of, kv_of, flags))
+
+
+def _step(q_of, kv_of, flags):
+    """``(qi, ki, flag)`` of this grid step: its tile, and ``flag(bit)`` for
+    what :func:`_walk` noted of it."""
+    t = pl.program_id(2)
+    word = flags[t]
+    return q_of[t], kv_of[t], lambda bit: (word & bit) != 0
+
+
+def _side_spec(block_q, block_k, lanes, of_q: bool, at: int = 0):
+    """Spec of a q-side (``of_q``) or kv-side operand or output on the walk's
+    grid: the step's block of the sequence, lane block ``at + g``."""
+    return _vmem_spec((1, block_q if of_q else block_k, lanes),
+                      lambda b, g, t, q_of, kv_of, _: (b, (q_of if of_q else kv_of)[t], at + g))
+
+
+def _stat_spec(heads, block_q, groups):
+    """Spec of the per-row statistics (``lse``, ``delta − g_lse``) on the
+    walk's grid: a row a head, lane-major over the step's q block."""
+    return _vmem_spec((heads, 8, block_q), lambda b, g, t, q_of, kv_of, _: (b * groups + g, 0, q_of[t]))
+
+
+def _walk_grid(tables, batch, groups, in_specs, out_specs, scratch_shapes):
+    """The grid every flash kernel here runs on: ``(batch, lane blocks, tiles
+    of the walk)``, the walk's ``tables`` first among the operands."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables), grid=(batch, groups, tables[0].shape[0]),
+        in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+
+
+def grid_steps(s_q: int, s_kv: int, head_dim: int, causal: bool = True, window: int | None = None,
+               offset: int | None = 0, block_q: int | None = None, block_k: int | None = None) -> tuple[int, int]:
+    """``(walked, rectangle)``: the grid steps a call at these lengths takes
+    for one (batch, lane block), and the tiles of its rectangle (what it took
+    before the walk, and takes with ``offset=None``)."""
+    block_q, block_k = _default_blocks(s_q, s_kv, block_q, block_k, head_dim)
+    (bq, pq), (bk, pk) = _pad_choice(s_q, block_q), _pad_choice(s_kv, block_k)
+    live = _live_tiles(pq // bq, pk // bk, bq, bk, causal, window, offset)
+    return int(live.sum()), live.size
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv, window=None, qi=None, ki=None):
-    # qi/ki may be pre-read grid indices: a wrapping kernel that delegates
-    # here from inside pl.when must hoist its program_id reads to the top
-    # level — interpret mode substitutes the primitive only when it's bound
-    # in the outer kernel jaxpr, not inside a cond branch
-    if qi is None:
-        qi = pl.program_id(2)
-    if ki is None:
-        ki = pl.program_id(3)
+def _fwd_kernel(q_of, kv_of, flags, *refs, **static):
+    qi, ki, flag = _step(q_of, kv_of, flags)
+    _fwd_tile(qi, ki, flag(_ROW_FIRST), flag(_ROW_LAST), *refs, **static)
+
+
+def _fwd_tile(qi, ki, first, last, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, mask_kv, window=None):
+    """One grid step of the forward on tile ``(qi, ki)``, the ``first`` /
+    ``last`` of its q block. All four are values the caller read at the top
+    level of its kernel: a wrapping kernel that delegates here from inside
+    ``pl.when`` must not leave a ``program_id`` read for a cond branch
+    (interpret mode substitutes the primitive only where it is bound in the
+    outer kernel jaxpr)."""
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
     scale = head_dim**-0.5
     fold = _scale_folds(scale)
     heads = _head_lanes(acc.shape[1], head_dim)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _MAX_FLOOR)
@@ -440,7 +561,7 @@ def _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, 
 
     _per_tile_class(compute, q0, k0, kstop_ref[0], causal, mask_kv, block_q, block_k, window)
 
-    @pl.when(ki == kv_blocks - 1)
+    @pl.when(last)
     def _finish():
         l_fin = [jnp.maximum(jnp.sum(l_scr[j], -1, keepdims=True), 1e-30) for j in range(len(heads))]
         o_ref[0] = (acc[:] / _weave([_lanes(l, acc.shape[1]) for l in l_fin], heads)).astype(o_ref.dtype)
@@ -468,44 +589,47 @@ def _operands(qkv, head_dim):
     return q, k, v, (0, 0, 0), lanes, q.shape[2] // lanes
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None, offset=None):
     q, k, v, (q_at, k_at, v_at), lanes, groups = _operands(qkv, head_dim)
     batch, s_q = q.shape[:2]
     heads = lanes // head_dim
-    q_blocks, kv_blocks = s_q // block_q, k.shape[1] // block_k
+    tables = _walk(_live_tiles(s_q // block_q, k.shape[1] // block_k, block_q, block_k, causal, window, offset), kv_major=False)
+    side = functools.partial(_side_spec, block_q, block_k, lanes)
 
     kernel = functools.partial(
         _fwd_kernel, head_dim=head_dim, causal=causal,
-        block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv, window=window,
+        block_q=block_q, block_k=block_k, mask_kv=mask_kv, window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=(batch, groups, q_blocks, kv_blocks),
-        in_specs=[
-            _smem_spec(),
-            _smem_spec(),
-            _smem_spec(),
-            _vmem_spec((1, block_q, lanes), lambda b, g, qi, ki: (b, qi, q_at + g)),
-            _vmem_spec((1, block_k, lanes), lambda b, g, qi, ki: (b, ki, k_at + g)),
-            _vmem_spec((1, block_k, lanes), lambda b, g, qi, ki: (b, ki, v_at + g)),
-        ],
-        out_specs=[
-            _vmem_spec((1, block_q, lanes), lambda b, g, qi, ki: (b, qi, g)),
-            _vmem_spec((heads, 8, block_q), lambda b, g, qi, ki: (b * groups + g, 0, qi)),
-        ],
+        grid_spec=_walk_grid(
+            tables, batch, groups,
+            in_specs=[
+                _smem_spec(),
+                _smem_spec(),
+                _smem_spec(),
+                side(True, q_at),
+                side(False, k_at),
+                side(False, v_at),
+            ],
+            out_specs=[
+                side(True),
+                _stat_spec(heads, block_q, groups),
+            ],
+            scratch_shapes=[
+                _scratch((block_q, lanes)),
+                _scratch((heads, block_q, _stat_lanes(block_k))),
+                _scratch((heads, block_q, _stat_lanes(block_k))),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((batch, s_q, groups * lanes), q.dtype),
             jax.ShapeDtypeStruct((batch * groups * heads, 8, s_q), jnp.float32),
         ],
-        scratch_shapes=[
-            _scratch((block_q, lanes)),
-            _scratch((heads, block_q, _stat_lanes(block_k))),
-            _scratch((heads, block_q, _stat_lanes(block_k))),
-        ],
         interpret=interpret,
         name="flash_fwd",
-    )(_scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v)
+    )(*tables, _scalar(q_start), _scalar(k_start), _scalar(kv_stop), q, k, v)
     return out, lse
 
 
@@ -524,7 +648,8 @@ def _stream_fwd_kernel(qs_ref, ks_ref, kstop_ref, pred_ref, nbr_ref,
                        acc, m_scr, l_scr, send_sem, recv_sem, *,
                        head_dim, causal, block_q, block_k, q_blocks, kv_blocks,
                        n_bh, mask_kv, barrier):
-    """:func:`_fwd_kernel` with the ring hop absorbed: at the FIRST grid
+    """:func:`_fwd_tile` on a grid of its own, ``(batch·heads, q_blocks,
+    kv_blocks)``, with the ring hop absorbed: at the FIRST grid
     step the resident KV shard starts a remote async copy into the
     neighbor's receive buffers (``pltpu.make_async_remote_copy``), the
     whole flash grid then computes while those bytes fly, and the LAST
@@ -568,10 +693,10 @@ def _stream_fwd_kernel(qs_ref, ks_ref, kstop_ref, pred_ref, nbr_ref,
 
     @pl.when(pred_ref[0] != 0)
     def _math():
-        _fwd_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref,
-                    o_ref, lse_ref, acc, m_scr, l_scr, head_dim=head_dim,
-                    causal=causal, block_q=block_q, block_k=block_k,
-                    kv_blocks=kv_blocks, mask_kv=mask_kv, qi=qi, ki=ki)
+        _fwd_tile(qi, ki, ki == 0, ki == kv_blocks - 1,
+                  qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref,
+                  o_ref, lse_ref, acc, m_scr, l_scr, head_dim=head_dim,
+                  causal=causal, block_q=block_q, block_k=block_k, mask_kv=mask_kv)
 
     @pl.when((pred_ref[0] == 0) & (ki == kv_blocks - 1))
     def _masked():
@@ -688,16 +813,15 @@ def flash_stream_hop(
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, kv_blocks, mask_kv, window=None):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+def _dq_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, mask_kv, window=None):
+    qi, ki, flag = _step(q_of, kv_of, flags)  # a q-major walk, as the forward's
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
     scale = head_dim**-0.5
     fold = _scale_folds(scale)
     heads = _head_lanes(acc.shape[1], head_dim)
 
-    @pl.when(ki == 0)
+    @pl.when(flag(_ROW_FIRST))
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
@@ -725,12 +849,12 @@ def _dq_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
     else:
         compute()
 
-    @pl.when(ki == kv_blocks - 1)
+    @pl.when(flag(_ROW_LAST))
     def _finish():
         dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, q_blocks, kv_blocks, mask_kv, window=None):
+def _dkv_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, mask_kv, window=None):
     """``dk`` and ``dv`` of one kv block, accumulated over the q blocks; given
     a third output and scratch (``dq_ref``, ``dq_acc``) also ``dq``, from the
     tile it already holds.
@@ -747,32 +871,33 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     that head's rows of the accumulator, so a block of two heads does the
     dots two blocks of one head do.
 
-    ``dq`` sums over ``ki``, the OUTER of the two inner grid axes, so its
-    accumulator is the whole query length of one (batch, lane block),
-    ``[q_blocks, lanes, block_q]``, resident across that walk: block ``qi`` is
-    zeroed at ``ki == 0`` and written out at the last ``ki``, both outside
-    the ``_seen`` predicate (offsets are traced: a q block may be skipped on
-    every step and still owes its zeros). ``dq_ref`` is the whole query
-    length too, in ``q``'s dtype, and goes to HBM once a (batch, lane block)."""
+    The walk is kv-major (:func:`_walk`), so ``dq`` sums over what the walk
+    leaves and comes back to: its accumulator is the whole query length of one
+    (batch, lane block), ``[q_blocks, lanes, block_q]``, resident across that
+    walk. Block ``qi`` is zeroed at the first tile the walk holds of it and
+    written out at the last, both outside the ``_seen`` predicate: the walk
+    holds a tile of every q block, and where the offsets are traced it holds
+    the rectangle, of which a q block may be skipped on every step and still
+    owes its zeros. ``dq_ref`` is the whole query length too, in ``q``'s
+    dtype, and goes to HBM once a (batch, lane block)."""
     if len(rest) == 2:
         (dk_acc, dv_acc), dq_ref, dq_acc = rest, None, None
     else:
         dq_ref, dk_acc, dv_acc, dq_acc = rest
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    qi, ki, flag = _step(q_of, kv_of, flags)
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
     scale = head_dim**-0.5
     fold = _scale_folds(scale)
     heads = _head_lanes(dk_acc.shape[0], head_dim)
 
-    @pl.when(qi == 0)
+    @pl.when(flag(_ROW_FIRST))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     if dq_ref is not None:
-        @pl.when(ki == 0)
+        @pl.when(flag(_SEEN_FIRST))
         def _init_dq():
             dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
@@ -805,14 +930,14 @@ def _dkv_kernel(qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     else:
         compute()
 
-    @pl.when(qi == q_blocks - 1)
+    @pl.when(flag(_ROW_LAST))
     def _finish():
         dk = dk_acc[:] if fold else dk_acc[:] * scale
         dk_ref[0] = dk.T.astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].T.astype(dv_ref.dtype)
 
     if dq_ref is not None:
-        @pl.when(ki == kv_blocks - 1)
+        @pl.when(flag(_SEEN_LAST))
         def _finish_dq():
             rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
             dq_ref[0, rows, :] = (dq_acc[qi] * scale).T.astype(dq_ref.dtype)
@@ -844,7 +969,7 @@ def _fused_bwd_vmem(s_q: int, d: int, block_q: int, block_k: int, itemsize: int)
     return resident + tile + blocks
 
 
-def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None):
+def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None, offset=None):
     """``(dq, dk, dv)`` in the inputs' dtypes and layout, ``[B, S, W]`` each;
     where q, k, v came as one ``[B, S, 3·W]`` array ``dq`` is such an array
     too, written in q's lanes alone: the cotangent-to-be, whose other lanes
@@ -857,11 +982,11 @@ def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_
     q, _, _, _, lanes, _ = _operands(qkv, head_dim)
     vmem = _fused_bwd_vmem(q.shape[1], lanes, block_q, block_k, q.dtype.itemsize)
     return _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k,
-                            interpret, mask_kv, head_dim, vmem if vmem <= _VMEM_BUDGET else None, window)
+                            interpret, mask_kv, head_dim, vmem if vmem <= _VMEM_BUDGET else None, window, offset)
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
-def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, vmem, window=None):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14, 15, 16))
+def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, vmem, window=None, offset=None):
     """:func:`_flash_bwd`'s kernels, ``dq`` riding ``flash_dkv`` in ``vmem``
     bytes of VMEM or, with ``None``, the pair. Jitted, like ``_flash_fwd``:
     a model's like layers trace and lower each kernel once."""
@@ -869,7 +994,7 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
     batch, s_q = q.shape[:2]
     s_kv = k.shape[1]
     heads = lanes // head_dim
-    q_blocks, kv_blocks = s_q // block_q, s_kv // block_k
+    live = _live_tiles(s_q // block_q, s_kv // block_k, block_q, block_k, causal, window, offset)
     # ds = p · (dp − delta + g_lse): delta = Σ do·o over a head's lanes, and
     # g_lse is the cotangent of the lse output. Both are per query row, so
     # their difference is taken here once, not on every score tile. The sum is
@@ -888,62 +1013,42 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
     dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)  # q's own array: all three where they came as one
     kv_shape = jax.ShapeDtypeStruct((batch, s_kv, groups * lanes), k.dtype)
 
-    def specs(q_axis):
-        """Operand specs on a grid ``(b, g, i, j)`` whose inner axis
-        ``q_axis`` (0 or 1) walks the q blocks and whose other walks the kv
-        blocks, and the maker of a q-side (``of_q=True``) or kv-side block
-        spec on that grid (the outputs')."""
-        def side(of_q, at=0):
-            block, axis = (block_q, q_axis) if of_q else (block_k, 1 - q_axis)
-            return _vmem_spec((1, block, lanes), lambda b, g, *ij: (b, ij[axis], at + g))
-
-        stat = _vmem_spec((heads, 8, block_q), lambda b, g, *ij: (b * groups + g, 0, ij[q_axis]))
-        operands = [_smem_spec(), _smem_spec(), _smem_spec(),
-                    side(True, q_at), side(False, k_at), side(False, v_at), side(True), stat, stat]
-        return operands, side
+    static = dict(head_dim=head_dim, causal=causal, block_q=block_q, block_k=block_k, mask_kv=mask_kv, window=window)
+    stat = _stat_spec(heads, block_q, groups)
+    side = functools.partial(_side_spec, block_q, block_k, lanes)
+    in_specs = [_smem_spec(), _smem_spec(), _smem_spec(),
+                side(True, q_at), side(False, k_at), side(False, v_at), side(True), stat, stat]
 
     dq = None
     if not fused:
-        qrow, side = specs(0)
+        tables = _walk(live, kv_major=False)
         dq = pl.pallas_call(
-            functools.partial(
-                _dq_kernel, head_dim=head_dim, causal=causal,
-                block_q=block_q, block_k=block_k, kv_blocks=kv_blocks, mask_kv=mask_kv, window=window,
-            ),
-            grid=(batch, groups, q_blocks, kv_blocks),
-            in_specs=qrow,
-            out_specs=side(True, q_at),
+            functools.partial(_dq_kernel, **static),
+            grid_spec=_walk_grid(tables, batch, groups, in_specs, side(True, q_at), [_scratch((block_q, lanes))]),
             out_shape=dq_shape,
-            scratch_shapes=[_scratch((block_q, lanes))],
             interpret=interpret,
             name="flash_dq",
-        )(*scalars, q, k, v, do, lse8, dd)
+        )(*tables, *scalars, q, k, v, do, lse8, dd)
 
-    krow, side = specs(1)
+    tables = _walk(live, kv_major=True)
     out_specs = [side(False), side(False)]
     out_shape = [kv_shape, kv_shape]
     scratch_shapes = [_scratch((lanes, block_k)), _scratch((lanes, block_k))]
     compiler_params = None
     if fused:
-        out_specs.append(_vmem_spec((1, s_q, lanes), lambda b, g, ki, qi: (b, 0, q_at + g)))
+        out_specs.append(_vmem_spec((1, s_q, lanes), lambda b, g, *_: (b, 0, q_at + g)))
         out_shape.append(dq_shape)
-        scratch_shapes.append(_scratch((q_blocks, lanes, block_q)))
+        scratch_shapes.append(_scratch((s_q // block_q, lanes, block_q)))
         if not interpret:
             compiler_params = pltpu.CompilerParams(vmem_limit_bytes=max(vmem, _VMEM_DEFAULT))
     dk, dv, *riding = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, head_dim=head_dim, causal=causal, block_q=block_q, block_k=block_k,
-            q_blocks=q_blocks, kv_blocks=kv_blocks, mask_kv=mask_kv, window=window,
-        ),
-        grid=(batch, groups, kv_blocks, q_blocks),
-        in_specs=krow,
-        out_specs=out_specs,
+        functools.partial(_dkv_kernel, **static),
+        grid_spec=_walk_grid(tables, batch, groups, in_specs, out_specs, scratch_shapes),
         out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
         compiler_params=compiler_params,
         interpret=interpret,
         name="flash_dkv",
-    )(*scalars, q, k, v, do, lse8, dd)
+    )(*tables, *scalars, q, k, v, do, lse8, dd)
     if fused:
         (dq,) = riding
     return dq, dk, dv
@@ -954,25 +1059,28 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def _flash(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None, offset=None):
     """``qkv`` is ``(q, k, v)`` or the one array that holds all three
-    (:func:`_operands`); ``(out [B, S, W], lse [B·heads, S])``."""
-    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window)
+    (:func:`_operands`); ``(out [B, S, W], lse [B·heads, S])``. ``q_start``
+    and ``k_start`` reach the rules as tracers whatever the caller held, so
+    what it knew of them as it traced (:func:`_static_offset`) rides beside
+    ``window``, static."""
+    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window, offset)
     return out, lse8[:, 0, :]
 
 
-def _flash_fwd_rule(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window):
-    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window)
+def _flash_fwd_rule(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window, offset):
+    out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window, offset)
     return (out, lse8[:, 0, :]), (qkv, out, lse8, q_start, k_start, kv_stop)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, head_dim, window, res, g):
+def _flash_bwd_rule(causal, block_q, block_k, interpret, mask_kv, head_dim, window, offset, res, g):
     qkv, out, lse8, q_start, k_start, kv_stop = res
     g_out, g_lse = g
     grads = _flash_bwd(
         qkv, out, lse8, g_out, g_lse.astype(jnp.float32), q_start, k_start, kv_stop, causal,
-        block_q, block_k, interpret, mask_kv, head_dim, window,
+        block_q, block_k, interpret, mask_kv, head_dim, window, offset,
     )
     if len(qkv) == 1:  # one array in, one cotangent out: dk and dv set beside dq, as k and v lay beside q
         dq, dk, dv = grads
@@ -1027,7 +1135,8 @@ def _attend(qkv, head_dim, causal, q_start, k_start, block_q, block_k, interpret
         # transpose zero-pads their cotangent, so autodiff needs no help
         qkv = (_pad_rows(qkv[0], pq), _pad_rows(qkv[1], pk), _pad_rows(qkv[2], pk))
     kv_stop = k_start + s_kv  # global position the REAL kv columns end at
-    out, lse = _flash(qkv, q_start, k_start, kv_stop, causal, bq, bk, interpret, pk != s_kv, head_dim, window)
+    out, lse = _flash(qkv, q_start, k_start, kv_stop, causal, bq, bk, interpret, pk != s_kv, head_dim, window,
+                      _static_offset(q_start, k_start))
     return out[:, :s_q], lse[:, :s_q]
 
 
@@ -1188,7 +1297,7 @@ def flash_block_grads(
     lse8 = jnp.broadcast_to(lse_f[:, None, :], (b * h, 8, pq))
     dq, dk, dv = _flash_bwd(
         (qf, kf, vf), of, lse8, dof, glse_f, q_start, k_start, k_start + s_kv,
-        causal, bq, bk, interpret, pk != s_kv, d, window,
+        causal, bq, bk, interpret, pk != s_kv, d, window, _static_offset(q_start, k_start),
     )
     dq = dq[:, :s_q].astype(jnp.float32).reshape(b, h, s_q, d)
     dk = dk[:, :s_kv].astype(jnp.float32).reshape(b, h, s_kv, d)

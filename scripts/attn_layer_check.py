@@ -2,15 +2,20 @@
 shapes: the flash kernels reading the projections' own layout beside the head-major
 form they read before (PR 33), values and time.
 
-    chiprun -- python3 scripts/attn_layer_check.py [--parent-tree DIR] [--forms layout|head_major|window]
+    chiprun -- python3 scripts/attn_layer_check.py [--parent-tree DIR] [--forms layout|head_major|packed|window]
 
 The unit is what lies between ``wqkv`` and ``wo``: ``[B, S, 3·d]`` in (q, k, v side by
 side as the fused projection leaves them; for the grouped-query shape q ``[B, S, d]``
 and one key-value head ``[B, S, head_dim]`` each, repeated to the query heads),
-``[B, S, d]`` out, ``jax.value_and_grad`` of a weighted sum of the output. The four
+``[B, S, d]`` out, ``jax.value_and_grad`` of a weighted sum of the output. The
 shapes are the cells' layers, bf16: ``[32, 1024, 12x64]`` (``gpt2s-1k``),
 ``[4, 8192, 12x64]`` (``gpt2s-8k``), ``[4, 1024, 20x64]`` (``gpt2l-1k``, per chip at
-dp=4 too) and ``[1, 8192, 20x128]`` on one key-value head (``jamba2-3b-8k``).
+dp=4 too), ``[1, 8192, 20x128]`` on one key-value head (``jamba2-3b-8k``) and ``[1,
+8192, 32x128]`` on four (``mellum2-8k``: its full layer, and ``mellum2-8k-window`` its
+three sliding ones, 1024 keys a query). Each row carries ``grid_steps``, ``[walked,
+rectangle]``: the grid steps this tree's kernels take for one (batch, lane block) and
+the tiles of the rectangle the kernels stepped through before PR 36
+(``ops/flash.py::grid_steps``).
 ``head_major`` below is the parent's form: the split and the transposes of
 ``models/gpt2.py::_qkv_heads``, the kernels on ``[batch, heads, seq, head_dim]``,
 ``_merge_heads``. Its kernels are this tree's head-major entry (the same bodies, one
@@ -27,8 +32,9 @@ more than ``--tolerance`` of the parent's largest magnitude.
 
 ``--forms`` picks the pair that is compared. ``layout`` (the default) is the above.
 ``head_major``: the head-major form on the parent's kernels beside the same form on
-this tree's, for a change to the kernels that must cost a shape nothing (the window
-of PR 35: ``jamba2-3b-8k`` within 1%). ``window``: this tree's head-major form with
+this tree's, the A/B of a change to the kernels at every layer shape of the cells
+(the window of PR 35: ``jamba2-3b-8k`` within 1%; the walk of PR 36), and ``packed`` the
+same of the packed form, which the head-64 cells run. ``window``: this tree's head-major form with
 every earlier key beside the same with ``--window`` keys, at ``mellum2-8k``'s ``[1,
 8192, 32x128]`` on 4 key-value heads; the two compute different things, so only the
 times are compared (values: ``tests/test_flash_window.py`` and the cell's own check).
@@ -47,16 +53,18 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
-# batch, seq, query heads, head_dim, key-value heads
-SHAPES = {"gpt2s-1k": (32, 1024, 12, 64, 12), "gpt2s-8k": (4, 8192, 12, 64, 12),
-          "gpt2l-1k": (4, 1024, 20, 64, 20), "jamba2-3b-8k": (1, 8192, 20, 128, 1),
-          "mellum2-8k": (1, 8192, 32, 128, 4)}
+# batch, seq, query heads, head_dim, key-value heads, keys a query sees (None: every earlier one)
+SHAPES = {"gpt2s-1k": (32, 1024, 12, 64, 12, None), "gpt2s-8k": (4, 8192, 12, 64, 12, None),
+          "gpt2l-1k": (4, 1024, 20, 64, 20, None), "jamba2-3b-8k": (1, 8192, 20, 128, 1, None),
+          "mellum2-8k": (1, 8192, 32, 128, 4, None), "mellum2-8k-window": (1, 8192, 32, 128, 4, 1024)}
 TRACE_DIR = REPO / ".bench_trace" / "attn_layer"
 KERNELS = ("flash_fwd", "flash_dkv", "flash_dq")
 
 
 def layer_forms(flash, parent_flash, n_head: int, head_dim: int, n_kv: int, forms: str = "layout", window=None):
-    """``(parent, change)`` of ``forms``: each maps the layer's inputs to ``[B, S, d]``."""
+    """``(parent, change)`` of ``forms``: each maps the layer's inputs to ``[B, S, d]``.
+    ``window`` is the shape's own and both sides see it, but under ``forms="window"``,
+    where it is what the change alone is given."""
     import jax.numpy as jnp
 
     repeat = n_head // n_kv
@@ -74,14 +82,19 @@ def layer_forms(flash, parent_flash, n_head: int, head_dim: int, n_kv: int, form
 
         return head_major
 
-    def packed(*inputs):
-        if len(inputs) == 3:
-            inputs = [tuple(t.reshape(*t.shape[:2], -1) for t in split(inputs))]
-        return flash.flash_attention_packed(inputs[0], head_dim, causal=True)[0]
+    def packed_on(kernels, **more):
+        def packed(*inputs):
+            if len(inputs) == 3:
+                inputs = [tuple(t.reshape(*t.shape[:2], -1) for t in split(inputs))]
+            return kernels.flash_attention_packed(inputs[0], head_dim, causal=True, **more)[0]
+
+        return packed
 
     if forms == "window":
         return head_major_on(flash), head_major_on(flash, window=window)
-    return head_major_on(parent_flash), head_major_on(flash) if forms == "head_major" else packed
+    own = {} if window is None else {"window": window}  # a parent from before PR 35 has no such argument
+    parent = packed_on if forms == "packed" else head_major_on
+    return parent(parent_flash, **own), (head_major_on if forms == "head_major" else packed_on)(flash, **own)
 
 
 def main(argv=None) -> int:
@@ -91,7 +104,7 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument("--tolerance", type=float, default=2**-7,
                     help="largest |change - parent| / max|parent| allowed of a gradient (bf16: 2^-8 a rounding)")
-    ap.add_argument("--forms", default="layout", choices=("layout", "head_major", "window"))
+    ap.add_argument("--forms", default="layout", choices=("layout", "head_major", "packed", "window"))
     ap.add_argument("--window", type=int, default=1024, help="keys a query sees under --forms window")
     ap.add_argument("--parent-tree", help="a checkout of the parent commit: its ops/flash.py runs the head-major form")
     ap.add_argument("--rehearse", action="store_true", help="tiny shapes, for a run without the chip")
@@ -136,7 +149,9 @@ def main(argv=None) -> int:
 
     out, ok = {}, True
     for name in args.shapes:
-        batch, seq, n_head, head_dim, n_kv = (2, 256, 2, 64, 2 if SHAPES[name][4] > 1 else 1) if args.rehearse else SHAPES[name]
+        batch, seq, n_head, head_dim, n_kv, own_window = SHAPES[name]
+        if args.rehearse:
+            batch, seq, n_head, head_dim, n_kv, own_window = 2, 256, 2, 64, 2 if n_kv > 1 else 1, own_window and 64
         d = n_head * head_dim
         ks = jax.random.split(jax.random.key(args.seed), 4)
         if n_kv == n_head:
@@ -150,7 +165,9 @@ def main(argv=None) -> int:
             return jax.jit(jax.value_and_grad(
                 lambda *inputs: jnp.sum(form(*inputs).astype(jnp.float32) * weight), argnums=tuple(range(len(operands)))))
 
-        window = min(args.window, seq // 4) if args.rehearse else args.window
+        window = own_window
+        if args.forms == "window":
+            window = min(args.window, seq // 4) if args.rehearse else args.window
         parent, change = map(layer, layer_forms(flash, parent_flash, n_head, head_dim, n_kv, args.forms, window))
         (l_p, g_p), (l_c, g_c) = parent(*operands), change(*operands)
         loss_diff, diff = abs(float(l_c) - float(l_p)) / abs(float(l_p)), {}
@@ -161,6 +178,7 @@ def main(argv=None) -> int:
         p_ms, p_kernels, clock = per_call_ms(parent, operands, f"{name}-parent")
         c_ms, c_kernels, _ = per_call_ms(change, operands, f"{name}-change")
         row = {"shape": [batch, seq, n_head, head_dim, n_kv], "forms": args.forms, "clock": clock,
+               "grid_steps": flash.grid_steps(seq, seq, head_dim, window=window),
                "parent_ms": p_ms, "change_ms": c_ms, "ratio": c_ms / p_ms,
                "parent_kernels_ms": p_kernels, "change_kernels_ms": c_kernels,
                "kernels_ratio": sum(c_kernels.values()) / sum(p_kernels.values()) if p_kernels else None,

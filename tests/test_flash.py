@@ -417,19 +417,24 @@ def test_flash_forward_row_chunks_of_a_1024_block():
 # ---------------------------------------------------------------------------
 
 
-def _bwd_kernels(fn, *args):
-    """Names of the Pallas calls in ``fn``'s jaxpr, in order."""
-    names = []
+def _pallas_calls(fn, *args):
+    """``(name, grid)`` of the Pallas calls in ``fn``'s jaxpr, in order."""
+    calls = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                names.append(eqn.params["name"])
+                calls.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return names
+    return calls
+
+
+def _bwd_kernels(fn, *args):
+    """Names of the Pallas calls in ``fn``'s jaxpr, in order."""
+    return [name for name, _ in _pallas_calls(fn, *args)]
 
 
 def _block_grads_both_ways(monkeypatch, q, k, v, causal, q_start=0, k_start=0, block=128):
@@ -676,3 +681,195 @@ def test_model_at_head_64_equals_the_head_major_route(monkeypatch, family):
     assert len(flat) == len(flat_major)
     for g, e in zip(flat, flat_major):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the walk: one grid axis over the list of live tiles (PR 36)
+# ---------------------------------------------------------------------------
+
+# s_q, s_kv, block_q, block_k, causal, window, q_start − k_start (None: traced)
+_WALKS = {
+    "causal": (512, 512, 128, 128, True, None, 0),
+    "not-causal": (512, 512, 128, 128, False, None, 0),
+    "window-over-a-block": (512, 512, 128, 128, True, 200, 0),
+    "window-of-a-block": (512, 512, 128, 128, True, 128, 0),
+    "window-of-one-key": (512, 512, 128, 128, True, 1, 0),
+    "unequal-blocks": (512, 512, 64, 256, True, 300, 0),
+    "kv-blocks-no-query-sees": (256, 768, 128, 64, True, None, 0),
+    "chunk-at-the-end": (256, 768, 128, 64, True, None, 512),
+    "q-blocks-whose-windows-hold-no-key": (768, 256, 64, 128, True, 100, 0),
+    "q-blocks-before-every-key": (512, 512, 128, 128, True, None, -256),
+    "odd-offset": (512, 384, 128, 128, True, 96, 37),
+    "padded-203": (256, 256, 64, 64, True, 70, 0),  # what 203 rows pad to: the tail is masked, never skipped
+    "traced": (640, 384, 128, 128, True, 100, None),
+}
+
+
+def _tiles_with_a_visible_key(s_q, s_kv, block_q, block_k, causal, window, offset):
+    """Brute force over positions: the tiles in which some query sees some key."""
+    if offset is None or not causal:
+        return np.ones((s_q // block_q, s_kv // block_k), bool)
+    distance = (offset + np.arange(s_q))[:, None] - np.arange(s_kv)[None, :]
+    visible = (distance >= 0) & ((distance < window) if window is not None else True)
+    return visible.reshape(s_q // block_q, block_q, s_kv // block_k, block_k).any(axis=(1, 3))
+
+
+@pytest.mark.parametrize("kv_major", [False, True], ids=["q-major", "kv-major"])
+@pytest.mark.parametrize("case", list(_WALKS))
+def test_walk_lists_the_tiles_seen_and_a_tile_of_every_block(case, kv_major):
+    """The tables the index maps read, against a brute-force count of visible
+    keys: every tile with one is there, once, in the rectangle's own order
+    (q-major: each q block's kv blocks ascending; kv-major: each kv block's q
+    blocks ascending); what else is there is the one tile of a block that has
+    none; the flags mark the first and last tile of each major block and the
+    first and last appearance of each minor one."""
+    from dsml_tpu.ops import flash
+
+    s_q, s_kv, block_q, block_k, causal, window, offset = _WALKS[case]
+    want = _tiles_with_a_visible_key(*_WALKS[case])
+    live = flash._live_tiles(s_q // block_q, s_kv // block_k, block_q, block_k, causal, window, offset)
+    q_of, kv_of, flags = (np.asarray(t) for t in flash._walk(live, kv_major))
+    assert all(t.dtype == np.int32 for t in (q_of, kv_of, flags))
+
+    tiles = list(zip(q_of.tolist(), kv_of.tolist()))
+    assert len(set(tiles)) == len(tiles)
+    assert set(tiles) >= set(zip(*np.nonzero(want)))
+    assert set(q_of) == set(range(s_q // block_q)) and set(kv_of) == set(range(s_kv // block_k))
+    fillers = set(tiles) - set(zip(*np.nonzero(want)))  # a block with no key to see keeps one tile
+    assert all(not want[qi].any() or not want[:, ki].any() for qi, ki in fillers)
+    assert len(fillers) <= (~want.any(1)).sum() + (~want.any(0)).sum()
+    assert tiles == sorted(tiles, key=(lambda t: t[::-1]) if kv_major else None)
+
+    major, minor = (kv_of, q_of) if kv_major else (q_of, kv_of)
+    steps = np.arange(len(tiles))
+    for bit, of, pick in ((flash._ROW_FIRST, major, min), (flash._ROW_LAST, major, max),
+                          (flash._SEEN_FIRST, minor, min), (flash._SEEN_LAST, minor, max)):
+        assert sorted(steps[flags & bit != 0]) == sorted(pick(steps[of == block]) for block in set(of))
+    if offset is None or not causal:
+        assert len(tiles) == want.size  # the rectangle: the list no one could shorten
+
+
+@pytest.mark.parametrize("shape,window,walked,rectangle", [
+    ((8192, 128), 1024, 45, 256),  # mellum2-8k, a sliding layer
+    ((8192, 128), None, 136, 256),  # mellum2-8k's full layer, jamba2-3b-8k
+    ((8192, 64), None, 36, 64),  # gpt2s-8k
+    ((1024, 64), None, 3, 4),  # gpt2s-1k, gpt2l-1k, gpt2l-1k-dp4
+    ((203, 64), None, 1, 1),
+    ((2001, 128), 512, 7, 16),  # padded to 2048: rows of 1 + 2 + 2 + 2
+])
+def test_grid_steps_at_the_cells_shapes(shape, window, walked, rectangle):
+    from dsml_tpu.ops.flash import grid_steps
+
+    seq, head_dim = shape
+    assert grid_steps(seq, seq, head_dim, window=window) == (walked, rectangle)
+    assert grid_steps(seq, seq, head_dim, window=window, offset=None) == (rectangle, rectangle)
+
+
+def _grids(fn, *args):
+    """``{kernel name: grid}`` of the Pallas calls in ``fn``'s jaxpr."""
+    return dict(_pallas_calls(fn, *args))
+
+
+# s_q, s_kv, head_dim, block, causal, window, q_start, k_start, dtype
+_BIT_EQUAL = {
+    "causal": (384, 384, 64, 128, True, None, 0, 0, jnp.float32),
+    "causal-bf16": (384, 384, 64, 128, True, None, 0, 0, jnp.bfloat16),
+    "window": (384, 384, 64, 128, True, 150, 0, 0, jnp.float32),
+    "window-head-128-bf16": (512, 512, 128, 128, True, 128, 0, 0, jnp.bfloat16),
+    "padded-203": (203, 203, 64, 64, True, None, 0, 0, jnp.float32),
+    "padded-203-window": (203, 203, 32, 64, True, 70, 0, 0, jnp.float32),
+    "unequal-lengths-offset-128": (256, 512, 64, 128, True, None, 256, 128, jnp.float32),
+    "q-block-before-every-key": (256, 256, 64, 128, True, None, 0, 128, jnp.float32),
+    "kv-blocks-no-query-sees": (256, 384, 64, 128, True, None, 0, 0, jnp.float32),
+    "not-causal": (384, 384, 64, 128, False, None, 0, 0, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_BIT_EQUAL))
+def test_walk_is_bit_equal_to_the_rectangle(case):
+    """Offsets known as the call is traced (Python ints: the walk over the
+    live tiles) against the same offsets handed as ``jnp.int32`` (the
+    rectangle, ``_seen`` deciding inside the step): the same tiles through the
+    same bodies in the same order, so ``out``, ``lse`` and all three gradients
+    are equal to the bit, under a cotangent on both outputs."""
+    from dsml_tpu.ops import flash
+
+    s_q, s_kv, d, block, causal, window, q_start, k_start, dtype = _BIT_EQUAL[case]
+    rng = np.random.default_rng(s_q + s_kv + d)
+    q = jnp.asarray(rng.standard_normal((1, 2, s_q, d)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, s_kv, d)), dtype) for _ in range(2))
+    w_out, w_lse = jnp.cos(jnp.arange(float(d))), jnp.sin(jnp.arange(float(s_q)))
+
+    def run(qs, ks):
+        def fn(q, k, v, *offsets):
+            out, lse = flash.flash_attention_lse(q, k, v, causal, *(offsets or (qs, ks)), block, block, window=window)
+            return (out.astype(jnp.float32) * w_out).sum() + (lse * w_lse).sum(), (out, lse)
+
+        offsets = () if isinstance(qs, int) else (qs, ks)
+        grads = jax.grad(fn, argnums=(0, 1, 2), has_aux=True)
+        return jax.jit(grads)(q, k, v, *offsets), _grids(grads, q, k, v, *offsets)
+
+    (walk_grads, walk_out), walk_grid = run(q_start, k_start)
+    (rect_grads, rect_out), rect_grid = run(jnp.int32(q_start), jnp.int32(k_start))
+    for got, want in zip((*walk_out, *walk_grads), (*rect_out, *rect_grads)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+    pq, pk = -(-s_q // block) * block, -(-s_kv // block) * block
+    walked, rectangle = flash.grid_steps(s_q, s_kv, d, causal, window, q_start - k_start, block, block)
+    assert rectangle == (pq // block) * (pk // block)
+    assert walk_grid == {"flash_fwd": (2, 1, walked), "flash_dkv": (2, 1, walked)}
+    assert rect_grid == {"flash_fwd": (2, 1, rectangle), "flash_dkv": (2, 1, rectangle)}
+    assert (walked < rectangle) == (causal and rectangle > 1)
+
+
+@pytest.mark.parametrize("case", ["2-heads-of-64", "2-heads-of-128", "grouped-4-on-2", "padded-203", "pair-2-heads-of-64"])
+def test_packed_walk_is_bit_equal_to_the_rectangle(monkeypatch, case):
+    """The packed entry always knows its offsets (0 and 0); with that
+    knowledge denied it steps through the rectangle, and loss and gradients
+    are the same bits. The last case holds the pair (``flash_dq`` walks
+    q-major as the forward does) to the same."""
+    from dsml_tpu.ops import flash
+
+    if _PACKED[case][-1]:
+        monkeypatch.setattr(flash, "_VMEM_BUDGET", 0)
+    inputs, packed, _, _ = _packed_case(case)
+    argnums = tuple(range(len(inputs)))
+    walk = jax.jit(jax.value_and_grad(packed, argnums))(*inputs)
+    walk_grid = _grids(jax.grad(packed, argnums), *inputs)
+    monkeypatch.setattr(flash, "_static_offset", lambda q_start, k_start: None)
+    rectangle = jax.jit(jax.value_and_grad(packed, argnums))(*inputs)
+    rect_grid = _grids(jax.grad(packed, argnums), *inputs)
+    for got, want in zip(jax.tree.leaves(walk), jax.tree.leaves(rectangle)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert set(walk_grid) == set(rect_grid) == {"flash_fwd", "flash_dkv"} | ({"flash_dq"} if _PACKED[case][-1] else set())
+    for name in walk_grid:
+        assert walk_grid[name][:2] == rect_grid[name][:2] and walk_grid[name][2] < rect_grid[name][2]
+
+
+@pytest.mark.parametrize("window", [None, 100])
+@pytest.mark.parametrize("pair", [False, True], ids=["fused", "pair"])
+@pytest.mark.parametrize("q_start,k_start", [(0, 0), (0, 128), (256, 0)])
+def test_block_grads_walk_is_bit_equal_to_the_rectangle(monkeypatch, pair, window, q_start, k_start):
+    """``flash_block_grads`` (no custom VJP between the caller's offsets and
+    the kernels): Python ints walk the live tiles, tracers the rectangle, in
+    the one kernel and in the pair; ``dq``, ``dk``, ``dv`` equal to the bit."""
+    from dsml_tpu.ops import flash
+
+    if pair:
+        monkeypatch.setattr(flash, "_VMEM_BUDGET", 0)
+    rng = np.random.default_rng(36)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((1, 2, 384, 64)), jnp.float32) for _ in range(4))
+    out, lse = jax.jit(lambda: flash.flash_attention_lse(q, k, v, True, q_start, k_start, 128, 128, window=window))()
+
+    def grads(*offsets):
+        return flash.flash_block_grads(q, k, v, out, lse, do, None, True, *(offsets or (q_start, k_start)), 128, 128,
+                                       window=window)
+
+    traced = (jnp.int32(q_start), jnp.int32(k_start))
+    for got, want in zip(jax.jit(grads)(), jax.jit(grads)(*traced)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    walk_grid, rect_grid = _grids(grads), _grids(grads, *traced)
+    assert list(walk_grid) == list(rect_grid) == (["flash_dq", "flash_dkv"] if pair else ["flash_dkv"])
+    walked = flash.grid_steps(384, 384, 64, True, window, q_start - k_start, 128, 128)[0]
+    assert all(grid == (2, 1, walked) for grid in walk_grid.values())
+    assert all(grid == (2, 1, 9) for grid in rect_grid.values())

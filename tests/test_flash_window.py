@@ -146,3 +146,31 @@ def test_window_none_is_bit_equal_to_the_call_without_it(seq, block):
     np.testing.assert_allclose(c, a, rtol=1e-6)
     for x, y in zip(gc, ga):
         np.testing.assert_allclose(x, y, atol=1e-5)
+
+
+@pytest.mark.parametrize("seq,block,window", CASES)
+def test_windowed_walk_is_bit_equal_to_the_rectangle(monkeypatch, seq, block, window):
+    """The grid walks only the tiles the window and the diagonal leave (PR
+    36); with the offsets' knowledge denied it steps through the rectangle
+    and ``_seen`` decides inside the step. Same tiles, same order, same bits,
+    forward and all three gradients; and the walk is the shorter whenever a
+    tile could be left out."""
+    q, k, v, weight = _qkv(seq)
+
+    def grads():
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v, block_q=block, block_k=block, window=window) * weight),
+            (0, 1, 2)))(q, k, v)
+
+    (walk, walk_grads) = grads()
+    monkeypatch.setattr(flash, "_static_offset", lambda q_start, k_start: None)
+    (rectangle, rectangle_grads) = grads()
+    assert np.array_equal(walk, rectangle)
+    assert all(np.array_equal(a, b) for a, b in zip(walk_grads, rectangle_grads))
+
+    walked, tiles = flash.grid_steps(seq, seq, 32, window=window, block_q=block, block_k=block)
+    block, padded = flash._pad_choice(seq, block)  # 200 and 136 tile exactly by 8: no padding, many small tiles
+    blocks = padded // block
+    assert tiles == blocks * blocks and walked < tiles
+    reach = -(-(window - 1) // block) + 1  # the kv blocks a q block's window can touch
+    assert walked == sum(min(qi + 1, reach) for qi in range(blocks))

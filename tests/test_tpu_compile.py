@@ -422,6 +422,21 @@ def test_flash_compiles_at_head_128_with_and_without_a_window(topo, window, what
     assert found == ({"flash_fwd"} if what == "fwd" else {"flash_fwd", "flash_dkv"})
 
 
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_flash_compiles_on_the_rectangle_where_offsets_are_traced(topo, window):
+    """What the ring and cp callers lower (PR 36): offsets that are values of the program, so the walk's
+    tables list the whole rectangle (256 tiles of 512 here) and ``_seen`` decides inside the step."""
+    from dsml_tpu.ops.flash import flash_attention_lse
+
+    def loss(q, k, v, q_start, k_start):
+        out, lse = flash_attention_lse(q, k, v, True, q_start, k_start, interpret=False, window=window)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    qkv, at = _sds((1, 4, 8192, 128), jnp.bfloat16), _sds((), jnp.int32)
+    text = _compile(topo, jax.grad(loss, (0, 1, 2)), qkv, qkv, qkv, at, at)
+    assert _flash_kernels_in(text) == ["flash_fwd", "flash_dkv"]
+
+
 @pytest.mark.parametrize("tile", [128, 256, 512])
 def test_grouped_matmuls_compile_and_carry_their_names(topo, tile):
     """The three kernels of an expert matmul and its backward at 65,536 pairs over 64 experts of
