@@ -150,6 +150,7 @@ def sample_token_logits(logits, key, temperature: float, top_k: int = 0,
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("normalize")
 def _layer_norm(x, scale, bias, eps=1e-5):
     x32 = x.astype(jnp.float32)
     mean = x32.mean(-1, keepdims=True)

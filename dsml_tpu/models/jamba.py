@@ -202,7 +202,8 @@ class Jamba(Llama):
         cfg, p = self.config, layer["ssm"]
         x = _rms_norm(h, layer["rms_1"]["scale"], cfg.rms_eps)
         u, z = jnp.split(qmatmul(x, p["w_in"], x.dtype), 2, axis=-1)
-        u = jax.nn.silu(_causal_conv(u, p["conv_w"], p["conv_b"]))
+        with jax.named_scope("ssm_conv"):
+            u = jax.nn.silu(_causal_conv(u, p["conv_w"], p["conv_b"]))
         dt, b, c = jnp.split(qmatmul(u, p["w_x"], u.dtype),
                              [cfg.dt_rank, cfg.dt_rank + cfg.d_state], axis=-1)
         dt = _rms_norm(dt, p["dt_norm"], cfg.rms_eps)

@@ -122,6 +122,7 @@ class LlamaConfig:
         )
 
 
+@jax.named_scope("normalize")
 def _rms_norm(x, scale, eps=1e-5):
     x32 = x.astype(jnp.float32)
     rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -271,6 +272,7 @@ class Llama(GPT2):
             return lax.psum(params["wte"][safe_ids] * in_shard[..., None], tp_axis)
         return params["wte"][tokens]
 
+    @jax.named_scope("rope")
     def _rotate(self, t, positions, head_axis=1):
         """Positions enter here, on q and k (a family without rotary
         overrides with the identity)."""
@@ -296,8 +298,9 @@ class Llama(GPT2):
         v = heads(qmatmul(x, layer["attn"]["wv"], x.dtype), n_kv_local)
         q, k = self._rotate(q, positions, head_axis), self._rotate(k, positions, head_axis)
         repeat = n_head_local // n_kv_local
-        ka = jnp.repeat(k, repeat, axis=head_axis) if repeat > 1 else k
-        va = jnp.repeat(v, repeat, axis=head_axis) if repeat > 1 else v
+        with jax.named_scope("kv_repeat"):
+            ka = jnp.repeat(k, repeat, axis=head_axis) if repeat > 1 else k
+            va = jnp.repeat(v, repeat, axis=head_axis) if repeat > 1 else v
         return q, k, v, ka, va
 
     def _block(self, layer, h, n_head_local, tp_axis, sp_axis, attn_impl):

@@ -190,6 +190,7 @@ class Mellum(Llama):
 
     # ---- architecture ---------------------------------------------------------
 
+    @jax.named_scope("rope")
     def _rotate(self, t, table, head_axis=1):
         """Rotate-half by the layer's own ``(cos, sin)`` table, which
         ``_qkv_gqa`` hands through where ``Llama`` hands positions."""

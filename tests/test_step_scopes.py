@@ -3,15 +3,18 @@
 instruction of the compiled hybrid step, forward and, where there is one,
 backward (``transpose(``). Compile-time metadata only: nothing runs."""
 
+import dataclasses
 import functools
 import re
 
 import jax
+import jax.numpy as jnp
 import optax
 import pytest
 
 from dsml_tpu.models.gpt2 import GPT2, GPT2Config
 from dsml_tpu.models.jamba import Jamba, JambaConfig
+from dsml_tpu.models.llama import Llama, LlamaConfig
 from dsml_tpu.models.mellum import Mellum, MellumConfig
 from dsml_tpu.parallel.hybrid import make_hybrid_train_step
 from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
@@ -21,6 +24,7 @@ from dsml_tpu.parallel.mesh import MeshSpec, build_mesh
 def _op_names(dp: int, dp_sync: str = "xla", family: str = "gpt2") -> list[list[str]]:
     """The op names of the compiled tiny step, each split into its components."""
     model = {"gpt2": lambda: GPT2(GPT2Config.tiny()), "jamba": lambda: Jamba(JambaConfig.tiny(remat=True)),
+             "llama": lambda: Llama(dataclasses.replace(LlamaConfig.tiny(), remat=True)),
              "mellum": lambda: Mellum(MellumConfig.tiny(remat=True))}[family]()
     optimizer = optax.adamw(1e-3)
     mesh = build_mesh(MeshSpec(dp=dp), jax.devices()[:dp])
@@ -98,3 +102,69 @@ def test_compiled_mellum_step_names_its_work(scope, backward):
         assert all("mlp" in tokens for tokens in names if scope in tokens)
     if scope.startswith("attn_"):
         assert all("attn" in tokens for tokens in names if scope in tokens)
+
+
+# -- one level down (PR 37): the norms, the rotation, the key-value repeat, the convolution ------
+
+REMAT = "rematted_computation"  # what `jax.checkpoint` writes round the forward it computes again
+INNER = ("normalize", "rope", "kv_repeat", "ssm_conv")
+_SETS = {  # family -> the inner names its step holds; the others it must not hold
+    "gpt2": ("normalize",),                           # learned positions, as many key-value heads as heads
+    "llama": ("normalize", "rope", "kv_repeat"),      # 8 query heads on 2
+    "jamba": ("normalize", "kv_repeat", "ssm_conv"),  # `_rotate` returns its input and stays bare
+    "mellum": ("normalize", "rope", "kv_repeat"),
+}
+
+
+def _direction(tokens, scope: str) -> str:
+    """As ``benchmarks/name_reduce.py`` reads it."""
+    if REMAT in tokens:
+        return "remat"
+    return "bwd" if "transpose" in tokens[:tokens.index(scope)] else "fwd"
+
+
+@pytest.mark.parametrize("family,scope,direction", [
+    (family, scope, direction) for family, scopes in _SETS.items() for scope in scopes
+    for direction in (("fwd", "bwd") if family == "gpt2" else ("fwd", "remat", "bwd"))])  # tiny GPT-2: no remat
+def test_compiled_step_names_its_work_one_level_down(family, scope, direction):
+    names = [tokens for tokens in _op_names(1, family=family) if scope in tokens]
+    assert any(_direction(tokens, scope) == direction for tokens in names)
+    # each inside one of the scopes that were there, so every accepted reader still finds its own name
+    outer = {"ssm_conv": {"ssm"}, "rope": {"attn"}, "kv_repeat": {"attn"},
+             "normalize": {"attn", "mlp", "ssm", "loss_head"}}[scope]
+    assert all(outer.intersection(tokens) for tokens in names)
+
+
+@pytest.mark.parametrize("family,scope", [
+    (family, scope) for family, scopes in _SETS.items() for scope in INNER if scope not in scopes])
+def test_compiled_step_holds_no_name_for_work_it_does_not_do(family, scope):
+    assert not any(scope in tokens for tokens in _op_names(1, family=family))
+
+
+def test_checkpoint_writes_the_component_the_recomputed_forward_is_read_by():
+    """``remat_ms`` (``benchmarks/layer_metrics``) reads the instructions whose op name holds
+    ``rematted_computation``; a jax that renames it fails here and does not silently null the metric."""
+    grad = jax.jit(jax.grad(jax.checkpoint(lambda x: jnp.sin(jnp.sin(x)).sum())))
+    text = grad.lower(jnp.ones(8)).compile().as_text()
+    names = [re.split(r"[/();]", name) for name in re.findall(r'op_name="([^"]*)"', text)]
+    assert any(REMAT in tokens and "transpose" in tokens[:tokens.index(REMAT)] for tokens in names)
+
+
+def test_the_inner_names_collide_with_nothing_jax_writes():
+    """The same ``jnp`` calls the four scopes wrap, jitted here under no scope: a root-mean-square
+    norm, ``jnp.repeat``, the rotation and a depthwise convolution by taps, forward and backward."""
+    def bare(x, scale, cos, sin, taps):
+        x32 = x.astype(jnp.float32)
+        normed = (x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)).astype(x.dtype) * scale
+        repeated = jnp.repeat(normed.reshape(2, 16, 2, 8), 4, axis=2)
+        x1, x2 = repeated[..., :4], repeated[..., 4:]
+        rotated = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).reshape(2, 16, 64)
+        padded = jnp.pad(rotated, ((0, 0), (3, 0), (0, 0)))
+        conv = sum(padded[:, k:k + 16] * taps[k] for k in range(4))
+        return jax.nn.silu(conv).sum()
+
+    shapes = [jax.ShapeDtypeStruct(shape, "float32") for shape in ((2, 16, 16), (16,), (16, 1, 4), (16, 1, 4), (4, 64))]
+    text = jax.jit(jax.grad(bare, argnums=(0, 1, 4))).lower(*shapes).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert len(names) > 20
+    assert not [name for name in names if set(INNER).intersection(re.split(r"[/();]", name))]
