@@ -1,28 +1,41 @@
 """A layer of sparse gated experts as it is deployed: every token goes to its
 ``top_k`` experts, none is dropped, and the work is a function of shapes alone.
 
-``y_t = Σ_e w_te · (SiLU(x_t·Wg_e) ⊙ (x_t·Wu_e))·Wd_e`` over the ``top_k`` experts
+``y_t = Σ_e (w_te · SiLU(x_t·Wg_e) ⊙ (x_t·Wu_e))·Wd_e`` over the ``top_k`` experts
 ``e`` with the largest router probabilities ``softmax(x_t·Wr)``, their weights
 renormalised to sum to one. Four steps, each under its own scope:
 
 - ``moe_route``: the router in float32, ``top_k``, and the plan: the ``T·top_k``
   (token, expert) pairs stable-sorted by expert into one row buffer, each
   expert's rows padded to whole tiles (:func:`plan`). The plan is integers only
-  and is tagged (:data:`PLAN_NAMES`) so that a block recomputed in the backward
-  can keep it and not sort twice.
-- ``moe_dispatch``: the rows gathered into the buffer ``[rows, d]``.
+  and is tagged (:data:`PLAN_NAMES`, as ``row_w`` is) so that a block recomputed
+  in the backward can keep it and not sort twice.
+- ``moe_dispatch``: the rows gathered into the buffer ``[rows, d]``, and each
+  row's weight ``row_w [rows]`` beside them (0 on a padding row).
 - ``experts``: three grouped matmuls (``ops/grouped_matmul.py``: ``gmm_fwd``,
-  and ``gmm_dx`` / ``gmm_dw`` in the backward) with the gate between them.
-- ``moe_combine``: each token's ``top_k`` rows gathered back and summed under
-  its weights.
+  and ``gmm_dx`` / ``gmm_dw`` in the backward) with the gate between them. The
+  weight lies on the gate, ``mid = SiLU(gate) ⊙ up · row_w`` in float32 and one
+  cast, BEFORE ``Wd``, so the backward needs nothing of the down projection's
+  output: a block recomputed there runs two grouped matmuls, not three, and
+  ``dw`` is a row sum inside the gate's backward.
+- ``moe_combine``: each token's ``top_k`` rows gathered back and summed.
 
-Dispatch and combine are gathers in both directions (a row's cotangent is read
-from where its pair went; nothing is scattered), so they carry their own
-backward. The buffer holds ``T·min(top_k, held) / tile + held`` tiles whatever
-the router does (``n_row_tiles``) and every one is computed: at uniform routing
-and with every token on one set of ``top_k`` experts the layer does the same
-work (PERF.md §6, PR 35: a cell whose work followed the router could not be
-measured).
+**Two movements, each the other's backward** (:func:`_moved`): token -> rows
+(``x[row_pair // top_k]``) and rows -> token (``Σ_j rows[dest[t, j]]`` in float32
+over the pairs that have a row). The dispatch is the first with the second as
+its backward, the combine the second with the first; the weights go the first
+way too. Both are gathers (a row's cotangent is read from where its pair went).
+
+**Padding rows** hold token 0's ``x`` and, in the backward, token 0's ``dy``:
+the spread is not masked. ``ops/grouped_matmul.py`` wants them to reach
+``gmm_dw`` as zeros, and the zero comes from the weight: ``mid · row_w`` is 0
+there, so ``dWd`` sees nothing, and ``d_mid = d(mid · w) · row_w`` is 0 there, so
+``dWg``, ``dWu`` and ``dx`` see nothing; no token reads a padding row back.
+
+The buffer holds ``T·min(top_k, held) / tile + held`` tiles whatever the router
+does (``n_row_tiles``) and every one is computed: at uniform routing and with
+every token on one set of ``top_k`` experts the layer does the same work (a
+cell whose work followed the router could not be measured: PERF.md §6, PR 35).
 
 ``experts_held = (first, count)`` is the chip's share of a deployment that
 spreads a layer's experts over several chips: the router stays as wide as
@@ -43,10 +56,10 @@ from dsml_tpu.ops.grouped_matmul import grouped_matmul, n_row_tiles
 
 __all__ = ["PLAN_NAMES", "expert_layer", "plan", "route"]
 
-# the integers of a layer's routing: under `jax.checkpoint` with
-# `save_only_these_names(*PLAN_NAMES)` the recomputed forward reads them back (0.9 MB a layer at
-# 8,192 tokens) where it would run top-k, the sort and the index arithmetic a second time
-PLAN_NAMES = ("moe_top_e", "moe_row_pair", "moe_dest", "moe_tile_group")
+# the integers of a layer's routing and each row's weight: under `jax.checkpoint` with
+# `save_only_these_names(*PLAN_NAMES)` the recomputed forward reads them back (1.3 MB a layer at 8,192
+# tokens) where it would run top-k, the sort, the index arithmetic and a gather of scalars a second time
+PLAN_NAMES = ("moe_top_e", "moe_row_pair", "moe_dest", "moe_tile_group", "moe_row_w")
 
 
 def route(x, w_router, top_k: int):
@@ -99,51 +112,34 @@ def plan(top_e, held: tuple[int, int], tile: int):
             checkpoint_name(tile_group, "moe_tile_group"))
 
 
-@jax.custom_vjp
-def _dispatch(x, row_pair, dest):
-    """``x [T, d]`` -> the buffer ``[rows, d]``; padding rows hold token 0's
-    (no one reads them, and their cotangent arrives as zeros)."""
+def _to_rows(x, row_pair, dest):
+    """Token -> rows: ``x [T, n]`` -> ``[rows, n]``, row ``r`` the entry of its
+    pair's token; a padding row holds token 0's."""
     return x[jnp.maximum(row_pair, 0) // dest.shape[1]]
 
 
-def _dispatch_fwd(x, row_pair, dest):
-    return _dispatch(x, row_pair, dest), dest
-
-
-def _dispatch_bwd(dest, d_rows):
-    with jax.named_scope("moe_dispatch"):
-        picked = d_rows[jnp.maximum(dest, 0)].astype(jnp.float32)  # [T, k, d]
-        dx = jnp.sum(jnp.where((dest >= 0)[:, :, None], picked, 0.0), axis=1)
-    return dx.astype(d_rows.dtype), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _combine(rows, w, row_pair, dest):
-    """``y [T, d] = Σ_j w[t, j] · rows[dest[t, j]]`` in float32, over the
-    pairs that have a row."""
+def _to_tokens(rows, row_pair, dest):
+    """Rows -> token: ``[rows, n]`` -> ``[T, n]``, ``Σ_j rows[dest[t, j]]`` in
+    float32 over the pairs that have a row."""
     picked = rows[jnp.maximum(dest, 0)].astype(jnp.float32)
-    return jnp.sum(jnp.where(dest >= 0, w, 0.0)[:, :, None] * picked, axis=1).astype(rows.dtype)
+    return jnp.sum(jnp.where((dest >= 0)[:, :, None], picked, 0.0), axis=1).astype(rows.dtype)
 
 
-def _combine_fwd(rows, w, row_pair, dest):
-    return _combine(rows, w, row_pair, dest), (rows, w, row_pair, dest)
+def _moved(move, back, scope: str):
+    """``move(a, row_pair, dest)`` with ``back``, its transpose, as its
+    backward under ``scope``: each of the two movements is the other's."""
+    moved = jax.custom_vjp(move)
+
+    def bwd(row_plan, d):
+        with jax.named_scope(scope):
+            return back(d, *row_plan), None, None
+
+    moved.defvjp(lambda a, *row_plan: (move(a, *row_plan), row_plan), bwd)
+    return moved
 
 
-def _combine_bwd(res, dy):
-    rows, w, row_pair, dest = res
-    with jax.named_scope("moe_combine"):
-        pair = jnp.maximum(row_pair, 0)
-        row_w = jnp.where(row_pair >= 0, w.reshape(-1)[pair], 0.0)
-        d_rows = (row_w[:, None] * dy[pair // dest.shape[1]].astype(jnp.float32)).astype(rows.dtype)
-        picked = rows[jnp.maximum(dest, 0)].astype(jnp.float32)
-        dw = jnp.where(dest >= 0, jnp.sum(picked * dy.astype(jnp.float32)[:, None, :], axis=-1), 0.0)
-    return d_rows, dw.astype(w.dtype), None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_dispatch = _moved(_to_rows, _to_tokens, "moe_dispatch")
+_collect = _moved(_to_tokens, _to_rows, "moe_combine")
 
 
 def expert_layer(p: dict, x, *, top_k: int, tile: int, experts_held: tuple[int, int] | None = None):
@@ -159,9 +155,13 @@ def expert_layer(p: dict, x, *, top_k: int, tile: int, experts_held: tuple[int, 
         row_pair, dest, tile_group = plan(top_e, held, tile)
     with jax.named_scope("moe_dispatch"):
         rows = _dispatch(x, row_pair, dest)
+        # the weights go the rows' way (a gather of scalars by pair is slower than this one of a token's
+        # top_k), and a row keeps its own pair's: [T, k] -> [rows, k] -> [rows], 0 on a padding row
+        own = (row_pair[:, None] >= 0) & (row_pair[:, None] % top_k == jnp.arange(top_k))
+        row_w = checkpoint_name(jnp.sum(jnp.where(own, _dispatch(w, row_pair, dest), 0.0), axis=1), "moe_row_w")
     with jax.named_scope("experts"):
-        mid = jax.nn.silu(grouped_matmul(rows, p["w_gate"], tile_group, tile)) * grouped_matmul(
-            rows, p["w_up"], tile_group, tile)
+        gate, up = (grouped_matmul(rows, p[name], tile_group, tile).astype(jnp.float32) for name in ("w_gate", "w_up"))
+        mid = (jax.nn.silu(gate) * up * row_w[:, None]).astype(rows.dtype)
         rows = grouped_matmul(mid, p["w_down"], tile_group, tile)
     with jax.named_scope("moe_combine"):
-        return _combine(rows, w, row_pair, dest)
+        return _collect(rows, row_pair, dest)

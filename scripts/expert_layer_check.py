@@ -1,8 +1,10 @@
 """The expert layer alone on the chip, at the cell's shape: ``jax.value_and_grad``
 of ``models/experts.py::expert_layer`` on ``[8192, 2304]`` tokens, 64 gated experts
-of width 896, 8 a token, bf16; device time a call split by the layer's own scopes
-into route (router, top-k, sort, plan) / dispatch (gather) / experts (the grouped
-matmuls, and of them the kernels by name) / combine, forward and backward together.
+of width 896, 8 a token, bf16, under the checkpoint a block of ``models/mellum.py``
+runs it in (all but what ``PLAN_NAMES`` tags computed again in the backward); device
+time a call split by the layer's own scopes into route (router, top-k, sort, plan)
+/ dispatch (gather) / experts (the grouped matmuls, and of them the kernels by
+name) / combine, forward, recomputed forward and backward together.
 
     chiprun -- python3 scripts/expert_layer_check.py [--tiles 128 256 512]
 
@@ -10,11 +12,13 @@ Each ``--tiles`` entry (the row tile of the grouped matmuls) is timed twice: wit
 the router drawn like the model's (near-uniform routing) and with a router of
 zeros, which sends every token, the same rows, to the same 8 experts. The work is a function of shapes alone
 (``ops/grouped_matmul.py``), so the two must take the same time; ``collapsed_ratio``
-is collapsed over uniform. Values are held to the dense form (every expert for
-every token) at ``--rehearse`` sizes on the CPU and by ``tests/test_mellum.py``; on
-the chip the cell's own check does that at the published widths. Time is the
-device's, from a ``jax.profiler`` trace of ``--calls`` calls after two warm ones,
-reduced by ``benchmarks/moe_reduce``. The last line is one JSON object.
+is collapsed over uniform, and ``gmm_fwd_calls`` the forward kernel's calls in the
+differentiated jaxpr (5: three forward, the gate's two again). Values are held to
+the dense form (every expert for every token) at ``--rehearse`` sizes on the CPU
+and by ``tests/test_mellum.py``; on the chip the cell's own check does that at the
+published widths. Time is the device's, from a ``jax.profiler`` trace of ``--calls``
+calls after two warm ones, reduced by ``benchmarks/moe_reduce``. The last line is
+one JSON object.
 """
 
 from __future__ import annotations
@@ -30,6 +34,14 @@ sys.path.insert(0, str(REPO))
 TRACE_DIR = REPO / ".bench_trace" / "expert_layer"
 
 
+def kernel_calls(jaxpr, name: str) -> int:
+    """The ``pallas_call``s named ``name`` in ``jaxpr`` and the jaxprs its equations hold."""
+    import jax
+
+    return sum((eqn.primitive.name == "pallas_call" and eqn.params["name"] == name)
+               + sum(kernel_calls(inner, name) for inner in jax.core.jaxprs_in_params(eqn.params)) for eqn in jaxpr.eqns)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiles", type=int, nargs="*", default=[256])
@@ -42,7 +54,7 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     from benchmarks import moe_reduce, trace_reduce
-    from dsml_tpu.models.experts import expert_layer, route
+    from dsml_tpu.models.experts import PLAN_NAMES, expert_layer, route
 
     tokens, d, f, experts, top_k = (256, 128, 128, 8, 2) if args.rehearse else (8192, 2304, 896, 64, 8)
     tiles = [16] if args.rehearse else args.tiles
@@ -64,9 +76,10 @@ def main(argv=None) -> int:
 
     out = {}
     for tile in tiles:
-        fn = jax.jit(jax.value_and_grad(
-            lambda p, x: jnp.sum(expert_layer(p, x, top_k=top_k, tile=tile).astype(jnp.float32) * weight), argnums=(0, 1)))
-        row = {}
+        layer = jax.checkpoint(lambda p, x: expert_layer(p, x, top_k=top_k, tile=tile),
+                               policy=jax.checkpoint_policies.save_only_these_names(*PLAN_NAMES))
+        fn = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32) * weight), argnums=(0, 1)))
+        row = {"gmm_fwd_calls": kernel_calls(jax.make_jaxpr(fn)(p, x).jaxpr, "gmm_fwd")}
         for name, operands in (("uniform", (p, x)), ("collapsed", (collapsed, x))):
             top_e, _ = route(operands[1], operands[0]["router"], top_k)
             load = jnp.sum(top_e.reshape(-1, 1) == jnp.arange(experts), axis=0)

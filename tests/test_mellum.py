@@ -24,6 +24,7 @@ from dsml_tpu.models.mellum import Mellum, MellumConfig, rotary_tables  # noqa: 
 from dsml_tpu.ops.grouped_matmul import grouped_matmul, n_row_tiles  # noqa: E402
 from dsml_tpu.parallel.hybrid import hybrid_loss_fn, init_hybrid, make_hybrid_train_step  # noqa: E402
 from dsml_tpu.parallel.mesh import MeshSpec, build_mesh  # noqa: E402
+from scripts.expert_layer_check import kernel_calls  # noqa: E402
 
 SEQ = 96
 
@@ -205,15 +206,70 @@ def _share(p, first, count):
     return {**p, **{name: p[name][first:first + count] for name in ("w_gate", "w_up", "w_down")}}
 
 
+def _expert_layer(top_k, tile, checkpointed):
+    """The layer as a test calls it, or as a block of ``models/mellum.py``
+    recomputed in the backward does: all made again but what ``PLAN_NAMES`` tags."""
+    def run(p, x):
+        return experts.expert_layer(p, x, top_k=top_k, tile=tile)
+
+    return jax.checkpoint(run, policy=jax.checkpoint_policies.save_only_these_names(*experts.PLAN_NAMES)) if checkpointed else run
+
+
+@pytest.mark.parametrize("checkpointed", [False, True], ids=["plain", "checkpointed"])
 @pytest.mark.parametrize("top_k,tile", [(2, 16), (3, 32), (8, 16)])
-def test_expert_layer_and_its_gradients_match_the_dense_form(top_k, tile):
+def test_expert_layer_and_its_gradients_match_the_dense_form(top_k, tile, checkpointed):
     p, x = _layer()
     weight = jax.random.normal(jax.random.key(9), x.shape)
-    got = jax.jit(jax.value_and_grad(
-        lambda p, x: jnp.sum(experts.expert_layer(p, x, top_k=top_k, tile=tile) * weight), (0, 1)))(p, x)
+    layer = _expert_layer(top_k, tile, checkpointed)
+    got = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(layer(p, x) * weight), (0, 1)))(p, x)
     want = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(_dense_layer(p, x, top_k) * weight), (0, 1)))(p, x)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.abs(w).max()))
+
+
+def test_checkpointed_layer_runs_the_down_projection_once():
+    """The weight lies before ``w_down``, so nothing of that product is a
+    residual: the recomputed forward holds the gate's two grouped matmuls
+    alone (3 + 2 ``gmm_fwd``; 3 + 3 with the weight behind the product), and
+    no gather of the backward reads a grouped matmul's output."""
+    p, x = _layer()
+    layer = _expert_layer(2, 16, checkpointed=True)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(layer(p, x) ** 2), (0, 1)))(p, x).jaxpr
+    assert [kernel_calls(jaxpr, name) for name in ("gmm_fwd", "gmm_dx", "gmm_dw")] == [5, 3, 3]
+    (backward,) = [eqn.params["jaxpr"] for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"]
+    assert kernel_calls(backward, "gmm_fwd") == 2
+
+    def gathers_of_products(jaxpr):  # the gathers of `jaxpr` itself that read what its grouped matmuls give
+        products = {v for eqn in jaxpr.eqns for v in eqn.outvars
+                    if any(kernel_calls(inner, "gmm_fwd") for inner in jax.core.jaxprs_in_params(eqn.params))}
+        return sum(eqn.primitive.name == "gather" and eqn.invars[0] in products for eqn in jaxpr.eqns)
+
+    assert gathers_of_products(jaxpr) == 1  # the combine: each token's rows out of the down projection's
+    assert gathers_of_products(backward) == 0 and sum(e.primitive.name == "gather" for e in backward.eqns) >= 3
+
+
+@pytest.mark.parametrize("checkpointed", [False, True], ids=["plain", "checkpointed"])
+def test_padding_rows_reach_the_weight_gradients_as_zeros(checkpointed):
+    """Token ``t`` on experts ``t % 8`` and ``(t + 1) % 8``: 24 pairs an expert
+    in two tiles of 16, so every expert ends in 8 padding rows, which hold
+    token 0's ``x`` and, in the backward, token 0's ``dy`` (not zero): their
+    weight is 0, so no expert's gradient sees them."""
+    p, x = _layer()
+    t = jnp.arange(x.shape[0])
+    x = x.at[:, :8].set(4.0 * jax.nn.one_hot(t % 8, 8) + 2.0 * jax.nn.one_hot((t + 1) % 8, 8))
+    p["router"] = jnp.zeros_like(p["router"]).at[:8].set(5.0 * jnp.eye(8))
+    top_e, _ = experts.route(x, p["router"], 2)
+    np.testing.assert_array_equal(top_e, jnp.stack([t % 8, (t + 1) % 8], axis=1))
+    row_pair, _, tile_group = experts.plan(top_e, (0, 8), 16)
+    padding = np.asarray(row_pair < 0).reshape(-1, 16).sum(axis=1)
+    assert all(padding[np.asarray(tile_group) == e].sum() >= 8 for e in range(8))
+    weight = jax.random.normal(jax.random.key(9), x.shape)
+    assert float(jnp.abs(weight[0]).min()) > 0
+    layer = _expert_layer(2, 16, checkpointed)
+    got = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x) * weight), (0, 1)))(p, x)
+    want = jax.jit(jax.grad(lambda p, x: jnp.sum(_dense_layer(p, x, 2) * weight), (0, 1)))(p, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.abs(w).max()))
 
 
