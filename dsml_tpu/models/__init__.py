@@ -1,4 +1,4 @@
-"""Model families: MLP (MNIST), CNN, ResNet-18 (CIFAR-10), GPT-2, Llama, Jamba, Mellum."""
+"""Model families: MLP (MNIST), CNN, ResNet-18 (CIFAR-10), GPT-2, Llama, Jamba, Mellum, DeepSeek-V3."""
 
 from dsml_tpu.models.mlp import MLP  # noqa: F401
 

@@ -3,7 +3,9 @@
 
 ``y_t = Σ_e (w_te · SiLU(x_t·Wg_e) ⊙ (x_t·Wu_e))·Wd_e`` over the ``top_k`` experts
 ``e`` with the largest router probabilities ``softmax(x_t·Wr)``, their weights
-renormalised to sum to one. Four steps, each under its own scope:
+renormalised to sum to one; or, with a selection bias beside the router, over
+the ``top_k`` largest ``sigmoid(x_t·Wr) + bias``, their sigmoid scores
+renormalised and scaled (:func:`route`). Four steps, each under its own scope:
 
 - ``moe_route``: the router in float32, ``top_k``, and the plan: the ``T·top_k``
   (token, expert) pairs stable-sorted by expert into one row buffer, each
@@ -62,17 +64,28 @@ __all__ = ["PLAN_NAMES", "expert_layer", "plan", "route"]
 PLAN_NAMES = ("moe_top_e", "moe_row_pair", "moe_dest", "moe_tile_group", "moe_row_w")
 
 
-def route(x, w_router, top_k: int):
+def route(x, w_router, top_k: int, bias=None, scale: float = 1.0):
     """``(top_e [T, k] int32, w [T, k] float32)``: each token's ``top_k``
-    experts by router probability (float32 logits and softmax; ties to the
-    lower index) and their probabilities renormalised to sum to one."""
+    experts and their weights, the logits in float32, ties to the lower index.
+
+    Softmax (``bias`` None): the ``top_k`` largest probabilities
+    ``softmax(x·Wr)``, renormalised to sum to one. Sigmoid (``bias [E]``, a
+    per-expert selection bias): scores ``s = sigmoid(x·Wr)``, the ``top_k``
+    largest ``s + bias`` chosen, and the chosen ``s`` (without the bias)
+    renormalised and multiplied by ``scale`` (``routed_scaling_factor``). The
+    bias takes part in the choice alone, so no gradient reaches it."""
     logits = jnp.dot(x, w_router, preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(logits, axis=-1)
-    top_e = checkpoint_name(lax.top_k(lax.stop_gradient(p), top_k)[1].astype(jnp.int32), "moe_top_e")
+    if bias is None:
+        p = pick = jax.nn.softmax(logits, axis=-1)
+    else:
+        p = jax.nn.sigmoid(logits)
+        pick = p + bias.astype(jnp.float32)
+    top_e = checkpoint_name(lax.top_k(lax.stop_gradient(pick), top_k)[1].astype(jnp.int32), "moe_top_e")
     # the chosen probabilities by a 0/1 product, not a gather: its transpose is a product too
     chosen = top_e[:, :, None] == jnp.arange(p.shape[-1], dtype=jnp.int32)
     top_p = jnp.sum(jnp.where(chosen, p[:, None, :], 0.0), axis=-1)
-    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    w = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_e, w if bias is None else w * scale
 
 
 def plan(top_e, held: tuple[int, int], tile: int):
@@ -142,16 +155,19 @@ _dispatch = _moved(_to_rows, _to_tokens, "moe_dispatch")
 _collect = _moved(_to_tokens, _to_rows, "moe_combine")
 
 
-def expert_layer(p: dict, x, *, top_k: int, tile: int, experts_held: tuple[int, int] | None = None):
+def expert_layer(p: dict, x, *, top_k: int, tile: int, experts_held: tuple[int, int] | None = None,
+                 routed_scaling: float = 1.0):
     """``x [T, d]`` -> the held experts' part of the layer's output ``[T, d]``.
     ``p``: ``router [d, E]``, and of the experts held ``w_gate``, ``w_up``
-    ``[held, d, f]``, ``w_down [held, f, d]``. ``tile`` is the row tile of the
+    ``[held, d, f]``, ``w_down [held, f, d]``; with ``bias [E]`` beside the
+    router the sigmoid router with that selection bias and ``routed_scaling``
+    (:func:`route`), else the softmax. ``tile`` is the row tile of the
     grouped matmuls; ``experts_held = (first, count)``, by default all."""
     held = experts_held or (0, p["router"].shape[1])
     if p["w_gate"].shape[0] != held[1]:
         raise ValueError(f"{p['w_gate'].shape[0]} experts' weights for experts_held={held}")
     with jax.named_scope("moe_route"):
-        top_e, w = route(x, p["router"], top_k)
+        top_e, w = route(x, p["router"], top_k, p.get("bias"), routed_scaling)
         row_pair, dest, tile_group = plan(top_e, held, tile)
     with jax.named_scope("moe_dispatch"):
         rows = _dispatch(x, row_pair, dest)

@@ -10,11 +10,11 @@ table, a full layer sees every earlier key and rotates by the YaRN table
 (:func:`rotary_tables`). Then ``expert_top_k`` of ``n_experts`` gated SiLU
 experts, no bias anywhere, RMSNorm, an untied head.
 
-:class:`Mellum` overrides :class:`~dsml_tpu.models.llama.Llama` as ``Jamba``
-does and adds only what differs: the parameter tree, the two rotary tables, the
-window handed to the flash kernels, the expert layer (``models/experts.py``,
-mounted where ``Llama._ffn`` mounts its expert layer) and the walk over unlike
-layers. The norm, the grouped-query projections (``_qkv_gqa``: key-value heads
+:class:`Mellum` is a :class:`~dsml_tpu.models.stack.LayerStack` (the walk over
+unlike layers, shared with ``models/deepseek_v3.py``) and adds only what
+differs: the parameter tree, the two rotary tables, the window handed to the
+flash kernels and the expert layer (``models/experts.py``, mounted where
+``Llama._ffn`` mounts its expert layer). The norm, the grouped-query projections (``_qkv_gqa``: key-value heads
 repeated to the query heads), the embedding, the chunked loss head over
 ``lm_head`` and the loss are the parents' code.
 
@@ -32,17 +32,15 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from dsml_tpu.models.common import fsdp_spec_fn, qmatmul
-from dsml_tpu.models.experts import PLAN_NAMES, expert_layer, route
-from dsml_tpu.models.llama import Llama, _rms_norm
+from dsml_tpu.models.common import qmatmul
+from dsml_tpu.models.experts import expert_layer
+from dsml_tpu.models.llama import _rms_norm
+from dsml_tpu.models.stack import LayerStack, no_serving
 
 __all__ = ["MellumConfig", "Mellum", "rotary_tables"]
 
 _PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
-# what whole-block recomputation keeps: the integers of each layer's routing (`PLAN_NAMES`)
-_KEPT = jax.checkpoint_policies.save_only_these_names(*PLAN_NAMES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,7 +69,7 @@ class MellumConfig:
     yarn_attention_factor: float = 1.2772588722239782
     rms_eps: float = 1e-6
     dtype: str = "float32"
-    remat: bool = False      # True recomputes each block in the backward, but for `_KEPT`
+    remat: bool = False      # True recomputes each block in the backward, but for `stack.KEPT`
     xent_chunk: int = 8192   # the blocked head's vocabulary threshold, 0 = dense (`GPT2Config.xent_chunk`)
 
     def __post_init__(self):
@@ -158,7 +156,7 @@ def _draw_table(key, cfg: MellumConfig):
     return (jax.random.normal(key, (cfg.vocab_size, cfg.d_model), jnp.float32) * 0.02).astype(cfg.dtype)
 
 
-class Mellum(Llama):
+class Mellum(LayerStack):
     """Mellum on the Llama / GPT-2 mesh scaffolding (see module docstring)."""
 
     def __init__(self, config: MellumConfig | None = None):
@@ -176,18 +174,6 @@ class Mellum(Llama):
             "layers": [_draw_layer(jax.random.fold_in(key, i), cfg) for i in range(cfg.n_layer)],
         }
 
-    def param_specs(self, pp: bool = False, fsdp: int = 1) -> dict:
-        """Replicated but for ZeRO sharding over ``fsdp`` (each leaf on its
-        first divisible dim, ``models.common.with_fsdp``)."""
-        from jax.sharding import PartitionSpec as P
-
-        if pp:
-            raise NotImplementedError(
-                "Mellum: pp stacks like layers on a leading axis; this stack holds two kinds")
-        shapes = jax.eval_shape(lambda: self.init(0))
-        spec = fsdp_spec_fn(fsdp)
-        return jax.tree.map(lambda leaf: spec(P(), *leaf.shape), shapes)
-
     # ---- architecture ---------------------------------------------------------
 
     @jax.named_scope("rope")
@@ -200,9 +186,14 @@ class Mellum(Llama):
         t1, t2 = t32[..., :half], t32[..., half:]
         return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos], axis=-1).astype(t.dtype)
 
-    def _block_closure(self, tp_axis, sp_axis, attn_impl):
-        sharded = {axis: lax.axis_size(axis) for axis in (tp_axis, sp_axis)
-                   if axis and lax.axis_size(axis) > 1}
+    def _kinds(self):
+        return self.config.layer_types
+
+    def _tables(self, positions):
+        return rotary_tables(self.config, positions)
+
+    def _check_axes(self, tp_axis, sp_axis, attn_impl):
+        sharded = self._sharded(tp_axis, sp_axis)
         if sharded:
             raise NotImplementedError(
                 f"Mellum: neither the expert layer nor the window is sharded over {sharded}: an "
@@ -210,17 +201,9 @@ class Mellum(Llama):
         if attn_impl not in self._FLASH_IMPLS:
             raise NotImplementedError(
                 f"Mellum: attn_impl={attn_impl!r} has no window; the flash kernels do (attn_impl='flash')")
-        cfg = self.config
 
-        def block(kind: str):
-            def run(layer, h, table):
-                with jax.named_scope("attn"):
-                    h = h + self._attention(layer, h, table, kind)
-                return self._ffn(layer, h)
-
-            return jax.checkpoint(run, policy=_KEPT) if cfg.remat else run
-
-        return {kind: block(kind) for kind in set(cfg.layer_types)}
+    def _feed_forward(self, layer, h, kind: str):
+        return self._ffn(layer, h)
 
     def _attention(self, layer, h, table, kind: str):
         from dsml_tpu.ops.flash import flash_attention
@@ -240,47 +223,5 @@ class Mellum(Llama):
                          experts_held=cfg.experts_held)
         return y.reshape(x.shape)
 
-    def expert_load(self, params, tokens, layer: int = 0):
-        """The (token, expert) pairs each expert of ``layer`` gets from ``tokens
-        [b, s]`` under ``params``, ``[n_experts]`` int32: a counter (the
-        benchmark's ``moe_load_max``), computed by the program's own forward up
-        to that layer's router."""
-        cfg = self.config
-        blocks = self._block_closure(None, None, "flash")
-        tables = rotary_tables(cfg, jnp.arange(tokens.shape[1], dtype=jnp.int32))
-        h = self._embed_spmd(params, tokens)
-        for kind, p in zip(cfg.layer_types[:layer], params["layers"][:layer]):
-            h = blocks[kind](p, h, tables[kind])
-        kind, p = cfg.layer_types[layer], params["layers"][layer]
-        h = h + self._attention(p, h, tables[kind], kind)
-        x = _rms_norm(h, p["rms_2"]["scale"], cfg.rms_eps)
-        top_e, _ = route(x.reshape(-1, x.shape[-1]), p["moe"]["router"], cfg.expert_top_k)
-        return jnp.sum(top_e.reshape(-1, 1) == jnp.arange(cfg.n_experts), axis=0, dtype=jnp.int32)
 
-    def _blocks_spmd(self, params, tokens, tp_axis=None, sp_axis=None, attn_impl="ring",
-                     seq_offset=None, pp_axis=None, n_micro=1):
-        """Embedding, then the layers one after another, each by its own type."""
-        if pp_axis:
-            raise NotImplementedError("Mellum: no pipeline over unlike layers (see param_specs)")
-        blocks = self._block_closure(tp_axis, sp_axis, attn_impl)
-        tables = rotary_tables(self.config, jnp.arange(tokens.shape[1], dtype=jnp.int32))
-        h = self._embed_spmd(params, tokens, tp_axis, sp_axis)
-        for kind, layer in zip(self.config.layer_types, params["layers"]):
-            h = blocks[kind](layer, h, tables[kind])
-        return h
-
-
-def _no_serving(name: str):
-    def entry(self, *args, **kwargs):
-        raise NotImplementedError(
-            f"Mellum.{name}: serving needs the window in the paged cache and a cache budget by "
-            "layer type: ROADMAP Reach 3")
-
-    entry.__name__ = name
-    return entry
-
-
-for _name in ("init_cache", "prefill", "prefill_chunk", "decode_step", "decode_step_slots",
-              "verify_step", "init_page_pool", "prefill_chunk_paged", "decode_step_slots_paged",
-              "verify_step_paged", "generate", "generate_spmd"):
-    setattr(Mellum, _name, _no_serving(_name))
+no_serving(Mellum, "serving needs the window in the paged cache and a cache budget by layer type: ROADMAP Reach 3")

@@ -518,18 +518,19 @@ def _fwd_kernel(q_of, kv_of, flags, *refs, **static):
     _fwd_tile(qi, ki, flag(_ROW_FIRST), flag(_ROW_LAST), *refs, **static)
 
 
-def _fwd_tile(qi, ki, first, last, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, mask_kv, window=None):
+def _fwd_tile(qi, ki, first, last, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *, head_dim, causal, block_q, block_k, mask_kv, window=None, v_dim=None):
     """One grid step of the forward on tile ``(qi, ki)``, the ``first`` /
     ``last`` of its q block. All four are values the caller read at the top
     level of its kernel: a wrapping kernel that delegates here from inside
     ``pl.when`` must not leave a ``program_id`` read for a cond branch
     (interpret mode substitutes the primitive only where it is bound in the
-    outer kernel jaxpr)."""
+    outer kernel jaxpr). ``v_dim``: a value head of another width than the
+    query-key ``head_dim`` (one head a block: the head-major form)."""
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
     scale = head_dim**-0.5
     fold = _scale_folds(scale)
-    heads = _head_lanes(acc.shape[1], head_dim)
+    heads = _head_lanes(acc.shape[1], v_dim or head_dim)
 
     @pl.when(first)
     def _init():
@@ -573,33 +574,40 @@ def _fwd_tile(qi, ki, first, last, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_re
 
 def _operands(qkv, head_dim):
     """How the kernels find q, k and v in what they were handed:
-    ``(q, k, v, at, lanes, groups)``. A grid step takes one ``lanes``-wide
-    block of the minor dimension, ``groups`` of them cover the heads, and
-    ``at`` is the lane block where each operand's first head lies. Three
-    arrays ``[B, S, W]``: where ``W`` is one head (the head-major form, ``B``
-    = batch·heads) the block is the whole minor dimension; else the arrays
-    are a projection's own output, ``W`` = heads·head_dim, and a block is 128
-    lanes, ``128 // head_dim`` heads. ONE array ``[B, S, 3·W]`` is all three
-    side by side, as a fused projection leaves them."""
+    ``(q, k, v, at, lanes, v_lanes, groups)``. A grid step takes one
+    ``lanes``-wide block of q's and k's minor dimension and one
+    ``v_lanes``-wide block of v's (and of the output's), ``groups`` of them
+    cover the heads, and ``at`` is the lane block where each operand's first
+    head lies. Three arrays ``[B, S, W]``: where ``W`` is one head (the
+    head-major form, ``B`` = batch·heads) the block is the whole minor
+    dimension, and v's head may be narrower or wider than q's and k's
+    (latent attention: a query-key width of 192, a value width of 128); else
+    the arrays are a projection's own output, ``W`` = heads·head_dim, and a
+    block is 128 lanes, ``128 // head_dim`` heads, of one width in all three.
+    ONE array ``[B, S, 3·W]`` is all three side by side, as a fused
+    projection leaves them."""
     if len(qkv) == 1:
         blocks = qkv[0].shape[2] // 3 // 128
-        return *qkv * 3, (0, blocks, 2 * blocks), 128, blocks
+        return *qkv * 3, (0, blocks, 2 * blocks), 128, 128, blocks
     q, k, v = qkv
-    lanes = head_dim if q.shape[2] == head_dim else 128
-    return q, k, v, (0, 0, 0), lanes, q.shape[2] // lanes
+    if q.shape[2] == head_dim:
+        return q, k, v, (0, 0, 0), head_dim, v.shape[2], 1
+    return q, k, v, (0, 0, 0), 128, 128, q.shape[2] // 128
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window=None, offset=None):
-    q, k, v, (q_at, k_at, v_at), lanes, groups = _operands(qkv, head_dim)
+    q, k, v, (q_at, k_at, v_at), lanes, v_lanes, groups = _operands(qkv, head_dim)
     batch, s_q = q.shape[:2]
     heads = lanes // head_dim
     tables = _walk(_live_tiles(s_q // block_q, k.shape[1] // block_k, block_q, block_k, causal, window, offset), kv_major=False)
     side = functools.partial(_side_spec, block_q, block_k, lanes)
+    v_side = functools.partial(_side_spec, block_q, block_k, v_lanes)
 
     kernel = functools.partial(
         _fwd_kernel, head_dim=head_dim, causal=causal,
         block_q=block_q, block_k=block_k, mask_kv=mask_kv, window=window,
+        **({} if v_lanes == lanes else {"v_dim": v_lanes // heads}),
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -611,20 +619,20 @@ def _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpr
                 _smem_spec(),
                 side(True, q_at),
                 side(False, k_at),
-                side(False, v_at),
+                v_side(False, v_at),
             ],
             out_specs=[
-                side(True),
+                v_side(True),
                 _stat_spec(heads, block_q, groups),
             ],
             scratch_shapes=[
-                _scratch((block_q, lanes)),
+                _scratch((block_q, v_lanes)),
                 _scratch((heads, block_q, _stat_lanes(block_k))),
                 _scratch((heads, block_q, _stat_lanes(block_k))),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((batch, s_q, groups * lanes), q.dtype),
+            jax.ShapeDtypeStruct((batch, s_q, groups * v_lanes), q.dtype),
             jax.ShapeDtypeStruct((batch * groups * heads, 8, s_q), jnp.float32),
         ],
         interpret=interpret,
@@ -813,7 +821,11 @@ def flash_stream_hop(
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, mask_kv, window=None):
+def _dq_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, acc, *, head_dim, causal, block_q, block_k, mask_kv, window=None, v_dim=None):
+    """``dq`` of one q block, accumulated over the kv blocks. ``v_dim``: a
+    value head of another width than the query-key ``head_dim`` comes one
+    head a block (the head-major form), so ``do`` and ``v`` are that head's
+    whole block and ``dp = do·vᵀ`` contracts it as it stands."""
     qi, ki, flag = _step(q_of, kv_of, flags)  # a q-major walk, as the forward's
     q0 = qs_ref[0] + qi * block_q
     k0 = ks_ref[0] + ki * block_k
@@ -854,7 +866,7 @@ def _dq_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_re
         dq_ref[0] = (acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, mask_kv, window=None):
+def _dkv_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *rest, head_dim, causal, block_q, block_k, mask_kv, window=None, v_dim=None):
     """``dk`` and ``dv`` of one kv block, accumulated over the q blocks; given
     a third output and scratch (``dq_ref``, ``dq_acc``) also ``dq``, from the
     tile it already holds.
@@ -869,7 +881,9 @@ def _dkv_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_r
     way out. Of a transposed operand block the heads are row ranges, aligned
     slices: every gradient dot takes ONE head's ``[d, block]`` and yields
     that head's rows of the accumulator, so a block of two heads does the
-    dots two blocks of one head do.
+    dots two blocks of one head do. ``v_dim``: ``dv``'s rows where the value
+    head is of another width than the query-key ``head_dim`` (one head a
+    block).
 
     The walk is kv-major (:func:`_walk`), so ``dq`` sums over what the walk
     leaves and comes back to: its accumulator is the whole query length of one
@@ -912,13 +926,14 @@ def _dkv_kernel(q_of, kv_of, flags, qs_ref, ks_ref, kstop_ref, q_ref, k_ref, v_r
         q_t, k_t, do_t = q.T, k.T, do.T  # [lanes, block]: a head is a row range
         for j, own in enumerate(heads):
             mine = slice(j * head_dim, (j + 1) * head_dim)
+            mine_v = mine if v_dim is None else slice(j * v_dim, (j + 1) * v_dim)
             st = _dot(k, _only(q, own), _NT)
             if not fold:
                 st = st * scale
             if causal or mask_kv:
                 st = _mask(st, q0, k0, kstop_ref[0], causal, mask_kv, q_axis=1, window=window)
             pt = jnp.exp(st - lse_ref[j, :1])
-            dv_acc[mine] = dv_acc[mine] + _dot(do_t[mine], pt.astype(do.dtype), _NT)  # dvᵀ += doᵀ·p
+            dv_acc[mine_v] = dv_acc[mine_v] + _dot(do_t[mine_v], pt.astype(do.dtype), _NT)  # dvᵀ += doᵀ·p
             # cast once, feeds both its dots
             dst = (pt * (_dot(v, _only(do, own), _NT) - dd_ref[j, :1])).astype(q.dtype)
             dk_acc[mine] = dk_acc[mine] + _dot(q_t[mine], dst, _NT)  # dkᵀ += qᵀ·ds
@@ -979,8 +994,8 @@ def _flash_bwd(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_
     (batch, lane block) fits VMEM beside the tile: a rule on the shapes in
     hand and nothing else. A longer query keeps the pair, ``flash_dq`` then
     ``flash_dkv``."""
-    q, _, _, _, lanes, _ = _operands(qkv, head_dim)
-    vmem = _fused_bwd_vmem(q.shape[1], lanes, block_q, block_k, q.dtype.itemsize)
+    q, _, _, _, lanes, v_lanes, _ = _operands(qkv, head_dim)
+    vmem = _fused_bwd_vmem(q.shape[1], max(lanes, v_lanes), block_q, block_k, q.dtype.itemsize)
     return _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, block_q, block_k,
                             interpret, mask_kv, head_dim, vmem if vmem <= _VMEM_BUDGET else None, window, offset)
 
@@ -990,10 +1005,11 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
     """:func:`_flash_bwd`'s kernels, ``dq`` riding ``flash_dkv`` in ``vmem``
     bytes of VMEM or, with ``None``, the pair. Jitted, like ``_flash_fwd``:
     a model's like layers trace and lower each kernel once."""
-    q, k, v, (q_at, k_at, v_at), lanes, groups = _operands(qkv, head_dim)
+    q, k, v, (q_at, k_at, v_at), lanes, v_lanes, groups = _operands(qkv, head_dim)
     batch, s_q = q.shape[:2]
     s_kv = k.shape[1]
     heads = lanes // head_dim
+    v_dim = v_lanes // heads
     live = _live_tiles(s_q // block_q, s_kv // block_k, block_q, block_k, causal, window, offset)
     # ds = p · (dp − delta + g_lse): delta = Σ do·o over a head's lanes, and
     # g_lse is the cotangent of the lse output. Both are per query row, so
@@ -1002,8 +1018,8 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
     # lie and lands a row a head, lane-major over seq like lse (a reshape to
     # [.., heads, head_dim] and a sum has XLA relayout the float32 products
     # first); the products of two bf16 are exact in float32 and HIGHEST keeps them so
-    width = groups * lanes
-    own = (jnp.arange(width)[:, None] // head_dim == jnp.arange(width // head_dim)).astype(jnp.float32)
+    width = groups * v_lanes
+    own = (jnp.arange(width)[:, None] // v_dim == jnp.arange(width // v_dim)).astype(jnp.float32)
     delta = jnp.einsum("bsw,wh->bhs", do.astype(jnp.float32) * o.astype(jnp.float32), own,
                        precision=lax.Precision.HIGHEST)
     dd = delta.reshape(-1, s_q) - glse  # [B·heads, s_q]
@@ -1012,12 +1028,16 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
     fused = vmem is not None
     dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)  # q's own array: all three where they came as one
     kv_shape = jax.ShapeDtypeStruct((batch, s_kv, groups * lanes), k.dtype)
+    dv_shape = jax.ShapeDtypeStruct((batch, s_kv, groups * v_lanes), v.dtype)
 
     static = dict(head_dim=head_dim, causal=causal, block_q=block_q, block_k=block_k, mask_kv=mask_kv, window=window)
+    if v_lanes != lanes:
+        static["v_dim"] = v_dim
     stat = _stat_spec(heads, block_q, groups)
     side = functools.partial(_side_spec, block_q, block_k, lanes)
+    v_side = functools.partial(_side_spec, block_q, block_k, v_lanes)
     in_specs = [_smem_spec(), _smem_spec(), _smem_spec(),
-                side(True, q_at), side(False, k_at), side(False, v_at), side(True), stat, stat]
+                side(True, q_at), side(False, k_at), v_side(False, v_at), v_side(True), stat, stat]
 
     dq = None
     if not fused:
@@ -1031,9 +1051,9 @@ def _flash_bwd_calls(qkv, o, lse8, do, glse, q_start, k_start, kv_stop, causal, 
         )(*tables, *scalars, q, k, v, do, lse8, dd)
 
     tables = _walk(live, kv_major=True)
-    out_specs = [side(False), side(False)]
-    out_shape = [kv_shape, kv_shape]
-    scratch_shapes = [_scratch((lanes, block_k)), _scratch((lanes, block_k))]
+    out_specs = [side(False), v_side(False)]
+    out_shape = [kv_shape, dv_shape]
+    scratch_shapes = [_scratch((lanes, block_k)), _scratch((v_lanes, block_k))]
     compiler_params = None
     if fused:
         out_specs.append(_vmem_spec((1, s_q, lanes), lambda b, g, *_: (b, 0, q_at + g)))
@@ -1199,8 +1219,10 @@ def flash_attention_lse(
     window: int | None = None,
 ):
     """Flash attention returning ``(out, lse)``. Shapes: q/k/v
-    [batch, heads, seq, head_dim] → out same-as-q, lse [batch, heads, seq_q]
-    (float32 logsumexp over the kv positions this call saw).
+    [batch, heads, seq, head_dim] → out same-as-v, lse [batch, heads, seq_q]
+    (float32 logsumexp over the kv positions this call saw). v's head may be
+    of another width than q's and k's (latent attention: 192 and 128); the
+    scale is then q's ``head_dim ** -0.5``.
 
     ``q_start``/``k_start`` are the GLOBAL positions of the first q/k row
     (traced values allowed) — the causal mask compares global positions, so
@@ -1215,7 +1237,7 @@ def flash_attention_lse(
     """
     b, h, s_q, d = q.shape
     out, lse = _attend((_flat3(q), _flat3(k), _flat3(v)), d, causal, q_start, k_start, block_q, block_k, interpret, window)
-    return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
+    return out.reshape(b, h, s_q, v.shape[-1]), lse.reshape(b, h, s_q)
 
 
 def flash_attention(
@@ -1228,7 +1250,8 @@ def flash_attention(
     interpret: bool | None = None,
     window: int | None = None,
 ) -> jax.Array:
-    """Flash attention. Shapes: [batch, heads, seq, head_dim].
+    """Flash attention. Shapes: [batch, heads, seq, head_dim] (v's own
+    width may differ: :func:`flash_attention_lse`).
 
     Numerically equivalent to ``dsml_tpu.ops.attention.attention`` (tests
     assert it) but never materializes the [seq, seq] score matrix — peak
@@ -1301,7 +1324,7 @@ def flash_block_grads(
     )
     dq = dq[:, :s_q].astype(jnp.float32).reshape(b, h, s_q, d)
     dk = dk[:, :s_kv].astype(jnp.float32).reshape(b, h, s_kv, d)
-    dv = dv[:, :s_kv].astype(jnp.float32).reshape(b, h, s_kv, d)
+    dv = dv[:, :s_kv].astype(jnp.float32).reshape(b, h, s_kv, v.shape[-1])
     return dq, dk, dv
 
 

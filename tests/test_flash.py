@@ -45,6 +45,43 @@ def test_flash_backward_matches_attention(causal):
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("dk,dv", [(48, 32), (192, 128), (32, 48)])
+@pytest.mark.parametrize("seq", [64, 70])  # 70 takes the padded path: blocks of 16 over 80 rows, the tail masked
+@pytest.mark.parametrize("pair", [False, True], ids=["fused", "pair"])
+def test_flash_query_key_width_apart_from_value_width(monkeypatch, dk, dv, seq, pair):
+    """Latent attention's shapes: q and k ``dk`` wide, v ``dv``; the output and
+    ``dv`` are ``dv`` wide, ``dq`` and ``dk`` ``dk`` wide, the scale ``dk ** -0.5``;
+    causal, forward and the three gradients against dense attention, with ``dq``
+    riding ``flash_dkv`` and, with no VMEM to give (a query too long for the
+    fused backward), through ``flash_dq`` beside it."""
+    from dsml_tpu.ops import flash as kernels
+
+    if pair:
+        monkeypatch.setattr(kernels, "_VMEM_BUDGET", 0)
+    rng = np.random.default_rng(dk + dv + seq)
+    q, k = (jnp.asarray(rng.standard_normal((1, 2, seq, dk)), jnp.float32) for _ in range(2))
+    v, w = (jnp.asarray(rng.standard_normal((1, 2, seq, dv)), jnp.float32) for _ in range(2))
+
+    def dense(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(dk)
+        s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=16, block_k=16)
+
+    def loss(f):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(f(q, k, v) * w), (0, 1, 2))
+
+    assert _bwd_kernels(loss(flash), q, k, v) == ["flash_fwd"] + (["flash_dq"] if pair else []) + ["flash_dkv"]
+    got, want = (jax.jit(loss(f))(q, k, v) for f in (flash, dense))
+    assert jax.eval_shape(flash, q, k, v).shape == (1, 2, seq, dv)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, e in zip(got[1], want[1]):
+        assert g.shape == e.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=1e-4, atol=1e-4)
+
+
 def test_flash_jits_and_handles_bf16():
     q, k, v = _qkv(s=128, seed=2)
     q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))

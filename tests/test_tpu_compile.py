@@ -422,6 +422,28 @@ def test_flash_compiles_at_head_128_with_and_without_a_window(topo, window, what
     assert found == ({"flash_fwd"} if what == "fwd" else {"flash_fwd", "flash_dkv"})
 
 
+@pytest.mark.parametrize("what", ["fwd", "grad", "grad_pair"])
+def test_flash_compiles_at_a_query_key_width_apart_from_the_value_width(topo, monkeypatch, what):
+    """[1, 32, 8192, 192] q and k beside [1, 32, 8192, 128] v, head-major:
+    `kanana2-8k`'s latent attention; the output and dv 128 wide, dq and dk 192.
+    ``grad_pair``: with no VMEM to give, as at a query too long for the fused
+    backward, ``flash_dq`` beside ``flash_dkv``."""
+    from dsml_tpu.ops import flash
+    from dsml_tpu.ops.flash import flash_attention
+
+    if what == "grad_pair":
+        monkeypatch.setattr(flash, "_VMEM_BUDGET", 0)
+
+    def out(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    fn = out if what == "fwd" else jax.grad(lambda q, k, v: out(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
+    qk, v = _sds((1, 32, 8192, 192), jnp.bfloat16), _sds((1, 32, 8192, 128), jnp.bfloat16)
+    text = _compile(topo, fn, qk, qk, v)
+    assert set(_flash_kernels_in(text)) == {"fwd": {"flash_fwd"}, "grad": {"flash_fwd", "flash_dkv"},
+                                            "grad_pair": {"flash_fwd", "flash_dq", "flash_dkv"}}[what]
+
+
 @pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
 def test_flash_compiles_on_the_rectangle_where_offsets_are_traced(topo, window):
     """What the ring and cp callers lower (PR 36): offsets that are values of the program, so the walk's
