@@ -86,7 +86,7 @@ class DeepseekV3Config:
     rope_theta: float = 1000000.0
     rms_eps: float = 1e-6
     dtype: str = "float32"
-    remat: bool = False      # True recomputes each block in the backward, but for `stack.KEPT`
+    remat: bool = False      # True recomputes each block in the backward, but for `stack.KEPT` (routing, flash outputs)
     xent_chunk: int = 8192   # the blocked head's vocabulary threshold, 0 = dense (`GPT2Config.xent_chunk`)
 
     @property

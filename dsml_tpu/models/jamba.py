@@ -37,14 +37,16 @@ from jax import lax
 
 from dsml_tpu.models.common import fsdp_spec_fn, qmatmul
 from dsml_tpu.models.llama import Llama, _rms_norm
+from dsml_tpu.ops.flash import FLASH_OUTPUTS
 from dsml_tpu.ops.selective_scan import SCAN_OUTPUTS, selective_scan
 
 __all__ = ["JambaConfig", "Jamba"]
 
-# What whole-block recomputation keeps of a Mamba layer, because it is cheap to keep and
-# dear to make again: the scan's outputs (y and the block-boundary states, 105 MB a layer at
-# 8,192 tokens), so the forward kernel runs once a step, not twice.
-_KEPT = jax.checkpoint_policies.save_only_these_names(SCAN_OUTPUTS)
+# What whole-block recomputation keeps, because it is cheap to keep and dear to make again:
+# of a Mamba layer the scan's outputs (y and the block-boundary states, 105 MB a layer at
+# 8,192 tokens), of an attention layer the flash forward's out and lse (47 MB at 8,192
+# tokens), so neither forward kernel runs twice a step.
+_KEPT = jax.checkpoint_policies.save_only_these_names(SCAN_OUTPUTS, FLASH_OUTPUTS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,7 +68,7 @@ class JambaConfig:
     attn_layer_offset: int = 7
     rms_eps: float = 1e-6
     dtype: str = "float32"
-    remat: bool = False     # True recomputes each block in the backward, but for `_KEPT`
+    remat: bool = False     # True recomputes each block in the backward, but for `_KEPT` (the kernels' outputs)
     xent_chunk: int = 8192  # the blocked head's vocabulary threshold, 0 = dense (`GPT2Config.xent_chunk`)
     n_experts: int = 0      # `Llama._ffn` reads it: one plain gated MLP a layer
 

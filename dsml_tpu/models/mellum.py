@@ -69,7 +69,7 @@ class MellumConfig:
     yarn_attention_factor: float = 1.2772588722239782
     rms_eps: float = 1e-6
     dtype: str = "float32"
-    remat: bool = False      # True recomputes each block in the backward, but for `stack.KEPT`
+    remat: bool = False      # True recomputes each block in the backward, but for `stack.KEPT` (routing, flash outputs)
     xent_chunk: int = 8192   # the blocked head's vocabulary threshold, 0 = dense (`GPT2Config.xent_chunk`)
 
     def __post_init__(self):

@@ -1,9 +1,11 @@
 """A decoder walked over a list of layer types: the embedding, then each
 layer by its own type, each block computed again in the backward (whole-block
-``jax.checkpoint``) but for the integers of its expert layer's routing
-(:data:`KEPT`). One walk for the families whose layers are not all alike and
-whose feed-forwards are expert layers (``models/mellum.py``: sliding and full
-attention; ``models/deepseek_v3.py``: a dense and a sparse feed-forward).
+``jax.checkpoint``) but for what :data:`KEPT` names: the integers of its
+expert layer's routing, and the flash forward kernel's ``out`` and ``lse``, so
+that the recomputed block does not run the attention kernel again. One walk
+for the families whose layers are not all alike and whose feed-forwards are
+expert layers (``models/mellum.py``: sliding and full attention;
+``models/deepseek_v3.py``: a dense and a sparse feed-forward).
 
 :class:`LayerStack` overrides :class:`~dsml_tpu.models.llama.Llama` where the
 walk differs and asks its family for four things: the type of each layer
@@ -24,11 +26,13 @@ from jax import lax
 from dsml_tpu.models.common import fsdp_spec_fn
 from dsml_tpu.models.experts import PLAN_NAMES, route
 from dsml_tpu.models.llama import Llama, _rms_norm
+from dsml_tpu.ops.flash import FLASH_OUTPUTS
 
 __all__ = ["KEPT", "LayerStack", "no_serving"]
 
 # what whole-block recomputation keeps: the integers of each layer's routing (`PLAN_NAMES`)
-KEPT = jax.checkpoint_policies.save_only_these_names(*PLAN_NAMES)
+# and the flash forward kernel's two outputs, so that kernel runs once a step, not twice
+KEPT = jax.checkpoint_policies.save_only_these_names(*PLAN_NAMES, FLASH_OUTPUTS)
 
 
 class LayerStack(Llama):

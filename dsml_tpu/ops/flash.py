@@ -128,6 +128,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from dsml_tpu.ops.collectives import ring_pass
@@ -138,6 +139,7 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 __all__ = [
+    "FLASH_OUTPUTS",
     "flash_attention",
     "flash_attention_lse",
     "flash_attention_packed",
@@ -148,6 +150,7 @@ __all__ = [
     "ring_flash_attention",
 ]
 
+FLASH_OUTPUTS = "flash_outputs"  # the checkpoint name of the forward kernel's out and lse
 _NEG_INF = -1e30
 _MAX_FLOOR = -1e20  # running-max floor: keeps exp() sane for fully-masked rows
 
@@ -1092,6 +1095,8 @@ def _flash(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, 
 
 def _flash_fwd_rule(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window, offset):
     out, lse8 = _flash_fwd(qkv, q_start, k_start, kv_stop, causal, block_q, block_k, interpret, mask_kv, head_dim, window, offset)
+    # named so that a remat policy can keep the forward kernel's two outputs and not run it again
+    out, lse8 = checkpoint_name(out, FLASH_OUTPUTS), checkpoint_name(lse8, FLASH_OUTPUTS)
     return (out, lse8[:, 0, :]), (qkv, out, lse8, q_start, k_start, kv_stop)
 
 

@@ -150,6 +150,31 @@ def test_checkpoint_writes_the_component_the_recomputed_forward_is_read_by():
     assert any(REMAT in tokens and "transpose" in tokens[:tokens.index(REMAT)] for tokens in names)
 
 
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "not_kept"])
+def test_the_flash_outputs_name_reaches_the_compiled_step(kept):
+    """Whole-block remat keeps what ``ops/flash.py::FLASH_OUTPUTS`` names (``models/stack.py::KEPT``,
+    ``models/jamba.py::_KEPT``): under a policy that names it, the compiled gradient holds the flash
+    forward outside the recomputed forward alone; under one that names nothing, inside it too. A jax
+    that loses the name on the way fails here and does not silently run the kernel twice a step."""
+    from dsml_tpu.ops.flash import FLASH_OUTPUTS, flash_attention_packed
+
+    policy = jax.checkpoint_policies.save_only_these_names(*([FLASH_OUTPUTS] if kept else []))
+    block = jax.checkpoint(lambda x, w: flash_attention_packed(x @ w, 64)[0].sum(), policy=policy)
+    shapes = [jax.ShapeDtypeStruct(shape, "float32") for shape in ((1, 64, 128), (128, 384))]
+    text = jax.jit(jax.grad(block, argnums=(0, 1))).lower(*shapes).compile().as_text()
+    names = [tokens for tokens in (re.split(r"[/();]", name) for name in re.findall(r'op_name="([^"]*)"', text))
+             if "flash_fwd" in tokens]
+    assert any(REMAT not in tokens for tokens in names)
+    assert any(REMAT in tokens for tokens in names) == (not kept)
+
+
+@pytest.mark.parametrize("family", ["jamba", "mellum"])
+def test_the_remat_families_recompute_no_flash_forward(family):
+    """The same in the two families' compiled steps: the flash forward is never in their recomputed forward."""
+    names = [tokens for tokens in _op_names(1, family=family) if "flash_fwd" in tokens]
+    assert names and not any(REMAT in tokens for tokens in names)
+
+
 def test_the_inner_names_collide_with_nothing_jax_writes():
     """The same ``jnp`` calls the four scopes wrap, jitted here under no scope: a root-mean-square
     norm, ``jnp.repeat``, the rotation and a depthwise convolution by taps, forward and backward."""
