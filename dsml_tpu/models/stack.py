@@ -11,13 +11,18 @@ expert layers (``models/mellum.py``: sliding and full attention;
 walk differs and asks its family for four things: the type of each layer
 (``_kinds``), what a step computes once for all layers of a type (``_tables``:
 rotary tables), and a block's attention (``_attention``) and feed-forward
-(``_feed_forward``) by its type. A family checks the mesh axes and attention
+(``_feed_forward``) by its type. A family may also apply the layers more
+than once a step with the same parameters (``passes``, 1 here), each pass
+under the name ``ut_step`` and handing on what ``_pass_end`` makes of its
+output (``models/ouro.py``). A family checks the mesh axes and attention
 implementations it can run (``_check_axes``); ``tp``, ``sp`` / ``cp`` and ``pp``
 raise in both (an exchange of rows between chips is ROADMAP Reach 2; a pipeline
 stacks like layers on a leading axis, and this stack holds unlike ones).
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +66,15 @@ class LayerStack(Llama):
         """Raise for the mesh axes and attention implementations the family does not compute."""
         raise NotImplementedError
 
+    @property
+    def passes(self) -> int:
+        """How many times a step applies the layers, the same parameters each time."""
+        return 1
+
+    def _pass_end(self, params, h):
+        """What a pass hands on, to the next pass and to the loss: ``h`` itself."""
+        return h
+
     # ---- the walk ------------------------------------------------------------
 
     def _block_fn(self, kind: str):
@@ -92,13 +106,19 @@ class LayerStack(Llama):
         return blocks
 
     def _walk(self, params, tokens, blocks, upto: int | None = None, tp_axis=None, sp_axis=None):
-        """The embedding, then layers ``[0, upto)`` each by its own type:
-        ``(h, tables)``."""
+        """The embedding, then ``passes`` times layers ``[0, upto)`` each by
+        its own type, each pass's output through ``_pass_end``: ``(states,
+        tables)``, ``states`` the state after each pass."""
         tables = self._tables(jnp.arange(tokens.shape[1], dtype=jnp.int32))
         h = self._embed_spmd(params, tokens, tp_axis, sp_axis)
-        for kind, layer in zip(self._kinds()[:upto], params["layers"][:upto]):
-            h = blocks[kind](layer, h, tables[kind])
-        return h, tables
+        states = []
+        for _ in range(self.passes):
+            with jax.named_scope("ut_step") if self.passes > 1 else contextlib.nullcontext():
+                for kind, layer in zip(self._kinds()[:upto], params["layers"][:upto]):
+                    h = blocks[kind](layer, h, tables[kind])
+                h = self._pass_end(params, h)
+            states.append(h)
+        return states, tables
 
     def _blocks_spmd(self, params, tokens, tp_axis=None, sp_axis=None, attn_impl="ring",
                      seq_offset=None, pp_axis=None, n_micro=1):
@@ -106,7 +126,7 @@ class LayerStack(Llama):
         if pp_axis:
             raise NotImplementedError(f"{type(self).__name__}: no pipeline over unlike layers (see param_specs)")
         blocks = self._block_closure(tp_axis, sp_axis, attn_impl)
-        return self._walk(params, tokens, blocks, tp_axis=tp_axis, sp_axis=sp_axis)[0]
+        return self._walk(params, tokens, blocks, tp_axis=tp_axis, sp_axis=sp_axis)[0][-1]
 
     def expert_load(self, params, tokens, layer: int | None = None):
         """The (token, expert) pairs each expert of ``layer`` (by default the
@@ -117,7 +137,8 @@ class LayerStack(Llama):
         kinds = self._kinds()
         if layer is None:
             layer = next(i for i, p in enumerate(params["layers"]) if "moe" in p)
-        h, tables = self._walk(params, tokens, self._block_closure(None, None, "flash"), upto=layer)
+        states, tables = self._walk(params, tokens, self._block_closure(None, None, "flash"), upto=layer)
+        h = states[-1]
         kind, p = kinds[layer], params["layers"][layer]
         h = h + self._attention(p, h, tables[kind], kind)
         x = _rms_norm(h, p["rms_2"]["scale"], cfg.rms_eps)

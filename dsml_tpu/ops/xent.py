@@ -15,6 +15,11 @@ and a block's log-sum-exp is complete when its logits are:
   ``dh_blk = ds·W`` and ``dW += dsᵀ·h_blk``: three vocabulary-wide matmuls
   a block and no second pass; the backward only scales by the cotangent.
 
+The sweep takes a weight of each row's own, held constant, and gives each
+row's loss beside the weighted sum (:func:`weighted_softmax_xent`: a loss
+whose weights are learned, ``models/ouro.py``'s exits); the mean
+(:func:`chunked_softmax_xent`) is it at ``1 / N`` a row.
+
 ``rows`` comes from the shapes alone (:func:`block_rows`). This is the
 single-shard counterpart of the TP path's distributed-logsumexp loss
 (``models/gpt2.py::loss_spmd``), which splits vocab across chips instead of
@@ -30,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["chunked_softmax_xent", "block_rows"]
+__all__ = ["chunked_softmax_xent", "weighted_softmax_xent", "block_rows"]
 
 # what the head's float32 working set may take: one block's [rows, V] logits and,
 # where there are several blocks, the [V, d] dW they sum into. Blocks cost traffic
@@ -62,19 +67,20 @@ def _vary_alike(*xs):
             for x in xs]
 
 
-def _sweep(h, wte, targets, rows, grads):
-    """Mean loss over the ``n`` rows of ``h`` and, with ``grads``, its
-    gradients ``dh`` [n, d] and ``dW`` [V, d] in the dtypes of ``h`` and
-    ``wte`` (``dW`` summed over the blocks in float32); else ``None``."""
+def _sweep(h, wte, targets, weight, rows, grads):
+    """``(Σ weight · (lse − tgt), each row's own loss lse − tgt [n])`` over the
+    ``n`` rows of ``h`` (``weight`` [n] float32, the losses float32) and, with
+    ``grads``, the gradients ``dh`` [n, d] and ``dW`` [V, d] of the sum in the
+    dtypes of ``h`` and ``wte`` (``dW`` summed over the blocks in float32),
+    else ``None``."""
     n, d = h.shape
     v = wte.shape[0]
     n_blocks = -(-n // rows)
     pad = n_blocks * rows - n
-    weight = jnp.pad(jnp.full((n,), 1.0 / n, jnp.float32), (0, pad))
     blocks = (
         jnp.pad(h, ((0, pad), (0, 0))).reshape(n_blocks, rows, d),
         jnp.pad(targets, (0, pad)).reshape(n_blocks, rows),
-        weight.reshape(n_blocks, rows),
+        jnp.pad(weight, (0, pad)).reshape(n_blocks, rows),
     )
 
     def body(carry, block):
@@ -86,34 +92,38 @@ def _sweep(h, wte, targets, rows, grads):
         m = logits.max(axis=-1)
         lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
         tgt = jnp.take_along_axis(logits, t_b[:, None], 1)[:, 0]
-        loss = loss + jnp.sum((lse - tgt) * w_b)
+        row_loss = lse - tgt
+        loss = loss + jnp.sum(row_loss * w_b)
         if not grads:
-            return (loss, dw), None
+            return (loss, dw), (None, row_loss)
         onehot = jnp.arange(v)[None, :] == t_b[:, None]
         ds = (jnp.exp(logits - lse[:, None]) - onehot) * w_b[:, None]  # [rows, V]
-        return (loss, dw + ds.T @ h32), (ds @ wte.astype(jnp.float32)).astype(h.dtype)
+        return (loss, dw + ds.T @ h32), ((ds @ wte.astype(jnp.float32)).astype(h.dtype), row_loss)
 
     loss0, dw0 = _vary_alike(h, jnp.zeros((), jnp.float32), jnp.zeros((v, d), jnp.float32))[1:]
-    (loss, dw), dh = lax.scan(body, (loss0, dw0 if grads else None), blocks)
+    (loss, dw), (dh, row_losses) = lax.scan(body, (loss0, dw0 if grads else None), blocks)
+    out = (loss, row_losses.reshape(-1)[:n])
     # dW is cast here, not in the backward: the float32 [V, d] must not outlive the sweep
-    return (loss, dh.reshape(n_blocks * rows, d)[:n], dw.astype(wte.dtype)) if grads else (loss, None, None)
+    return out + ((dh.reshape(n_blocks * rows, d)[:n], dw.astype(wte.dtype)) if grads else (None, None))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _blocked_xent(h: jax.Array, wte: jax.Array, targets: jax.Array, rows: int):
-    """Mean of ``lse − tgt_logit``. h [N, d] (any float dtype — promoted to
-    f32 for the reductions), wte [V, d], targets [N] int32 → scalar f32."""
-    return _sweep(h, wte, targets, rows, grads=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _blocked_xent(h, wte, targets, weight, rows):
+    """``(Σ_i weight_i · (lse_i − tgt_logit_i), the rows' own losses [N])``. h
+    [N, d] (any float dtype — promoted to f32 for the reductions), wte [V, d],
+    targets [N] int32, weight [N] f32; the weights and the rows' losses are
+    constants to differentiation."""
+    return _sweep(h, wte, targets, weight, rows, grads=False)[:2]
 
 
-def _fwd_rule(h, wte, targets, rows):
-    loss, dh, dw = _sweep(h, wte, targets, rows, grads=True)
-    return loss, (dh, dw)
+def _fwd_rule(h, wte, targets, weight, rows):
+    loss, row_losses, dh, dw = _sweep(h, wte, targets, weight, rows, grads=True)
+    return (loss, row_losses), (dh, dw)
 
 
-def _bwd_rule(rows, res, g):  # g: scalar cotangent of the mean loss
+def _bwd_rule(rows, res, g):  # g: the cotangents of the loss and of the rows' losses (a constant)
     dh, dw = res
-    return (g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None
+    return (g[0] * dh).astype(dh.dtype), (g[0] * dw).astype(dw.dtype), None, None
 
 
 _blocked_xent.defvjp(_fwd_rule, _bwd_rule)
@@ -126,6 +136,25 @@ def chunked_softmax_xent(
 ) -> jax.Array:
     """Mean next-token cross-entropy of ``h @ wte.T`` vs ``targets`` without
     ever materializing the logits. Differentiable in h and wte."""
+    n = math.prod(targets.shape)
+    return weighted_softmax_xent(h, wte, targets, jnp.full(targets.shape, 1.0 / n, jnp.float32))[0]
+
+
+def weighted_softmax_xent(
+    h: jax.Array,  # [..., d] final hidden states
+    wte: jax.Array,  # [V, d] unembedding matrix
+    targets: jax.Array,  # [...] int32
+    weights: jax.Array,  # [...] each row's weight in the loss
+) -> tuple[jax.Array, jax.Array]:
+    """``(Σ weights · CE, CE)``: the weighted sum of each row's next-token
+    cross-entropy, differentiable in ``h`` and ``wte`` with the weights held
+    constant (the gradients are made in the same one sweep), and each row's
+    own loss, shaped like ``targets``, float32 and constant. A weight that is
+    itself a function of parameters, ``p``, gets its gradient from
+    ``Σ (p − stop_gradient(p)) · CE`` beside this sum: together they are
+    ``Σ p · CE`` with the exact gradient."""
     n, d = math.prod(h.shape[:-1]), h.shape[-1]
-    operands = _vary_alike(h.reshape(n, d), wte, targets.reshape(n).astype(jnp.int32))
-    return _blocked_xent(*operands, block_rows(n, wte.shape[0], d)[1])
+    operands = _vary_alike(h.reshape(n, d), wte, targets.reshape(n).astype(jnp.int32),
+                           lax.stop_gradient(weights.reshape(n).astype(jnp.float32)))
+    loss, row_losses = _blocked_xent(*operands, block_rows(n, wte.shape[0], d)[1])
+    return loss, lax.stop_gradient(row_losses).reshape(targets.shape)

@@ -1,9 +1,10 @@
 """What whole-block remat keeps of an attention layer: the flash forward
 kernel's ``out`` and ``lse`` (``ops/flash.py::FLASH_OUTPUTS``), so that in
-the three families that recompute each block in the backward (Mellum and
-DeepSeek-V3 under ``models/stack.py::KEPT``, Jamba under its own ``_KEPT``)
-the kernel runs once per attention layer a step, not twice, and the
-gradients are the un-checkpointed block's. Tiny sizes, the interpreter."""
+the four families that recompute each block in the backward (Mellum,
+DeepSeek-V3 and Ouro under ``models/stack.py::KEPT``, Jamba under its own
+``_KEPT``) the kernel runs once per attention layer a step (in Ouro once
+per layer and pass), not twice, and the gradients are the un-checkpointed
+block's. Tiny sizes, the interpreter."""
 
 import dataclasses
 import functools
@@ -21,6 +22,7 @@ from dsml_tpu.models import experts, jamba, stack  # noqa: E402
 from dsml_tpu.models.deepseek_v3 import DeepseekV3, DeepseekV3Config  # noqa: E402
 from dsml_tpu.models.jamba import Jamba, JambaConfig  # noqa: E402
 from dsml_tpu.models.mellum import Mellum, MellumConfig  # noqa: E402
+from dsml_tpu.models.ouro import Ouro, OuroConfig  # noqa: E402
 from dsml_tpu.ops.selective_scan import SCAN_OUTPUTS  # noqa: E402
 from dsml_tpu.parallel.hybrid import hybrid_loss_fn  # noqa: E402
 from dsml_tpu.parallel.mesh import MeshSpec, build_mesh  # noqa: E402
@@ -34,12 +36,14 @@ FAMILIES = {
         MellumConfig.tiny(remat=remat), n_layer=2, layer_types=("sliding_attention", "full_attention"))), 2),
     "deepseek_v3": (lambda remat: DeepseekV3(dataclasses.replace(DeepseekV3Config.tiny(remat=remat), n_layer=2)), 2),
     "jamba": (lambda remat: Jamba(JambaConfig.tiny(remat=remat)), 1),  # one attention layer among four
+    "ouro": (lambda remat: Ouro(OuroConfig.tiny(remat=remat)), 2 * 4),  # two layers, four passes
 }
 # family -> (the module that holds its policy, the policy's name there, the policy without the flash outputs)
 POLICIES = {
     "mellum": (stack, "KEPT", jax.checkpoint_policies.save_only_these_names(*experts.PLAN_NAMES)),
     "deepseek_v3": (stack, "KEPT", jax.checkpoint_policies.save_only_these_names(*experts.PLAN_NAMES)),
     "jamba": (jamba, "_KEPT", jax.checkpoint_policies.save_only_these_names(SCAN_OUTPUTS)),
+    "ouro": (stack, "KEPT", jax.checkpoint_policies.save_only_these_names(*experts.PLAN_NAMES)),
 }
 
 

@@ -232,3 +232,63 @@ def test_1f1b_head_takes_the_blocked_path(devices8, rows_of):
     for a, b in zip(jax.tree.leaves(p_blocked), jax.tree.leaves(p_dense)):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
         assert np.max(np.abs(a - b)) <= 1e-5 * (np.max(np.abs(b)) + 1e-8)
+
+
+@pytest.mark.parametrize("rows", [16, 48])  # several blocks with padding, and one
+def test_default_weights_give_the_mean_bit_equal(rows_of, rows):
+    """``weighted_softmax_xent`` at ``1 / n`` a row is the mean head: the same
+    sweep, so loss and both gradients are the same bits."""
+    n, d, vocab = 48, 16, 1000
+    h, wte, targets = _inputs(3, n, d, vocab)
+    rows_of(rows, vocab, d)
+    mean = jax.value_and_grad(lambda h, w: chunked_softmax_xent(h, w, targets), argnums=(0, 1))(h, wte)
+    weighted = jax.value_and_grad(
+        lambda h, w: xent.weighted_softmax_xent(h, w, targets, jnp.full(n, 1.0 / n))[0], argnums=(0, 1))(h, wte)
+    for a, b in zip(jax.tree.leaves(weighted), jax.tree.leaves(mean)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("rows", [16, 48])
+def test_row_weights_and_row_losses_match_plain_jnp(rows_of, rows):
+    """Arbitrary weights of each row: the sum ``Σ w·CE``, its gradients with the
+    weights held constant, and each row's own loss, against ``jnp``."""
+    n, d, vocab = 48, 16, 1000
+    h, wte, targets = _inputs(4, n, d, vocab)
+    weights = jnp.asarray(np.random.default_rng(5).uniform(0.0, 2.0, n), jnp.float32)
+    rows_of(rows, vocab, d)
+
+    def plain(h, w):
+        logp = jax.nn.log_softmax((h @ w.T).astype(jnp.float32))
+        ce = -jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
+        return jnp.sum(weights * ce), ce
+
+    (want, want_rows), want_grads = jax.value_and_grad(plain, argnums=(0, 1), has_aux=True)(h, wte)
+    (got, got_rows), got_grads = jax.value_and_grad(
+        lambda h, w: xent.weighted_softmax_xent(h, w, targets, weights), argnums=(0, 1), has_aux=True)(h, wte)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_rows), np.asarray(want_rows), rtol=1e-5, atol=1e-6)
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+def test_weights_learned_beside_the_sweep_get_the_exact_gradient(rows_of):
+    """``Σ sg(p)·CE`` from the sweep plus ``Σ (p − sg(p))·sg(CE)`` has the value and
+    every gradient of ``Σ p·CE``, ``p`` a function of parameters (a softmax here)."""
+    n, d, vocab = 48, 16, 1000
+    h, wte, targets = _inputs(6, n, d, vocab)
+    logits_p = jnp.asarray(np.random.default_rng(7).standard_normal(n), jnp.float32)
+    rows_of(16, vocab, d)
+
+    def plain(h, w, z):
+        logp = jax.nn.log_softmax((h @ w.T).astype(jnp.float32))
+        return jnp.sum(jax.nn.softmax(z) * -jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0])
+
+    def swept(h, w, z):
+        p = jax.nn.softmax(z)
+        loss, ce = xent.weighted_softmax_xent(h, w, targets, jax.lax.stop_gradient(p))
+        return loss + jnp.sum((p - jax.lax.stop_gradient(p)) * ce)
+
+    want = jax.value_and_grad(plain, argnums=(0, 1, 2))(h, wte, logits_p)
+    got = jax.value_and_grad(swept, argnums=(0, 1, 2))(h, wte, logits_p)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
